@@ -151,7 +151,7 @@ lint-fast:
 # Wire-throughput baseline for the zero-copy data plane (ROADMAP item
 # 1): updates/sec x payload-size x K-shards over the REAL multihost TCP
 # path, recorded to benchmarks/WIRE_EVIDENCE.json so the protocol
-# rewrite lands against a measured number instead of BENCH_r05
+# rewrite lands against a measured (host-CPU) number instead of
 # folklore.  Baseline history: the v8 blob pipeline measured 10.8
 # updates/sec on the large-payload K=1 cell (whole-wall, jit compiles
 # included); the v9 segmented plane (PR 13) measures >= 55/sec steady
@@ -193,7 +193,7 @@ smoke-bucket:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_bucket_stream.py -q -m 'not slow' -p no:cacheprovider
 
 # Bucket-stream evidence run: gradsync_virtual w8 identity < 20 ms
-# under the solo bucket plan (vs 39.1 ms in BENCH_r05), interleaved
+# under the solo bucket plan (vs 39.1 ms before it, host CPU), interleaved
 # whole-tree vs bucket-streamed wire cells at the ~1.3 MB payload
 # (pooled medians — single runs on this 1-CPU host swing ~±30%),
 # the streaming-latency mechanism measurement (first bucket decodable
@@ -227,4 +227,9 @@ smoke-races:
 bench:
 	python bench.py
 
-.PHONY: test tier1 smoke-overlap smoke-chaos chaos-evidence smoke-elastic elastic-evidence smoke-robust robust-evidence smoke-shard shard-evidence smoke-failover failover-evidence smoke-hier hier-evidence smoke-overload overload-evidence lint lint-json lint-fast wire-evidence smoke-serve serve-evidence smoke-bucket bucket-evidence smoke-codec-wire smoke-races bench
+# On a TPU (through the chip tool): every training path starts, compiles
+# and steps; fails without a chip.  --devices 4 on the four-chip host.
+chip-smoke:
+	python chip_smoke.py
+
+.PHONY: chip-smoke test tier1 smoke-overlap smoke-chaos chaos-evidence smoke-elastic elastic-evidence smoke-robust robust-evidence smoke-shard shard-evidence smoke-failover failover-evidence smoke-hier hier-evidence smoke-overload overload-evidence lint lint-json lint-fast wire-evidence smoke-serve serve-evidence smoke-bucket bucket-evidence smoke-codec-wire smoke-races bench

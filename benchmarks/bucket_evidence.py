@@ -3,7 +3,7 @@
 Four sections, each anchored to a committed number:
 
 * ``gradsync_virtual`` — the w8 identity gradsync pattern cost
-  (BENCH_r05: **39.1 ms**; the acceptance gate is **< 20 ms**).  The
+  (the r05 host-CPU record: **39.1 ms**; the acceptance gate is **< 20 ms**).  The
   lever is the solo-large-leaf bucket plan (`parallel.collectives.
   _plan_buckets(solo_bytes=...)`): packing a multi-MB matrix into a
   shared bucket pays a concat-in/slice-out memcpy both ways for a
@@ -65,9 +65,10 @@ os.environ.setdefault("PS_BUFFER_SENTINEL", "1")
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("PS_BUCKET_EV_JAX_CACHE",
-                                 "/tmp/ps_bucket_ev_jax_cache"))
+from pytorch_ps_mpi_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache)
+
+configure_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 import numpy as np  # noqa: E402
@@ -91,7 +92,7 @@ WARMUP = 4
 # Committed PR 13 whole-tree steady baseline at this cell
 # (benchmarks/WIRE_EVIDENCE.json ``cells.large_k1.updates_per_sec``).
 PR13_BASELINE_UPS = 65.565
-# BENCH_r05's committed gradsync number the < 20 ms gate is anchored to.
+# The r05 host-CPU gradsync number the < 20 ms gate is anchored to.
 R05_GRADSYNC_MS = 39.122
 
 
@@ -110,7 +111,7 @@ def _teacher(seed, sizes):
 def gradsync_virtual() -> dict:
     """The bench.py ``gradsync_virtual`` w8 identity measurement (same
     1.86M-param payload, same jitted shard_map psum program), timed for
-    BOTH bucket plans: the legacy pack-everything plan (what BENCH_r05's
+    BOTH bucket plans: the legacy pack-everything plan (what the r05 record's
     39.1 ms measured) and the new solo-large-leaf default."""
     from collections import OrderedDict
 

@@ -95,7 +95,11 @@ def build_compiled_lm(zero: bool = False, decompose: bool = False):
                       n_layers=12, d_ff=4096, max_len=seq,
                       dtype=jnp.bfloat16,
                       attn=functools.partial(flash_attention, causal=True))
-    lparams = build_lm(lm, seq_len=seq)
+    # Init runs eagerly on the CPU, where the Mosaic flash kernel cannot:
+    # the parameter tree does not depend on the attention function, so it
+    # comes from the dense-attention twin; only the program lowered for
+    # the TPU topology holds the kernel.
+    lparams = build_lm(lm.copy(attn=None), seq_len=seq)
     opt = SGD(list(lparams.items()), lr=0.01, momentum=0.9, mesh=cpu_mesh,
               zero=zero, decompose_allreduce=decompose)
     opt.mesh = aot_mesh

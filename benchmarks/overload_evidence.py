@@ -415,8 +415,8 @@ def main(argv=None):
             json.dump(out, f, indent=1, default=str)
             f.write("\n")
         print(f"wrote {path}", file=sys.stderr)
-    # Hard exit: teardown against mid-dispatch daemon worker threads
-    # occasionally wedges the pinned CPU runtime (the CHAOS_EVIDENCE
+    # Hard exit: interpreter teardown against daemon worker threads that
+    # are still mid-dispatch can hang or abort (the CHAOS_EVIDENCE
     # precedent) — the artifact is on disk, nothing of value is lost.
     sys.stdout.flush()
     sys.stderr.flush()

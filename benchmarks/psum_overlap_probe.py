@@ -78,7 +78,11 @@ def lower_small_lm():
     lm = TransformerLM(vocab_size=8192, d_model=512, n_heads=8, n_layers=4,
                        d_ff=2048, max_len=seq, dtype=jnp.bfloat16,
                        attn=functools.partial(flash_attention, causal=True))
-    lparams = build_lm(lm, seq_len=seq)
+    # Init runs eagerly on the CPU, where the Mosaic flash kernel cannot:
+    # the parameter tree does not depend on the attention function, so it
+    # comes from the dense-attention twin; only the program lowered for
+    # the TPU topology holds the kernel.
+    lparams = build_lm(lm.copy(attn=None), seq_len=seq)
     opt = SGD(list(lparams.items()), lr=0.01, momentum=0.9, mesh=cpu_mesh)
     opt.mesh = aot_mesh
     step_fn = opt._make_spmd_step(make_lm_loss(lm), False)

@@ -175,8 +175,8 @@ def scenario_byzantine_mean(seed):
     """One of three ranks pushes 100x-scaled gradients; plain mean has
     breakdown point 0 — the attacker steers every update.  (Workers are
     paced here too: an unthrottled 4-thread fleet hammering the single
-    shared CPU device can wedge the pinned 0.4.x runtime's transfer path
-    — a harness artifact; deployed workers are separate processes.)"""
+    shared CPU device can stall the runtime's transfer path — a harness
+    artifact; deployed workers are separate processes.)"""
     plan = FaultPlan(seed=seed, byzantine_rank=1, byzantine_mode="scale",
                      byzantine_scale=100.0)
     out, _, _ = _run_fleet(seed, plan=plan, pace_s=0.05)
@@ -327,10 +327,9 @@ def main(argv=None):
             f.write("\n")
         print(f"wrote {path}", file=sys.stderr)
     # Hard exit: the threaded in-process fleets can leave daemon worker
-    # threads mid-XLA-dispatch, and the pinned 0.4.x CPU runtime's
-    # teardown occasionally wedges against them at interpreter shutdown
-    # (observed as a post-print hang with no Python frame).  The evidence
-    # is already flushed; skip teardown.
+    # threads mid-XLA-dispatch, and interpreter teardown against them can
+    # hang (observed as a post-print hang with no Python frame) or abort.
+    # The evidence is already flushed; skip teardown.
     sys.stdout.flush()
     sys.stderr.flush()
     os._exit(0)

@@ -105,7 +105,11 @@ def main() -> None:
                        n_layers=12, d_ff=4096, max_len=seq,
                        dtype=jnp.bfloat16,
                        attn=functools.partial(flash_attention, causal=True))
-    lparams = build_lm(lm, seq_len=seq)
+    # Init runs eagerly on the CPU, where the Mosaic flash kernel cannot:
+    # the parameter tree does not depend on the attention function, so it
+    # comes from the dense-attention twin; only the program lowered for
+    # the TPU topology holds the kernel.
+    lparams = build_lm(lm.copy(attn=None), seq_len=seq)
     lopt = SGD(list(lparams.items()), lr=0.01, momentum=0.9, mesh=cpu_mesh)
     toks = synthetic_lm(16, seq_len=seq, vocab=32768, seed=0)
     lb = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=shd)

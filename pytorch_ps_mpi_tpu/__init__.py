@@ -8,10 +8,6 @@ TPU-first: gradient sync is a static-shape XLA collective over an ICI mesh
 inside one jitted SPMD step, not host-side MPI (plus an AdamW extension).
 """
 
-from .utils import compat as _compat
-
-_compat.install()  # jax.shard_map polyfill; must precede submodule imports
-
 from .ps import (MPI_PS, PS, SGD, Adam, AdamW, ElasticResumeError,
                  SDCDetectedError)
 from .async_ps import AsyncPS, AsyncSGD, AsyncAdam

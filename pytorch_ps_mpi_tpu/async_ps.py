@@ -53,6 +53,7 @@ import numpy as np
 
 from .errors import FleetDeadError, NotCompiledError, WorkerFailedError
 from .ops.codecs import Codec, IdentityCodec, get_codec
+from .parallel.mesh import default_devices
 from .ps import init_ps_core
 from .utils.bytes import bytes_of
 
@@ -144,7 +145,6 @@ class AsyncPS:
         from .utils.timing import RankLatency
 
         self.optim = optim
-        self.code = get_codec(code)
         # Robust aggregation (ops.robust): how a fill's contributions
         # combine.  "mean" is the legacy staleness-weighted sum (renormed
         # to the fill target under quorum short-fills); the others are the
@@ -324,8 +324,11 @@ class AsyncPS:
             "fused_sync_encodes": 0}
 
         if devices is None:
-            devices = jax.devices()
+            devices = default_devices()
         self.ps_device = devices[0]
+        # Codec kernels follow the devices this PS was given (Mosaic on
+        # TPUs, the jnp reference on CPU devices), not the default backend.
+        self.code = get_codec(code, self.ps_device.platform)
         if len(devices) == 1:
             self.worker_devices = [devices[0]]
         else:
@@ -505,9 +508,9 @@ class AsyncPS:
             # Pre-warm NOW, on the compile path: the first quarantined
             # submission otherwise triggers this program's first compile
             # in the middle of the fill loop, concurrent with worker
-            # dispatch — observed to wedge the pinned 0.4.x CPU runtime
-            # when workers share the process (threaded test/evidence
-            # fleets).  One dummy call costs milliseconds here and makes
+            # dispatch — observed to stall the fill when workers share
+            # the process (threaded test/evidence fleets).  One dummy
+            # call costs milliseconds here and makes
             # the serve-loop call a pure cache hit.
             dummy = OrderedDict(
                 (n, jax.tree.map(np.asarray,
@@ -756,8 +759,8 @@ class AsyncPS:
                 # submissions so recovery stays observable (reversible,
                 # like transport eviction).  The probe is an intentional
                 # host sync of a jitted program prewarmed in
-                # `compile_step` — compiling it mid-fill wedged the
-                # pinned 0.4.x CPU runtime under threaded fleets.
+                # `compile_step` — a compile landing mid-fill stalls
+                # threaded fleets.
                 self._bump("quarantined_drops")
                 self._scoreboard.observe(rank, float(self._norm_fn(codes)))
                 if on_consumed is not None:

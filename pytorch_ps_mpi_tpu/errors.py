@@ -133,6 +133,21 @@ class NativeToolchainError(PSRuntimeError):
     encoder reported a hard error."""
 
 
+class NoAcceleratorError(PSRuntimeError):
+    """JAX fell back to the CPU although nobody asked for it: no
+    accelerator answered and ``JAX_PLATFORMS`` / ``jax_platforms`` does not
+    name ``cpu``.  Training on the host by accident looks healthy and
+    measures nothing — run on the CPU only by saying so
+    (``JAX_PLATFORMS=cpu``, ``train.py --force-cpu-devices N``)."""
+
+
+class KernelPlatformError(PSRuntimeError):
+    """A Pallas kernel's Mosaic (TPU) lowering was requested for devices
+    that are not TPUs.  Off the chip the interpreter and the ``jnp``
+    reference are reached by name only (``impl="interpret"`` /
+    ``impl="ref"``), never as a silent substitute."""
+
+
 class TorchUnavailableError(PSRuntimeError):
     """A torch-interop entry point was called but torch is not
     installed."""

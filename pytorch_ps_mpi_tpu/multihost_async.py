@@ -2317,6 +2317,26 @@ class AsyncPSServer(AsyncPS):
             with self._stats_lock:
                 self._last_drop = exc
 
+    def join(self, timeout: float = 10.0) -> None:
+        """Once `serve` has returned: wait until nothing this server
+        started still runs — the connection handlers (each answers its
+        peer's next PULL with DONE and ends when the peer hangs up) and
+        the decode pool.  Terminal, like `close`, which follows it.  A
+        role that returns to interpreter exit while a handler is inside a
+        native or JAX call aborts the process (rc 134) AFTER the work is
+        done; the CLI roles join before they return.  Separate from
+        `close` because the fleet supervisor closes a dead shard mid-run
+        and must not wait on its peers."""
+        deadline = time.monotonic() + timeout
+        for t in list(self._conn_threads):
+            t.join(timeout=max(0.0, deadline - time.monotonic()))
+        self._decode_pool.shutdown(wait=True)
+        alive = sum(t.is_alive() for t in self._conn_threads)
+        if alive:
+            print(f"async PS warning: {alive} connection handler(s) "
+                  f"still running {timeout:.0f}s after serve() ended",
+                  file=sys.stderr)
+
 
 class AsyncSGDServer(AsyncPSServer):
     def __init__(self, named_params, **kw):
@@ -2373,7 +2393,7 @@ class AsyncPSWorker:
                  bucket_bytes: "int | None" = None,
                  fused_encode: bool = False):
         from .ops.codecs import get_codec
-        import jax
+        from .parallel.mesh import default_devices
 
         # Bucket-streamed gradient production (v11): None = whole-tree
         # pushes (the legacy path, still the degenerate (0, 1) frame);
@@ -2396,8 +2416,13 @@ class AsyncPSWorker:
         self.bucket_bytes = bucket_bytes
         self.fused_encode = bool(fused_encode)
         self._bucket_plan = None
-        self.code = get_codec(code)
-        self.device = device if device is not None else jax.devices()[0]
+        # Device 0 of what THIS PROCESS was given: on a TPU host every
+        # worker process is started with its own chip (the launcher sets
+        # TPU_VISIBLE_CHIPS etc. before the child imports jax — README
+        # "Running on the chip"), so this is never a shared chip 0.
+        self.device = (device if device is not None
+                       else default_devices()[0])
+        self.code = get_codec(code, self.device.platform)
         self.wire_level = wire_level
         self.token = token or None  # "" must behave exactly like unset
         self.host, self.port = host, port

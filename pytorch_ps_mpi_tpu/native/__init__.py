@@ -9,11 +9,17 @@ C++: `src/ps_serial.cpp`, built lazily with g++ into ``_lib/`` and loaded with
 ctypes (no pybind11 in this image; the C ABI + ctypes keeps the binding
 zero-dependency).  Buffer pointers from numpy arrays pass straight through —
 the zero-copy design `/root/reference/serialization.py` was reaching for.
+
+The library file is named after a hash of the sources and the compile
+command (`build_id`), so a binary built from another tree — ``_lib/`` is
+git-ignored and travels with a copied directory — is never loaded: a
+changed source byte is a new name, and a missing name is a build.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -22,31 +28,48 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRCS = [os.path.join(_DIR, "src", f)
          for f in ("ps_serial.cpp", "ps_loader.cpp")]
 _LIBDIR = os.path.join(_DIR, "_lib")
-_LIB = os.path.join(_LIBDIR, "libps_native.so")
+_CXX = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread"]
 
 _lib_handle = None
 
 
+def build_id() -> str:
+    """12 hex digits over the source bytes and the compile command."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(b"\0" + os.path.basename(src).encode() + b"\0")
+            h.update(f.read())
+    return h.hexdigest()[:12]
+
+
+def lib_path() -> str:
+    return os.path.join(_LIBDIR, f"libps_native-{build_id()}.so")
+
+
 def _build() -> str:
-    """Compile the shared library if missing or stale (atomic rename so
-    concurrent importers race safely)."""
+    """Compile the shared library for the current sources if it is not
+    there yet (atomic rename so concurrent importers race safely)."""
+    out = lib_path()
+    if os.path.exists(out):
+        return out
     os.makedirs(_LIBDIR, exist_ok=True)
-    src_mtime = max(os.path.getmtime(s) for s in _SRCS)
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= src_mtime:
-        return _LIB
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_LIBDIR)
     os.close(fd)
-    cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
-           "-o", tmp, *_SRCS]
+    cmd = [*_CXX, "-o", tmp, *_SRCS]
+    from ..errors import NativeToolchainError
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except FileNotFoundError as e:
+        os.unlink(tmp)
+        raise NativeToolchainError(
+            f"native build needs g++ on PATH: {' '.join(cmd)}") from e
     except subprocess.CalledProcessError as e:  # pragma: no cover
         os.unlink(tmp)
-        from ..errors import NativeToolchainError
         raise NativeToolchainError(
             f"native build failed: {' '.join(cmd)}\n{e.stderr}") from e
-    os.replace(tmp, _LIB)
-    return _LIB
+    os.replace(tmp, out)
+    return out
 
 
 def lib() -> ctypes.CDLL:

@@ -49,6 +49,12 @@ class Codec:
 
     name = "codec"
 
+    # Which implementation a Pallas-backed codec's kernels run
+    # (`ops.pallas_kernels.IMPLS`); None for codecs with no kernel.
+    # `get_codec` picks it from the platform of the devices the program is
+    # built for, and refuses a Mosaic kernel on anything but a TPU.
+    impl: "str | None" = None
+
     # Whether ``decode`` recovers a SINGLE contribution's gradient.  True
     # for every codec here; a sketch-style codec (FetchSGD-like count
     # sketches) whose only decodable quantity is the cross-contributor sum
@@ -123,8 +129,9 @@ class CastCodec(Codec):
     lossy, not the reduction.
     """
 
-    def __init__(self, dtype=jnp.bfloat16):
+    def __init__(self, dtype=jnp.bfloat16, impl: str = "mosaic"):
         self.wire_dtype = jnp.dtype(dtype)
+        self.impl = impl
         # Name tracks the wire dtype: the multihost handshake compares
         # codec names, and a float16 CastCodec must not pass as bf16.
         self.name = self.wire_dtype.name.replace("bfloat", "bf").replace(
@@ -157,7 +164,7 @@ class CastCodec(Codec):
         flat = codes.reshape(world, -1)
         padded = jnp.zeros((world, total), flat.dtype).at[:, :n].set(flat)
         out = pk.cast_sum(padded.reshape(world, n_blocks * rows, pk.LANE),
-                          block_rows=rows)
+                          block_rows=rows, impl=self.impl)
         dt = jnp.float32 if dtype is None else dtype
         return out.reshape(-1)[:n].reshape(shape).astype(dt)
 
@@ -317,16 +324,20 @@ class BlockQuantizeCodec(Codec):
     tile (`ops.pallas_kernels.block_quantize`).  ``decode_sum`` fuses
     dequantize with the cross-rank sum (`block_dequant_sum`), the decode-loop-
     then-sum of the reference (`/root/reference/ps.py:165-176`) as a single
-    kernel sweep.  Off-TPU the same math runs as fused jnp (parity-tested).
+    kernel sweep.  ``impl="ref"`` runs the same math as fused jnp (the CPU
+    mesh; parity-tested), ``impl="interpret"`` the kernels under the Pallas
+    interpreter.
     """
 
     name = "blockq"
 
-    def __init__(self, bits: int = 8, block_rows: int | None = None):
+    def __init__(self, bits: int = 8, block_rows: int | None = None,
+                 impl: str = "mosaic"):
         from . import pallas_kernels as pk
         if bits not in (8, 16):
             raise ValueError("bits must be 8 or 16")
         self.bits = bits
+        self.impl = impl
         self.block_rows = block_rows if block_rows is not None else pk.BLOCK_ROWS
 
     def _rows_for(self, n: int) -> int:
@@ -343,7 +354,8 @@ class BlockQuantizeCodec(Codec):
         n = grad.size
         rows = self._rows_for(n)
         x2d, _ = pk.pad_to_blocks(grad.reshape(-1), rows)
-        q, scales = pk.block_quantize(x2d, bits=self.bits, block_rows=rows)
+        q, scales = pk.block_quantize(x2d, bits=self.bits, block_rows=rows,
+                                      impl=self.impl)
         return {"q": q, "scales": scales}
 
     def decode(self, code, *, shape=None, dtype=None):
@@ -356,7 +368,8 @@ class BlockQuantizeCodec(Codec):
         from . import pallas_kernels as pk
         n = int(np.prod(shape))
         out2d = pk.block_dequant_sum(codes["q"], codes["scales"],
-                                     block_rows=self._rows_for(n))
+                                     block_rows=self._rows_for(n),
+                                     impl=self.impl)
         dtype = jnp.float32 if dtype is None else dtype
         return out2d.reshape(-1)[:n].reshape(shape).astype(dtype)
 
@@ -369,10 +382,22 @@ class BlockQuantizeCodec(Codec):
         return n_blocks * per_block * (self.bits // 8) + n_blocks * 4
 
 
-def get_codec(spec) -> Codec:
-    """Resolve a codec from an instance or a name string."""
-    if isinstance(spec, Codec) or spec is None:
-        return spec if spec is not None else IdentityCodec()
+def get_codec(spec, platform: str) -> Codec:
+    """Resolve a codec from an instance or a name string, for a program
+    built on ``platform`` devices.  A name gets the kernel implementation
+    that platform runs (`pallas_kernels.impl_for_platform`: Mosaic on TPUs,
+    the jnp reference on the CPU mesh); an instance keeps the ``impl`` it
+    was constructed with and is refused (`KernelPlatformError`) when that
+    is the Mosaic kernel and the devices are not TPUs."""
+    # pallas_kernels (and with it the Pallas/Mosaic import, about a second)
+    # is loaded only for the codecs that have a kernel.
+    if spec is None:
+        return IdentityCodec()
+    if isinstance(spec, Codec):
+        if spec.impl is not None:
+            from .pallas_kernels import check_impl
+            check_impl(spec.impl, platform)
+        return spec
     table = {"identity": IdentityCodec, "bf16": CastCodec,
              "topk": TopKCodec,
              "topk_approx": lambda: TopKCodec(approx=True),
@@ -380,6 +405,9 @@ def get_codec(spec) -> Codec:
              "sign": SignCodec, "blockq": BlockQuantizeCodec}
     if spec not in table:
         raise ValueError(f"unknown codec {spec!r}; have {sorted(table)}")
+    if spec in ("bf16", "blockq"):
+        from .pallas_kernels import impl_for_platform
+        return table[spec](impl=impl_for_platform(platform))
     return table[spec]()
 
 

@@ -25,9 +25,10 @@ Composition: `flash_attention` is a drop-in for
 via ``attn=`` — and combines with ring attention by serving as the local
 block math while ppermute hops cover the sequence axis.
 
-Off-TPU the kernel runs under the Pallas interpreter (bit-faithful to the
-kernel logic, just slow), keeping the CPU test mesh honest; `dense_attention`
-remains the oracle in tests.
+The kernels compile through Mosaic for the TPU.  ``impl="interpret"`` runs
+the same kernel bodies under the Pallas interpreter (bit-faithful to the
+kernel logic, just slow) — by name only, for the CPU test mesh;
+`dense_attention` remains the oracle in tests.
 """
 
 from __future__ import annotations
@@ -39,11 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .pallas_kernels import HAVE_PALLAS, on_tpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-if HAVE_PALLAS:  # pragma: no branch - pallas ships with jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .pallas_kernels import use_interpreter
 
 BLOCK_Q = 512    # q tile rows per grid step (VMEM acc: BLOCK_Q x D f32)
 BLOCK_K = 1024   # k/v tile rows per grid step (scores: BLOCK_Q x BLOCK_K)
@@ -131,7 +131,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
-def _fwd_call(q3, k3, v3, *, causal, scale, true_len,
+def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
               blk_q=None, blk_k=None):
     """``q3,k3,v3: [BH, S_pad, D_pad]`` already padded to BLOCK/lane tiles;
     returns ``(out [BH, S_pad, D_pad], lse [BH, S_pad])``.  ``true_len``
@@ -174,7 +174,7 @@ def _fwd_call(q3, k3, v3, *, causal, scale, true_len,
             pltpu.VMEM((blk_q, BLOCK), jnp.float32),  # m (lane-replicated)
             pltpu.VMEM((blk_q, BLOCK), jnp.float32),  # l
         ],
-        interpret=not on_tpu(),
+        interpret=interpret,
     )(q3, k3, v3)
     return out, lse
 
@@ -190,27 +190,26 @@ def _from_bh(x3, b, h):
     return x3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, scale):
-    out, _ = _flash_fwd_res(q, k, v, causal, scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, scale, interpret):
+    out, _ = _flash_fwd_res(q, k, v, causal, scale, interpret)
     return out
 
 
-def _flash_fwd_res(q, k, v, causal, scale):
+def _flash_fwd_res(q, k, v, causal, scale, interpret):
     b, s, h, d = q.shape
     q3 = _pad_to(_pad_to(_to_bh(q), BLOCK, 1), BLOCK, 2)
     k3 = _pad_to(_pad_to(_to_bh(k), BLOCK, 1), BLOCK, 2)
     v3 = _pad_to(_pad_to(_to_bh(v), BLOCK, 1), BLOCK, 2)
     out3, lse3 = _fwd_call(q3, k3, v3, causal=causal, scale=scale,
-                           true_len=s)
+                           true_len=s, interpret=interpret)
     out = _from_bh(out3[:, :s, :d], b, h)
     lse = lse3[:, :s, 0].reshape(b, h, s)
     return out, (q, k, v, out, lse)
 
 
-def _flash_fwd_vjp(q, k, v, causal, scale):
-    out, res = _flash_fwd_res(q, k, v, causal, scale)
-    return out, res
+def _flash_fwd_vjp(q, k, v, causal, scale, interpret):
+    return _flash_fwd_res(q, k, v, causal, scale, interpret)
 
 
 def _bwd_probs(q, k, do, v, lse_col, delta_col, *, scale, causal, seq_len,
@@ -293,7 +292,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
-              blk_q=None, blk_k=None):
+              interpret, blk_q=None, blk_k=None):
     """``q3,k3,v3,do3: [BH, S_pad, D_pad]``; ``lse2, delta2:
     [BH, S_pad, BLOCK]`` f32, lane-replicated (same MIN_BLOCK_SIZE trick as
     the forward's lse output — Mosaic wants (8k, 128k) tiles, the kernels
@@ -338,7 +337,7 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
             pltpu.VMEM((blk_k, d), jnp.float32),
             pltpu.VMEM((blk_k, d), jnp.float32),
         ],
-        interpret=not on_tpu(),
+        interpret=interpret,
     )(q3, k3, v3, do3, lse2, delta2)
 
     dq3 = pl.pallas_call(
@@ -355,12 +354,12 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
         out_specs=pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n_q * blk_q, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        interpret=not on_tpu(),
+        interpret=interpret,
     )(q3, k3, v3, do3, lse2, delta2)
     return dq3[:, :s_pad], dk3[:, :s_pad], dv3[:, :s_pad]
 
 
-def _flash_bwd(causal, scale, res, dout):
+def _flash_bwd(causal, scale, interpret, res, dout):
     """Pallas blockwise backward from the saved logsumexp (FlashAttention-2
     style: a dk/dv kernel sweeping q tiles, a dq kernel sweeping k tiles);
     every live intermediate is one (blk_q, blk_k) tile in VMEM."""
@@ -377,7 +376,8 @@ def _flash_bwd(causal, scale, res, dout):
                    constant_values=NEG_INF).astype(jnp.float32)
     rep = lambda x2: jnp.broadcast_to(x2[..., None], x2.shape + (BLOCK,))
     dq3, dk3, dv3 = _bwd_call(q3, k3, v3, do3, rep(lse2), rep(delta2),
-                              causal=causal, scale=scale, true_len=s)
+                              causal=causal, scale=scale, true_len=s,
+                              interpret=interpret)
     back = lambda x3: _from_bh(x3[:, :s, :d], b, h).astype(q.dtype)
     return back(dq3), back(dk3), back(dv3)
 
@@ -386,16 +386,14 @@ _flash.defvjp(_flash_fwd_vjp, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
-                    scale: float | None = None):
+                    scale: float | None = None, impl: str = "mosaic"):
     """Exact attention, O(S·BLOCK) memory.  ``q,k,v: [B, S, H, D]`` →
     ``[B, S, H, D]`` — drop-in for `ring_attention.dense_attention`
     (`/root/reference` has no attention at all; this is the long-context
-    hot-op layer of the TPU framework)."""
+    hot-op layer of the TPU framework).  ``impl="interpret"`` runs the
+    kernels under the Pallas interpreter (the CPU mesh, by name); the
+    default lowers through Mosaic and fails to compile anywhere but on a
+    TPU."""
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
-    if not HAVE_PALLAS:  # pragma: no cover - pallas ships with jax
-        # Same convention as ops.pallas_kernels: degrade to the jnp math
-        # rather than NameError deep inside the kernel call.
-        from ..parallel.ring_attention import dense_attention
-        return dense_attention(q, k, v, causal=causal, scale=scale)
-    return _flash(q, k, v, causal, scale)
+    return _flash(q, k, v, causal, scale, use_interpreter(impl))

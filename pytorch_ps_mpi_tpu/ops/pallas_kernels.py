@@ -17,9 +17,13 @@ transform; these kernels are the custom-op layer for it:
   in one pass; the world loop rides the sequential TPU grid with an
   f32 VMEM accumulator.
 
-Both have jnp fallbacks (identical math) used automatically off-TPU, so the
-same codec runs under the CPU test mesh; ``tests/test_pallas_kernels.py``
-asserts kernel == fallback.
+Each kernel has a ``jnp`` reference with identical math (``*_ref``) and can
+run under the Pallas interpreter.  Neither is ever chosen silently: the
+dispatchers take ``impl`` — ``"mosaic"`` (the compiled TPU kernel, the
+default), ``"interpret"`` or ``"ref"`` — and whoever owns the devices picks
+it from their platform (`impl_for_platform`; `ops.codecs.get_codec` does so
+for the optimizers).  ``tests/test_pallas_kernels.py`` asserts kernel ==
+reference on the CPU; ``chip_smoke.py`` asserts it on the chip.
 
 Layout contract: gradients of any rank/shape are flattened and zero-padded to
 ``(rows, 128)`` with ``rows`` a multiple of the sublane tile — zero padding is
@@ -34,21 +38,52 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # pallas is TPU/Mosaic; import is cheap and safe everywhere
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover - pallas ships with jax
-    HAVE_PALLAS = False
+from ..errors import KernelPlatformError
 
 LANE = 128
 # Rows per kernel tile: 512*128 f32 = 256 KB in VMEM, comfortable double-buffer.
 BLOCK_ROWS = 512
 
+IMPLS = ("mosaic", "interpret", "ref")
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+
+def impl_for_platform(platform: str, *, cpu: str = "ref") -> str:
+    """The kernel implementation for a program built on ``platform``
+    devices (``mesh.devices.flat[0].platform``, never the process-global
+    default backend): the Mosaic kernel on TPUs; on the CPU — the virtual
+    test mesh — the implementation the caller names with ``cpu=`` (the
+    ``jnp`` reference for the codec kernels, ``"interpret"`` where no
+    reference exists).  Any other platform has no implementation here."""
+    if platform == "tpu":
+        return "mosaic"
+    if platform == "cpu":
+        return cpu
+    raise KernelPlatformError(
+        f"no Pallas kernel implementation for platform {platform!r} "
+        f"(have tpu, and cpu by name)")
+
+
+def check_impl(impl: str, platform: str) -> None:
+    """Refuse a Mosaic kernel on devices that cannot run it (typed, at
+    construction — before Pallas's own lowering error deep in a compile)."""
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+    if impl == "mosaic" and platform != "tpu":
+        raise KernelPlatformError(
+            f"impl='mosaic' needs TPU devices, but this program is built "
+            f"for {platform!r}; name impl='ref' or impl='interpret' to run "
+            f"off the chip")
+
+
+def use_interpreter(impl: str) -> bool:
+    """``interpret=`` for a `pallas_call` under ``impl`` (not ``"ref"``)."""
+    if impl not in ("mosaic", "interpret"):
+        raise ValueError(
+            f"impl must be 'mosaic' or 'interpret' here, got {impl!r}")
+    return impl == "interpret"
 
 
 def _qmax(bits: int) -> float:
@@ -94,10 +129,7 @@ def block_quantize_tpu(x2d: jax.Array, *, bits: int = 8,
     """Pallas path: ``x2d`` is ``(n_blocks*block_rows, LANE)`` f32-ish.
 
     ``interpret=True`` runs the same kernel under the Pallas interpreter
-    — the CPU parity path for the fused per-bucket encode
-    (`parallel.overlap.make_async_bucket_step`): the encode half of the
-    kernel pair whose decode half (`cast_sum`) already carries the same
-    escape hatch."""
+    (CPU parity tests)."""
     n_blocks = x2d.shape[0] // block_rows
     qdtype = jnp.int8 if bits == 8 else jnp.int16
     kernel = functools.partial(_quantize_kernel, qmax=_qmax(bits))
@@ -121,7 +153,7 @@ def block_quantize_tpu(x2d: jax.Array, *, bits: int = 8,
 
 def block_quantize_ref(x2d: jax.Array, *, bits: int = 8,
                        block_rows: int = BLOCK_ROWS):
-    """jnp fallback with identical math (used off-TPU and in parity tests)."""
+    """jnp reference with identical math (``impl="ref"``, parity tests)."""
     qmax = _qmax(bits)
     qdtype = jnp.int8 if bits == 8 else jnp.int16
     n_blocks = x2d.shape[0] // block_rows
@@ -132,9 +164,11 @@ def block_quantize_ref(x2d: jax.Array, *, bits: int = 8,
     return q.reshape(x2d.shape), scales.astype(jnp.float32)
 
 
-def block_quantize(x2d, *, bits=8, block_rows=BLOCK_ROWS):
-    fn = block_quantize_tpu if (HAVE_PALLAS and on_tpu()) else block_quantize_ref
-    return fn(x2d, bits=bits, block_rows=block_rows)
+def block_quantize(x2d, *, bits=8, block_rows=BLOCK_ROWS, impl="mosaic"):
+    if impl == "ref":
+        return block_quantize_ref(x2d, bits=bits, block_rows=block_rows)
+    return block_quantize_tpu(x2d, bits=bits, block_rows=block_rows,
+                              interpret=use_interpreter(impl))
 
 
 # ---------------------------------------------------------------------------
@@ -158,9 +192,10 @@ def _dequant_sum_kernel(q_ref, scale_ref, out_ref):
         out_ref[:] += x
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows",))
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def block_dequant_sum_tpu(q: jax.Array, scales: jax.Array, *,
-                          block_rows: int = BLOCK_ROWS):
+                          block_rows: int = BLOCK_ROWS,
+                          interpret: bool = False):
     """``q``: (world, rows, LANE) int8/int16; ``scales``: (world, n_blocks, 1).
 
     Returns f32 ``(rows, LANE)`` = sum over the world dim of q*scale.
@@ -177,6 +212,7 @@ def block_dequant_sum_tpu(q: jax.Array, scales: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_rows, LANE), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+        interpret=interpret,
     )(q, scales)
     return out
 
@@ -189,10 +225,11 @@ def block_dequant_sum_ref(q, scales, *, block_rows: int = BLOCK_ROWS):
     return deq.sum(axis=0).reshape(rows, LANE)
 
 
-def block_dequant_sum(q, scales, *, block_rows=BLOCK_ROWS):
-    fn = (block_dequant_sum_tpu if (HAVE_PALLAS and on_tpu())
-          else block_dequant_sum_ref)
-    return fn(q, scales, block_rows=block_rows)
+def block_dequant_sum(q, scales, *, block_rows=BLOCK_ROWS, impl="mosaic"):
+    if impl == "ref":
+        return block_dequant_sum_ref(q, scales, block_rows=block_rows)
+    return block_dequant_sum_tpu(q, scales, block_rows=block_rows,
+                                 interpret=use_interpreter(impl))
 
 
 # ---------------------------------------------------------------------------
@@ -246,13 +283,15 @@ def cast_sum_tpu(x: jax.Array, *, block_rows: int = BLOCK_ROWS,
 
 
 def cast_sum_ref(x, *, block_rows: int = BLOCK_ROWS):
-    """jnp fallback with identical math (used off-TPU and in parity tests)."""
+    """jnp reference with identical math (``impl="ref"``, parity tests)."""
     return x.astype(jnp.float32).sum(axis=0)
 
 
-def cast_sum(x, *, block_rows=BLOCK_ROWS):
-    fn = cast_sum_tpu if (HAVE_PALLAS and on_tpu()) else cast_sum_ref
-    return fn(x, block_rows=block_rows)
+def cast_sum(x, *, block_rows=BLOCK_ROWS, impl="mosaic"):
+    if impl == "ref":
+        return cast_sum_ref(x, block_rows=block_rows)
+    return cast_sum_tpu(x, block_rows=block_rows,
+                        interpret=use_interpreter(impl))
 
 
 def rows_for_flat(n: int, block_rows: int = BLOCK_ROWS) -> int:

@@ -15,8 +15,28 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..errors import NoAcceleratorError
+
 # Canonical axis name for the data-parallel PS "world" axis.
 PS_AXIS = "ps"
+
+
+def default_devices() -> list:
+    """``jax.devices()``, minus JAX's silent fall-back to the host: when no
+    accelerator answers, JAX logs an error and hands back one CPU device,
+    and a run built on it trains, prints a loss and measures nothing.  The
+    CPU is a valid world only when named (``JAX_PLATFORMS=cpu``, or
+    ``jax.config.update("jax_platforms", "cpu")`` — what the tests and
+    ``train.py --force-cpu-devices`` do); otherwise a missing chip is a
+    `NoAcceleratorError`."""
+    devices = jax.devices()
+    named = (jax.config.jax_platforms or "").split(",")
+    if devices[0].platform == "cpu" and "cpu" not in named:
+        raise NoAcceleratorError(
+            "JAX found no accelerator and fell back to the CPU; set "
+            "JAX_PLATFORMS=cpu (or pass --force-cpu-devices N to train.py) "
+            "to run on the host on purpose")
+    return devices
 
 
 def make_ps_mesh(n_devices: int | None = None, *, axis: str = PS_AXIS,
@@ -28,7 +48,7 @@ def make_ps_mesh(n_devices: int | None = None, *, axis: str = PS_AXIS,
     all visible devices.
     """
     if devices is None:
-        devices = jax.devices()
+        devices = default_devices()
     if n_devices is None:
         n_devices = len(devices)
     if n_devices > len(devices):
@@ -42,7 +62,7 @@ def _make_dp_x_mesh(axis2: str, dp: int | None, k: int, devices) -> Mesh:
     inner degree, default ``dp`` to whatever fills the device set, and
     range-check the product."""
     if devices is None:
-        devices = jax.devices()
+        devices = default_devices()
     if k < 1:
         raise ValueError(f"{axis2} must be >= 1, got {k}")
     if dp is None:
@@ -108,7 +128,7 @@ def make_dp_sp_tp_mesh(dp: int, sp: int, tp: int, *, devices=None) -> Mesh:
     composed.  Batch shards over (ps, sp); heads/MLP compute shards over tp;
     gradient sum over ps, mean over sp and tp."""
     if devices is None:
-        devices = jax.devices()
+        devices = default_devices()
     n = dp * sp * tp
     if n > len(devices) or min(dp, sp, tp) < 1:
         raise ValueError(
@@ -122,7 +142,7 @@ def make_dp_pp_tp_mesh(dp: int, pp: int, tp: int, *, devices=None) -> Mesh:
     """3-D ``(ps, pp, tp)`` mesh: data × pipeline × tensor parallelism.
     Batch shards over ps; depth over the pp ring; heads/MLP over tp."""
     if devices is None:
-        devices = jax.devices()
+        devices = default_devices()
     n = dp * pp * tp
     if n > len(devices) or min(dp, pp, tp) < 1:
         raise ValueError(
@@ -149,7 +169,7 @@ def make_hybrid_mesh(slices: int | None = None, *, axis: str = PS_AXIS,
     `distributed_init` where ``jax.devices()`` spans processes.
     """
     if devices is None:
-        devices = jax.devices()
+        devices = default_devices()
     if slices is None:
         slices = max(1, jax.process_count())
     n = len(devices)

@@ -251,8 +251,23 @@ def _sync_codec(cot: "OrderedDict", axis, codec):
         for n in cot)
 
 
-def _sync_blockq_fused(cot: "OrderedDict", axis, codec,
-                       interpret: bool = False):
+def _blockq_bucket_encode(cot: "OrderedDict", codec):
+    """The encode half of `_sync_blockq_fused`: the bucket's cotangents as
+    ONE flat payload through ONE quantize sweep.  Returns ``(q, scales,
+    rows)``; split out so the parity tests (CPU and chip) can compare the
+    codes themselves bit for bit."""
+    from ..ops import pallas_kernels as pk
+
+    flat = (jnp.concatenate([g.reshape(-1) for g in cot.values()])
+            if len(cot) > 1 else next(iter(cot.values())).reshape(-1))
+    rows = codec._rows_for(flat.size)
+    x2d, _ = pk.pad_to_blocks(flat, rows)
+    q, scales = pk.block_quantize(x2d, bits=codec.bits, block_rows=rows,
+                                  impl=codec.impl)
+    return q, scales, rows
+
+
+def _sync_blockq_fused(cot: "OrderedDict", axis, codec):
     """The FUSED bucket exchange for the block-quantize codec (ISSUE 16,
     the sync-path MFU residual): ONE concat → ONE Pallas quantize sweep
     over the whole bucket, vs `_sync_codec`'s one kernel launch plus
@@ -262,42 +277,30 @@ def _sync_blockq_fused(cot: "OrderedDict", axis, codec,
     run bucket k's encode under bucket k-1's remaining backward FLOPs,
     and the gather moves exactly the bucket's wire bytes (q + scales)
     instead of per-leaf padded tiles.  Parity contract
-    (``tests/test_overlap.py``): bitwise-identical to the same math run
-    as separate host-boundary programs, and to `block_quantize_ref`
-    under ``interpret=True`` (the Pallas-interpreter escape hatch the
-    async fused encode already carries)."""
+    (``tests/test_overlap.py``): the same codes, bit for bit, as the same
+    math run as separate host-boundary programs under every
+    ``codec.impl``, and the same f32 sum to within the FMA contraction
+    XLA may apply inside one program and not another."""
     from ..ops import pallas_kernels as pk
 
-    names = list(cot)
-    flat = (jnp.concatenate([cot[n].reshape(-1) for n in names])
-            if len(names) > 1 else cot[names[0]].reshape(-1))
-    rows = codec._rows_for(flat.size)
-    x2d, _ = pk.pad_to_blocks(flat, rows)
-    if interpret:
-        q, scales = pk.block_quantize_tpu(x2d, bits=codec.bits,
-                                          block_rows=rows, interpret=True)
-    else:
-        q, scales = pk.block_quantize(x2d, bits=codec.bits,
-                                      block_rows=rows)
+    q, scales, rows = _blockq_bucket_encode(cot, codec)
     gathered = collectives.allgather_tree_bucketed(
         {"q": q, "scales": scales}, axis, bucket_bytes=1 << 62)
     out2d = pk.block_dequant_sum(gathered["q"], gathered["scales"],
-                                 block_rows=rows)
-    summed = out2d.reshape(-1)[:flat.size]
+                                 block_rows=rows, impl=codec.impl)
+    summed = out2d.reshape(-1)
     out = OrderedDict()
     off = 0
-    for n in names:
-        sz = cot[n].size
-        out[n] = (summed[off:off + sz].reshape(cot[n].shape)
-                  .astype(cot[n].dtype))
-        off += sz
+    for n, g in cot.items():
+        out[n] = (summed[off:off + g.size].reshape(g.shape)
+                  .astype(g.dtype))
+        off += g.size
     return out
 
 
 def make_bucket_sync_fn(*, axis, world: int, codec=None,
                         reducer: str = "rs_ag",
-                        fused_encode: bool = False,
-                        interpret: bool = False) -> Callable:
+                        fused_encode: bool = False) -> Callable:
     """The per-bucket sync closure (applied to every bucket's cotangent
     sub-tree).  ``codec=None`` (or an identity codec — the caller decides)
     uses the flat-sum reducers; otherwise each bucket rides the codec's
@@ -308,8 +311,7 @@ def make_bucket_sync_fn(*, axis, world: int, codec=None,
     is definitionally bitwise-equal there, and the block-quantize codec
     gets `_sync_blockq_fused` (one quantize sweep per bucket).  Other
     codecs refuse loudly — a knob that silently fell back to the
-    per-leaf path would claim a fusion it never ran.  ``interpret=True``
-    routes the quantize through the Pallas interpreter (parity tests)."""
+    per-leaf path would claim a fusion it never ran."""
     if reducer not in ("rs_ag", "psum"):
         raise ValueError(f"unknown overlap reducer {reducer!r}; "
                          "have ('rs_ag', 'psum')")
@@ -326,8 +328,7 @@ def make_bucket_sync_fn(*, axis, world: int, codec=None,
             f"fused_encode supports the identity and blockq codecs; "
             f"got {type(codec).__name__} — run it unfused, or switch "
             f"the sync codec to 'blockq'")
-    return lambda cot: _sync_blockq_fused(cot, axis, codec,
-                                          interpret=interpret)
+    return lambda cot: _sync_blockq_fused(cot, axis, codec)
 
 
 def attach(params: "OrderedDict", plan: OverlapPlan,

@@ -193,8 +193,10 @@ class MPI_PS:
                  **hyper):
         del use_mpi, cuda, names  # accepted for API parity; meaningless on TPU
         self.optim = optim
-        self.code = get_codec(code)
         self.mesh = mesh if mesh is not None else make_ps_mesh()
+        # The codec's kernels follow the mesh's devices, not the process's
+        # default backend: Mosaic on TPUs, the jnp reference on the CPU mesh.
+        self.code = get_codec(code, self.mesh.devices.flat[0].platform)
         # ``axis`` may name several mesh axes that are all data-parallel —
         # e.g. ('dcn', 'ps') on a multi-slice hybrid mesh, where the inner
         # axis rides ICI and the outer rides DCN.  Collectives take the
@@ -440,24 +442,6 @@ class MPI_PS:
         self._phase_fns = None
         self._loss_fn = None
         self._warm = False
-
-    def _donate(self, *argnums: int) -> tuple:
-        """``donate_argnums`` for the CURRENT ``self.mesh`` backend.
-
-        Buffer donation (in-place parameter/state updates — halves the
-        step's HBM write traffic) is gated per platform: the pinned 0.4.x
-        CPU runtime mis-executes input-output aliasing under shard_map
-        (wrong numerics, and segfaults on executables reloaded from the
-        persistent compilation cache — reproduced in tests/test_zero.py),
-        so on the cpu platform every donate list resolves to ().  Host RAM
-        has no HBM-copy cost to save, so the virtual test mesh loses
-        nothing; accelerator backends keep full donation.  Resolved at
-        step-BUILD time, not construction: the AOT evidence path
-        constructs on a CPU mesh and rebinds ``self.mesh`` to a TPU
-        topology before lowering, and must compile the donating program a
-        real TPU run would execute."""
-        cpu = self.mesh.devices.flat[0].platform == "cpu"
-        return () if cpu else argnums
 
     # -- ZeRO state layout ----------------------------------------------------
 
@@ -742,20 +726,19 @@ class MPI_PS:
         # parameters in place — without it every step writes a second full
         # copy of the model + optimizer state to HBM before the old one is
         # freed.  Safe because step() replaces self.params/state/aux with
-        # the outputs.  Gated by `_donate` (off on the cpu backend, whose
-        # runtime mis-executes input-output aliasing — see __init__).
+        # the outputs.
         if self.extras:
             extras_specs = self._extras_specs()
             spmd_step = core
             in_specs = (P(), state_specs, P(), self.batch_spec, extras_specs)
             out_specs = (P(), state_specs, P(), P(), P(), extras_specs)
-            donate = self._donate(0, 1, 2, 4)
+            donate = (0, 1, 2, 4)
         else:
             def spmd_step(params, state, aux, batch):
                 return core(params, state, aux, batch, OrderedDict())[:5]
             in_specs = (P(), state_specs, P(), self.batch_spec)
             out_specs = (P(), state_specs, P(), P(), P())
-            donate = self._donate(0, 1, 2)
+            donate = (0, 1, 2)
         return jax.jit(jax.shard_map(
             spmd_step, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs,
@@ -921,7 +904,7 @@ class MPI_PS:
             encode_fn = jax.jit(smap(
                 encode_body, in_specs=(P(axis), P(axis)),
                 out_specs=(P(axis), P(axis))),
-                donate_argnums=self._donate(0, 1))
+                donate_argnums=(0, 1))
         elif identity:
             encode_fn = None  # nothing to encode; sync consumes raw grads
         else:
@@ -931,7 +914,7 @@ class MPI_PS:
                 return jax.tree.map(lambda c: c[None], codes)
             encode_fn = jax.jit(smap(
                 encode_body, in_specs=P(axis), out_specs=P(axis)),
-                donate_argnums=self._donate(0))
+                donate_argnums=(0,))
 
         sync_in = P() if overlap else P(axis)
         if self.zero:
@@ -950,7 +933,7 @@ class MPI_PS:
                 return jax.tree.map(lambda c: c[None], d_chunks)
             sync_fn = jax.jit(smap(
                 sync_body, in_specs=sync_in, out_specs=P(axis)),
-                donate_argnums=self._donate(0))
+                donate_argnums=(0,))
 
             def update_body(params, state, d_chunks):
                 d = OrderedDict(
@@ -959,7 +942,7 @@ class MPI_PS:
             update_fn = jax.jit(smap(
                 update_body, in_specs=(P(), state_specs, P(axis)),
                 out_specs=(P(), state_specs)),
-                donate_argnums=self._donate(0, 1))
+                donate_argnums=(0, 1))
         else:
             def sync_body(codes):
                 if overlap:
@@ -978,13 +961,13 @@ class MPI_PS:
                 return d_ps
             sync_fn = jax.jit(smap(
                 sync_body, in_specs=sync_in, out_specs=P()),
-                donate_argnums=self._donate(0))
+                donate_argnums=(0,))
 
             update_fn = jax.jit(smap(
                 lambda params, state, d_ps: self._apply_updates(
                     params, state, d_ps),
                 in_specs=(P(), P(), P()), out_specs=(P(), P())),
-                donate_argnums=self._donate(0, 1))
+                donate_argnums=(0, 1))
 
         ema_fn = None
         if self.ema_decay is not None:
@@ -995,7 +978,7 @@ class MPI_PS:
                                   + (1.0 - decay) * q.astype(e.dtype)),
                     ema, p),
                 in_specs=(P(), P()), out_specs=P()),
-                donate_argnums=self._donate(0))
+                donate_argnums=(0,))
 
         return {"grad": grad_fn, "encode": encode_fn, "sync": sync_fn,
                 "update": update_fn, "ema": ema_fn}

@@ -104,6 +104,7 @@ class InferenceFrontend:
         import jax
         import jax.numpy as jnp
 
+        from ..parallel.mesh import default_devices
         from ..utils.flatten import unflatten_params
 
         if max_batch < 1:
@@ -131,7 +132,10 @@ class InferenceFrontend:
         self.fault_stats: "dict[str, int]" = {
             "infer_requests": 0, "infer_shed": 0, "param_swaps": 0}
         self._stats_lock = threading.Lock()
-        self._device = device if device is not None else jax.devices()[0]
+        # Device 0 of what this process was given (one chip-holding
+        # process per chip; see AsyncPSWorker).
+        self._device = (device if device is not None
+                        else default_devices()[0])
         self._dev_params = jax.device_put(params, self._device)
         self._params_source = params_source
 

@@ -855,3 +855,9 @@ class PSFleet:
             srv.close()
         for sb in self.standbys:
             sb.close()
+
+    def join(self, timeout: float = 10.0) -> None:
+        """Once `serve` has returned: `AsyncPSServer.join` on every shard
+        and standby; `close` follows."""
+        for srv in (*self.servers, *self.standbys):
+            srv.join(timeout)

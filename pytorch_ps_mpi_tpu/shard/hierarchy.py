@@ -1202,3 +1202,9 @@ class Hierarchy:
     def close(self) -> None:
         for a in self.aggregators:
             a.close()
+
+    def join(self, timeout: float = 10.0) -> None:
+        """Once `serve` has returned: `AsyncPSServer.join` on every
+        aggregator; `close` follows."""
+        for a in self.aggregators:
+            a.join(timeout)

@@ -443,8 +443,9 @@ def main(argv=None):
                         "tokens ride all_to_all to their expert's rank")
     p.add_argument("--attn", default="dense", choices=["dense", "flash"],
                    help="transformer attention: XLA dense or the Pallas "
-                        "flash kernel (O(S*128) memory; interpreted "
-                        "off-TPU)")
+                        "flash kernel (O(S*128) memory; on a CPU run — "
+                        "JAX_PLATFORMS=cpu / --force-cpu-devices — the "
+                        "kernel runs under the Pallas interpreter)")
     p.add_argument("--sp-attn", default="ring", choices=["ring", "ulysses"],
                    help="sequence-parallel strategy for --sp: 'ring' "
                         "rotates K/V with a streaming softmax (O(S/N) "
@@ -608,6 +609,9 @@ def main(argv=None):
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.force_cpu_devices}")
         jax.config.update("jax_platforms", "cpu")
+
+    from .utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     if args.trace_dir:
         from .utils.timing import trace
@@ -1023,7 +1027,8 @@ def _dispatch(args):
 
     mesh = make_ps_mesh(args.n_devices)
     world = mesh.shape["ps"]
-    print(f"mesh: {world} x {jax.devices()[0].platform}", file=sys.stderr)
+    print(f"mesh: {world} x {mesh.devices.flat[0].platform}",
+          file=sys.stderr)
     if args.batch_size % world:
         raise SystemExit(f"--batch-size {args.batch_size} must divide by "
                          f"the {world}-device world")
@@ -1362,23 +1367,36 @@ def transformer_model(args):
                          moe_experts=args.moe_experts)
 
 
+def _flash_attention():
+    """`flash_attention` bound to the kernel implementation of the devices
+    this run trains on: the Mosaic kernel on TPUs; on a CPU run (named by
+    JAX_PLATFORMS=cpu / --force-cpu-devices) the Pallas interpreter."""
+    import functools
+
+    from .ops.flash_attention import flash_attention
+    from .ops.pallas_kernels import impl_for_platform
+    from .parallel.mesh import default_devices
+
+    impl = impl_for_platform(default_devices()[0].platform, cpu="interpret")
+    return functools.partial(flash_attention, impl=impl)
+
+
 def _build_lm_async(args):
     """(params, loss_fn, toks) for the async/multihost transformer paths.
     Each worker is one device (no sp/tp/pp sharding), but ``--attn flash``
     threads through: the worker's jitted grad+encode program runs the
-    Pallas kernel (interpret-mode off-TPU, same math)."""
+    Pallas kernel."""
     import functools
 
     from .data.datasets import synthetic_lm
     from .models.transformer import build_lm, make_lm_loss
-    from .ops.flash_attention import flash_attention
 
     dense = transformer_model(args)
     params = build_lm(dense, seq_len=args.seq_len, seed=args.seed)
     model = dense
     if args.attn == "flash":
         model = dense.copy(
-            attn=functools.partial(flash_attention, causal=True))
+            attn=functools.partial(_flash_attention(), causal=True))
     toks = synthetic_lm(max(args.n_examples, args.batch_size),
                         seq_len=args.seq_len, vocab=args.vocab,
                         seed=args.seed)
@@ -1397,8 +1415,9 @@ def run_transformer(args):
     from .data.datasets import synthetic_lm
     from .models.transformer import (TransformerLM, build_lm, lm_batch,
                                      make_lm_loss)
-    from .parallel.mesh import (make_dp_sp_mesh, make_dp_sp_tp_mesh,
-                                make_dp_tp_mesh, make_ps_mesh)
+    from .parallel.mesh import (default_devices, make_dp_sp_mesh,
+                                make_dp_sp_tp_mesh, make_dp_tp_mesh,
+                                make_ps_mesh)
     from .parallel.ring_attention import ring_attention
 
     if args.seq_len % args.sp:
@@ -1430,14 +1449,10 @@ def run_transformer(args):
                          "streaming softmax")
     flash = None
     if args.attn == "flash":
-        from .ops.flash_attention import flash_attention
-        flash = functools.partial(flash_attention, causal=True)
+        flash = functools.partial(_flash_attention(), causal=True)
     if args.sp > 1 and args.sp_attn == "ulysses":
         from .parallel.ulysses import ulysses_attention
-        inner = None
-        if flash is not None:
-            from .ops.flash_attention import flash_attention
-            inner = flash_attention
+        inner = _flash_attention() if flash is not None else None
         ring = functools.partial(ulysses_attention, axis="sp", causal=True,
                                  inner=inner)
     elif args.sp > 1:
@@ -1468,7 +1483,7 @@ def run_transformer(args):
             from .parallel.mesh import make_dp_pp_tp_mesh
 
             mesh = make_dp_pp_tp_mesh(
-                dp or len(jax.devices()) // shard, args.pp, args.tp)
+                dp or len(default_devices()) // shard, args.pp, args.tp)
         else:
             mesh = make_dp_pp_mesh(dp=dp, pp=args.pp)
         model = dense.copy(attn=ring, tp_axis=tp_axis)
@@ -1481,7 +1496,7 @@ def run_transformer(args):
         return _run_transformer_loop(args, opt, mesh, model,
                                      loss_fn=loss_fn)
     if args.sp > 1 and args.tp > 1:
-        mesh = make_dp_sp_tp_mesh(dp or len(jax.devices()) // shard,
+        mesh = make_dp_sp_tp_mesh(dp or len(default_devices()) // shard,
                                   args.sp, args.tp)
         batch_spec = P("ps", "sp")
     elif args.sp > 1:
@@ -1513,7 +1528,7 @@ def _run_transformer_loop(args, opt, mesh, model, loss_fn=None):
     print(f"mesh: dp={dp} sp={mesh.shape.get('sp', 1)} "
           f"tp={mesh.shape.get('tp', 1)} pp={mesh.shape.get('pp', 1)} "
           f"ep={mesh.shape.get('ep', 1)} x "
-          f"{jax.devices()[0].platform}", file=sys.stderr)
+          f"{mesh.devices.flat[0].platform}", file=sys.stderr)
 
     opt.compile_step(loss_fn if loss_fn is not None else make_lm_loss(model),
                      accum_steps=args.accum_steps,
@@ -1564,6 +1579,61 @@ def _run_transformer_loop(args, opt, mesh, model, loss_fn=None):
     return opt
 
 
+def _shutdown(*roles) -> None:
+    """End of a --serve role: let every connection handler DONE its peer
+    and finish, drain the decode pools (`AsyncPSServer.join`), then close.
+    The process must reach interpreter exit with no thread of ours inside
+    a native or JAX call — that aborts with rc 134 after a clean run."""
+    for role in roles:
+        role.join()
+        role.close()
+
+
+def _serve_single(args, srv, loss_fn):
+    """The plain --serve role on an already-constructed server (the
+    caller shuts it down)."""
+    srv.compile_step(loss_fn)
+    start = 0
+    if args.resume:
+        start = srv.resume_from(args.resume)
+        print(f"resumed from {args.resume} at step {start}",
+              file=sys.stderr)
+    updates = max(args.steps - start, 0)
+    if updates == 0:
+        print("nothing to do: checkpoint is already at "
+              f"step {start} >= --steps {args.steps}", file=sys.stderr)
+        return srv
+    # Machine-parseable on stdout: launchers read the bound port from
+    # here when --serve 0 asked for an ephemeral one.  Only the port is
+    # printed — the bind address (0.0.0.0) is not a connectable host.
+    print(f"serving on port {srv.address[1]}", flush=True)
+    t0 = time.perf_counter()
+    hist = srv.serve(steps=updates, log_every=10,
+                     checkpoint_path=args.save,
+                     checkpoint_every=args.checkpoint_every,
+                     start_step=start)
+    wall = time.perf_counter() - t0
+    grads = hist["grads_consumed"]
+    print(f"done: {updates} updates, {grads} grads, "
+          f"{grads * args.batch_size / wall:.1f} images/sec, "
+          f"mean staleness {np.mean(hist['staleness']):.2f}",
+          file=sys.stderr)
+    from .utils.timing import format_fault_stats
+    rendered = format_fault_stats(hist["fault_stats"])
+    if rendered != "clean":
+        print("fault stats: " + rendered, file=sys.stderr)
+    if args.save:
+        # Through the server's own checkpoint path (not the generic
+        # _maybe_save): it records the serving version counter, which
+        # a later --resume needs for continuous staleness accounting.
+        srv._auto_checkpoint(args.save, args.steps)
+        print(f"checkpoint -> {args.save} (step {args.steps})",
+              file=sys.stderr)
+    if args.summary:
+        srv.print_summary()
+    return srv
+
+
 def run_multihost(args):
     """Multi-host AsySG-InCon over TCP (`multihost_async`): the reference's
     multi-node deployment shape — one --serve process (rank 0 of
@@ -1612,46 +1682,10 @@ def run_multihost(args):
                             delta_parm=args.delta_parm,
                             fault_plan=plan,
                             **hyper_from_args(args))
-        srv.compile_step(loss_fn)
-        start = 0
-        if args.resume:
-            start = srv.resume_from(args.resume)
-            print(f"resumed from {args.resume} at step {start}",
-                  file=sys.stderr)
-        updates = max(args.steps - start, 0)
-        if updates == 0:
-            print("nothing to do: checkpoint is already at "
-                  f"step {start} >= --steps {args.steps}", file=sys.stderr)
-            return srv
-        # Machine-parseable on stdout: launchers read the bound port from
-        # here when --serve 0 asked for an ephemeral one.  Only the port is
-        # printed — the bind address (0.0.0.0) is not a connectable host.
-        print(f"serving on port {srv.address[1]}", flush=True)
-        t0 = time.perf_counter()
-        hist = srv.serve(steps=updates, log_every=10,
-                         checkpoint_path=args.save,
-                         checkpoint_every=args.checkpoint_every,
-                         start_step=start)
-        wall = time.perf_counter() - t0
-        grads = hist["grads_consumed"]
-        print(f"done: {updates} updates, {grads} grads, "
-              f"{grads * args.batch_size / wall:.1f} images/sec, "
-              f"mean staleness {np.mean(hist['staleness']):.2f}",
-              file=sys.stderr)
-        from .utils.timing import format_fault_stats
-        rendered = format_fault_stats(hist["fault_stats"])
-        if rendered != "clean":
-            print("fault stats: " + rendered, file=sys.stderr)
-        if args.save:
-            # Through the server's own checkpoint path (not the generic
-            # _maybe_save): it records the serving version counter, which
-            # a later --resume needs for continuous staleness accounting.
-            srv._auto_checkpoint(args.save, args.steps)
-            print(f"checkpoint -> {args.save} (step {args.steps})",
-                  file=sys.stderr)
-        if args.summary:
-            srv.print_summary()
-        return srv
+        try:
+            return _serve_single(args, srv, loss_fn)
+        finally:
+            _shutdown(srv)
 
     endpoints = []
     for part in args.connect.split(","):
@@ -1825,10 +1859,13 @@ def _run_fleet(args, params, loss_fn, plan):
     print("serving on ports "
           + " ".join(str(p) for _, p in fleet.addresses), flush=True)
     t0 = time.perf_counter()
-    hist = fleet.serve(steps=args.steps, log_every=10,
-                       checkpoint_path=args.save,
-                       checkpoint_every=args.checkpoint_every,
-                       snapshot_every=args.snapshot_every)
+    try:
+        hist = fleet.serve(steps=args.steps, log_every=10,
+                           checkpoint_path=args.save,
+                           checkpoint_every=args.checkpoint_every,
+                           snapshot_every=args.snapshot_every)
+    finally:
+        _shutdown(fleet)
     wall = time.perf_counter() - t0
     print(f"done: {hist['updates_total']} shard-updates across "
           f"{args.shards} shards ({hist['updates_total'] / wall:.1f} "
@@ -1962,10 +1999,12 @@ def _run_hier(args, params, loss_fn, plan):
     print("aggregators on ports "
           + " ".join(str(p) for _, p in hier.addresses), flush=True)
     t0 = time.perf_counter()
-    view = hier.serve(log_every=10)
-    root_thread.join(timeout=600)
+    try:
+        view = hier.serve(log_every=10)
+        root_thread.join(timeout=600)
+    finally:
+        _shutdown(hier, root)
     if "error" in root_out:
-        hier.close()
         raise root_out["error"]
     hist = root_out.get("hist") or {}
     wall = time.perf_counter() - t0
@@ -1997,7 +2036,6 @@ def _run_hier(args, params, loss_fn, plan):
             root._auto_checkpoint(args.save, args.steps)
         print(f"checkpoint -> {args.save} (step {args.steps})",
               file=sys.stderr)
-    hier.close()
     return root
 
 
@@ -2079,7 +2117,9 @@ def run_async(args):
         raise SystemExit("--save-every is not supported with --async-ps "
                          "(updates run inside one opt.run call); use --save")
     hyper = hyper_from_args(args)
-    devices = jax.devices()[:args.n_devices] if args.n_devices else None
+    from .parallel.mesh import default_devices
+    devices = (default_devices()[:args.n_devices] if args.n_devices
+               else None)
     plan = None
     if args.chaos:
         from .utils.faults import FaultPlan

@@ -732,6 +732,12 @@ class Session:
                 sock.close()
             except OSError:  # pragma: no cover - close best-effort
                 pass
+        hb = self._hb_thread
+        if hb is not None and hb is not threading.current_thread():
+            # The beat wakes on `_hb_stop` (or errors out of the closed
+            # socket) at once; joining it means an owner that returns to
+            # interpreter exit leaves no thread behind.
+            hb.join(timeout=2.0)
 
     # -- the race-sanitizer probe ---------------------------------------------
 

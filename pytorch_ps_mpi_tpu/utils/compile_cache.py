@@ -1,0 +1,51 @@
+"""Where JAX's persistent compilation cache lives — decided in one place.
+
+Every entry point that compiles (`train.main`, ``bench.py``,
+``chip_smoke.py`` phases, ``tests/conftest.py``) calls
+`configure_compile_cache` once, before its first compile:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX already uses
+  it, and nothing is set in code — whoever runs the program owns the
+  placement (a machine that keeps that directory between runs gets warm
+  starts).
+* unset: one fixed directory inside the checkout (`CACHE_DIR`,
+  git-ignored).  Fixed because the path is part of the cache key — a
+  directory named after a pid, a timestamp or a fresh ``/tmp`` entry never
+  hits.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Apply the policy above; returns the directory in effect."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR
+
+
+class CacheCounter:
+    """Counts this process's persistent-cache hits and misses (JAX's own
+    monitoring events), so a run can show that a program compiled by an
+    earlier process was found again."""
+
+    def __init__(self):
+        self.hits = 0
+        self.misses = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1

@@ -8,9 +8,7 @@ donated jax buffer read after donation.  The clean twins
 (``park_copy``, ``handoff_view``) prove materialization and the
 ``# pslint: transfers-ownership`` contract silence the rule; the
 ``allow()`` lines prove the escape hatch suppresses exactly what it
-annotates.  The literal ``donate_argnums`` also carries its PSL204
-marker — the platform-gate rule and the dataflow rule convict the same
-construction site for different reasons, by design.
+annotates.
 
 Marker contract as in bad_lock.py.  Never imported — pslint only
 parses (the ``jax`` names below are never resolved).
@@ -130,10 +128,8 @@ def _apply(a, b):
 
 
 def donated_reuse(x, y):
-    """Read-after-donation through a literal-donating jit handle (the
-    literal also trips PSL204's platform-gate rule — same site, two
-    reasons)."""
-    step = jax.jit(_apply, donate_argnums=(0,))  # [PSL204]
+    """Read-after-donation through a literal-donating jit handle."""
+    step = jax.jit(_apply, donate_argnums=(0,))
     out = step(x, y)
     return out + x  # [PSL704]
 

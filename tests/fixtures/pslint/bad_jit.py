@@ -31,7 +31,6 @@ def item_leak(x):
 
 leaky_jit = jax.jit(leaky)
 item_jit = jax.jit(item_leak)
-donating = jax.jit(item_leak, donate_argnums=(0,))  # [PSL204]
 
 
 class JitServer:

@@ -141,7 +141,7 @@ def test_solo_psum_bitwise_matches_packed_psum(mesh8):
 @pytest.mark.parametrize("codec", ["identity", "blockq"])
 def test_fused_encode_matches_host_encode(codec):
     params = _params()
-    code = get_codec(codec)
+    code = get_codec(codec, "cpu")
     plan = plan_overlap(params, 4096, record=False)
     fused = make_async_bucket_step(mlp_loss_fn, code, plan, fused=True)
     host = make_async_bucket_step(mlp_loss_fn, code, plan, fused=False)
@@ -161,7 +161,7 @@ def test_fused_encode_matches_host_encode(codec):
 
 def test_single_bucket_step_equals_whole_tree_step():
     params = _params()
-    code = get_codec(None)
+    code = get_codec(None, "cpu")
     plan = plan_overlap(params, 1 << 30, record=False)
     assert plan.n_buckets == 1
     bucketed = make_async_bucket_step(mlp_loss_fn, code, plan, fused=True)
@@ -182,8 +182,6 @@ def test_pallas_blockq_interpreter_encode_matches_reference():
     decode half already carries."""
     from pytorch_ps_mpi_tpu.ops import pallas_kernels as pk
 
-    if not pk.HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     rng = np.random.RandomState(0)
     x2d, _ = pk.pad_to_blocks(jnp.asarray(
         rng.randn(3000).astype(np.float32)), 8)
@@ -197,7 +195,7 @@ def test_pallas_blockq_interpreter_encode_matches_reference():
 def test_bucketed_step_steady_state_never_retraces():
     params = _params()
     plan = plan_overlap(params, 4096, record=False)
-    fn = make_async_bucket_step(mlp_loss_fn, get_codec(None), plan,
+    fn = make_async_bucket_step(mlp_loss_fn, get_codec(None, "cpu"), plan,
                                 fused=True)
     if not hasattr(fn, "_cache_size"):
         pytest.skip("jit cache introspection unavailable")
@@ -636,7 +634,7 @@ def test_cli_bucket_stream_chaos_endurance():
     import subprocess
     import sys as _sys
 
-    from test_multihost_async import _reap_all
+    from test_multihost_async import ChildProc, _reap_all
 
     from pytorch_ps_mpi_tpu.utils.faults import FaultPlan
 
@@ -649,21 +647,19 @@ def test_cli_bucket_stream_chaos_endurance():
     base = ("'--model','mlp','--steps','16','--quota','2',"
             "'--batch-size','32','--n-examples','128'")
 
-    server = subprocess.Popen(
+    server = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','0',{base},'--quorum','1',"
-         f"'--fill-deadline','0.2'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--fill-deadline','0.2'])"])
     line = server.stdout.readline()
     assert line.startswith("serving on port "), line
     port = line.strip().rsplit(" ", 1)[1]
 
-    workers = [subprocess.Popen(
+    workers = [ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--connect','127.0.0.1:{port}',{base},"
          f"'--async-bucket-bytes','4096','--fused-encode',"
-         f"'--chaos','{chaos}'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--chaos','{chaos}'])"])
         for _ in range(2)]
 
     outs = _reap_all([server] + workers, timeout=300)

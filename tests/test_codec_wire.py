@@ -541,7 +541,7 @@ def test_cli_bf16_wire_failover_endurance():
     import subprocess
     import sys as _sys
 
-    from test_multihost_async import _reap_all
+    from test_multihost_async import ChildProc, _reap_all
 
     from pytorch_ps_mpi_tpu.utils.faults import FaultPlan
 
@@ -553,29 +553,26 @@ def test_cli_bf16_wire_failover_endurance():
     base = ("'--model','mlp','--steps','16','--quota','1',"
             "'--batch-size','32','--n-examples','128'")
 
-    server = subprocess.Popen(
+    server = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','0','--shards','2',{base},"
          f"'--wire-codec','bf16','--delta-parm','--read-window','64',"
          f"'--checkpoint-every','1','--save','/tmp/_codec_wire_ckpt.psz',"
-         f"'--chaos','{chaos}'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--chaos','{chaos}'])"])
     line = server.stdout.readline()
     assert line.startswith("serving on ports "), line
     ports = line.strip().split("ports ", 1)[1].split()
     assert len(ports) == 2
     connect = ",".join(f"127.0.0.1:{p}" for p in ports)
 
-    worker = subprocess.Popen(
+    worker = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--connect','{connect}',{base},"
-         "'--reconnect-retries','100'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-    subscriber = subprocess.Popen(
+         "'--reconnect-retries','100'])"])
+    subscriber = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--subscribe','{connect}','--shards','2','--model','mlp',"
-         "'--steps','600','--reconnect-retries','100'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         "'--steps','600','--reconnect-retries','100'])"])
 
     outs = _reap_all([server, worker, subscriber], timeout=420)
     (s_out, s_err) = outs[0]

@@ -85,12 +85,12 @@ def test_decode_sum_equals_sum_of_decodes(codec):
 
 
 def test_get_codec_resolution():
-    assert isinstance(get_codec(None), IdentityCodec)
-    assert isinstance(get_codec("topk"), TopKCodec)
+    assert isinstance(get_codec(None, "cpu"), IdentityCodec)
+    assert isinstance(get_codec("topk", "cpu"), TopKCodec)
     c = QuantizeCodec(16)
-    assert get_codec(c) is c
+    assert get_codec(c, "cpu") is c
     with pytest.raises(ValueError):
-        get_codec("lz4")  # banned in the reference too (`mpi_comms.py:22-24`)
+        get_codec("lz4", "cpu")  # banned in the reference too (`mpi_comms.py:22-24`)
 
 
 def test_scale_code_is_linear_for_all_codecs():
@@ -108,7 +108,7 @@ def test_scale_code_is_linear_for_all_codecs():
     w = jnp.asarray([0.25, 1.0, 0.5], jnp.float32)
     for name in ("identity", "bf16", "topk", "topk_approx", "quantize",
                  "sign", "blockq"):
-        codec = get_codec(name)
+        codec = get_codec(name, "cpu")
         codes = [codec.encode(g) for g in gs]
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *codes)
         got = np.asarray(codec.decode_sum(

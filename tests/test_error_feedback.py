@@ -38,7 +38,7 @@ def _batches(world, n, seed=1):
 
 
 def test_cast_codec_roundtrip_and_bytes():
-    codec = get_codec("bf16")
+    codec = get_codec("bf16", "cpu")
     g = jnp.asarray(np.random.RandomState(0).randn(33, 7).astype(np.float32))
     code = codec.encode(g)
     assert code.dtype == jnp.bfloat16
@@ -230,7 +230,7 @@ def test_ef_resume_same_world_is_bitwise():
 
 
 def test_cast_codec_cli_name_roundtrip():
-    assert isinstance(get_codec("bf16"), CastCodec)
+    assert isinstance(get_codec("bf16", "cpu"), CastCodec)
 
 
 def test_ef_and_ema_compose():

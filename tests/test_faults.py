@@ -749,7 +749,7 @@ def test_cli_crash_resume_endurance(tmp_path):
     import subprocess
     import sys as _sys
 
-    from test_multihost_async import _reap_all
+    from test_multihost_async import ChildProc, _reap_all
 
     env_setup = ("import os; os.environ['XLA_FLAGS']=os.environ.get("
                  "'XLA_FLAGS','')+' --xla_force_host_platform_device_count=1'"
@@ -760,31 +760,28 @@ def test_cli_crash_resume_endurance(tmp_path):
     base = ("'--model','mlp','--steps','30','--quota','1',"
             "'--batch-size','32','--n-examples','128'")
 
-    server1 = subprocess.Popen(
+    server1 = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','0',{base},'--save','{ckpt}',"
-         f"'--checkpoint-every','4','--chaos','{chaos}'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--checkpoint-every','4','--chaos','{chaos}'])"])
     line = server1.stdout.readline()
     assert line.startswith("serving on port "), line
     port = line.strip().rsplit(" ", 1)[1]
 
-    workers = [subprocess.Popen(
+    workers = [ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--connect','127.0.0.1:{port}',{base},"
-         "'--reconnect-retries','100'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         "'--reconnect-retries','100'])"])
         for _ in range(2)]
 
     (s1_out, s1_err) = _reap_all([server1], timeout=300)[0]
     assert server1.returncode != 0  # the PS really crashed
     assert "SimulatedCrash" in s1_err, s1_err
 
-    server2 = subprocess.Popen(
+    server2 = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','{port}',{base},'--resume','{ckpt}',"
-         f"'--save','{ckpt}','--checkpoint-every','4'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--save','{ckpt}','--checkpoint-every','4'])"])
 
     outs = _reap_all([server2] + workers, timeout=300)
     (s2_out, s2_err) = outs[0]
@@ -809,7 +806,7 @@ def test_cli_robust_quorum_endurance():
     import subprocess
     import sys as _sys
 
-    from test_multihost_async import _reap_all
+    from test_multihost_async import ChildProc, _reap_all
 
     env_setup = ("import os; os.environ['XLA_FLAGS']=os.environ.get("
                  "'XLA_FLAGS','')+' --xla_force_host_platform_device_count=1'"
@@ -821,24 +818,22 @@ def test_cli_robust_quorum_endurance():
     base = ("'--model','mlp','--steps','20','--batch-size','32',"
             "'--n-examples','128'")
 
-    server = subprocess.Popen(
+    server = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','0',{base},'--quota','3','--quorum','2',"
          # norm_clip: its influence bound holds at any fill size, so it
          # composes with a quorum of 2 (trimmed_mean would refuse: a
          # 2-contribution short fill is below its breakdown size).
          "'--fill-deadline','0.1','--aggregate','norm_clip',"
-         "'--anomaly-z','4'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         "'--anomaly-z','4'])"])
     line = server.stdout.readline()
     assert line.startswith("serving on port "), line
     port = line.strip().rsplit(" ", 1)[1]
 
-    workers = [subprocess.Popen(
+    workers = [ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--connect','127.0.0.1:{port}',{base},"
-         f"'--chaos','{chaos}'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--chaos','{chaos}'])"])
         for _ in range(3)]
 
     outs = _reap_all([server] + workers, timeout=300)

@@ -14,8 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pytorch_ps_mpi_tpu.ops.flash_attention import BLOCK, flash_attention
+from pytorch_ps_mpi_tpu.ops import flash_attention as _fa
+from pytorch_ps_mpi_tpu.ops.flash_attention import BLOCK
 from pytorch_ps_mpi_tpu.parallel.ring_attention import dense_attention
+
+# The default lowers the Mosaic kernel; on the CPU mesh the interpreter is
+# asked for by name.
+flash_attention = functools.partial(_fa.flash_attention, impl="interpret")
 
 
 def _qkv(seed, b=2, s=96, h=2, d=8):

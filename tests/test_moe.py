@@ -197,7 +197,7 @@ def test_cli_moe_hier_endurance(tmp_path):
 
     from pytorch_ps_mpi_tpu.utils.faults import FaultPlan
 
-    from test_multihost_async import _reap_all
+    from test_multihost_async import ChildProc, _reap_all
 
     env_setup = ("import os; os.environ['XLA_FLAGS']=os.environ.get("
                  "'XLA_FLAGS','')+' --xla_force_host_platform_device_count=1'"
@@ -208,11 +208,10 @@ def test_cli_moe_hier_endurance(tmp_path):
             "'--batch-size','8','--n-examples','64','--steps','8',"
             "'--codec','topk'")
 
-    server = subprocess.Popen(
+    server = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','0','--aggregators','1','--group-size','2',"
-         f"'--quota','1',{base},'--chaos','{chaos}'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--quota','1',{base},'--chaos','{chaos}'])"])
     l1 = server.stdout.readline()
     assert l1.startswith("serving on port"), l1
     root_port = l1.strip().rsplit(" ", 1)[1]
@@ -220,12 +219,11 @@ def test_cli_moe_hier_endurance(tmp_path):
     assert l2.startswith("aggregators on ports"), l2
     agg_port = l2.strip().rsplit(" ", 1)[1]
 
-    workers = [subprocess.Popen(
+    workers = [ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--connect','127.0.0.1:{agg_port}',"
          f"'--fallback','127.0.0.1:{root_port}',{base},"
-         "'--reconnect-retries','100'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         "'--reconnect-retries','100'])"])
         for _ in range(2)]
 
     outs = _reap_all([server] + workers, timeout=420)

@@ -10,7 +10,9 @@ protocol round-trips codec payloads, and staleness is recorded.
 
 import subprocess
 import sys
+import tempfile
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -19,12 +21,32 @@ from pytorch_ps_mpi_tpu.models import init_mlp, mlp_apply, mlp_loss_fn
 from pytorch_ps_mpi_tpu.multihost_async import AsyncSGDServer
 
 
+class ChildProc(subprocess.Popen):
+    """`Popen` for the role/worker children every TCP suite spawns: stdout
+    is a text pipe (tests read the ``serving on port`` line from it), but
+    stderr goes to an unlinked temp FILE, handed back by `communicate` as
+    usual.  An undrained stderr PIPE is a deadlock: on jax 0.9 every hit
+    in the persistent compile cache makes XLA:CPU log ~3.6 KB of
+    ``cpu_aot_loader`` errors, the 64 KB pipe fills after ~18 hits, and
+    the child blocks before it ever prints its port while the test blocks
+    on that line — the hang that used to eat the tier-1 lane's clock."""
+
+    def __init__(self, cmd, **kw):
+        self._errfile = tempfile.TemporaryFile(mode="w+")
+        super().__init__(cmd, stdout=subprocess.PIPE,
+                         stderr=self._errfile, text=True, **kw)
+
+    def communicate(self, input=None, timeout=None):
+        out, _ = super().communicate(input, timeout)
+        self._errfile.seek(0)
+        return out, self._errfile.read()
+
+
 def _reap_all(procs, timeout: float = 60):
-    """Join every worker, killing any that wedges — one slow/stuck process
-    must not leave the REST un-reaped (the BENCH_r05 leftover-worker
-    shape: a single `communicate(timeout=...)` raising TimeoutExpired
-    abandoned every process after it in the list).  CPU-only workers hold
-    no TPU claim, so a kill is always safe."""
+    """Join every worker, killing any that hangs — one slow/stuck process
+    must not leave the REST un-reaped (a single `communicate(timeout=...)`
+    raising TimeoutExpired once abandoned every process after it in the
+    list)."""
     outs = []
     for p in procs:
         try:
@@ -52,6 +74,12 @@ x = rng.randn(256, 16).astype(np.float32)
 w = rng.randn(16, 4).astype(np.float32)
 y = (x @ w).argmax(1).astype(np.int32)
 
+# Start gate (`_spawn_workers`): imports done and the backend up, so the
+# dial is milliseconds after the test's "go".
+jax.devices()
+print("READY", flush=True)
+sys.stdin.readline()
+
 worker = AsyncPSWorker("127.0.0.1", port, code=None if code == "identity" else code)
 pushed = worker.run(mlp_loss_fn, dataset_batch_fn(x, y, 64, seed=3))
 print(f"WORKER rank={worker.rank} pushed={pushed}")
@@ -65,6 +93,29 @@ def _teacher_data():
     w = rng.randn(16, 4).astype(np.float32)
     y = (x @ w).argmax(1).astype(np.int32)
     return x, y
+
+
+def _spawn_workers(n: int, port: int, code: str = "identity"):
+    """Start ``n`` worker processes, release them TOGETHER once every one
+    has imported and initialized its backend, and give their dials a moment
+    to land in the listener's backlog — call this BEFORE ``srv.serve``.
+    With a warm compile cache a 16-update run lasts tens of milliseconds:
+    a worker that starts a little slower dials after the server has taken
+    every update from the fast ones and closed the listener, is refused (or
+    reset in the backlog), and exits 1 — failing tests that expect every
+    worker to connect and contribute.  With all of them already queued,
+    `serve` accepts the lot before the first gradient exists."""
+    procs = [ChildProc([sys.executable, "-c", WORKER_SCRIPT, str(port), code],
+                       stdin=subprocess.PIPE)
+             for _ in range(n)]
+    for p in procs:
+        line = p.stdout.readline()
+        assert line.strip() == "READY", (line, p.communicate())
+    for p in procs:
+        p.stdin.write("go\n")
+        p.stdin.flush()
+    time.sleep(0.5)
+    return procs
 
 
 @pytest.mark.parametrize("code", ["identity", "quantize"])
@@ -81,11 +132,7 @@ def test_two_worker_processes_train_over_tcp(code):
     srv.compile_step(mlp_loss_fn)
     port = srv.address[1]
 
-    procs = [subprocess.Popen([sys.executable, "-c", WORKER_SCRIPT,
-                               str(port), code],
-                              stdout=subprocess.PIPE,
-                              stderr=subprocess.PIPE, text=True)
-             for _ in range(2)]
+    procs = _spawn_workers(2, port, code)
     # 50 updates: on a slow CPU-share-limited host, 25 left the final
     # accuracy hovering at its threshold (flaky at baseline); 50 puts the
     # margin well clear while staying a few seconds of serving.
@@ -140,10 +187,7 @@ def test_four_worker_scale_quota_sweep():
                              momentum=0.9, quota=quota)
         srv.compile_step(mlp_loss_fn)
         port = srv.address[1]
-        procs = [subprocess.Popen(
-            [sys.executable, "-c", WORKER_SCRIPT, str(port), "identity"],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-            for _ in range(n_workers)]
+        procs = _spawn_workers(n_workers, port)
         # The quota=4 cell also carries the convergence oracle: on the
         # v9 wire four unthrottled workers saturate the credit window,
         # so applied staleness rides its bound and momentum (0.9) can
@@ -290,20 +334,20 @@ def test_worker_killed_midrun_survivors_finish():
                          quota=1)
     srv.compile_step(mlp_loss_fn)
     port = srv.address[1]
-    procs = [subprocess.Popen(
-        [sys.executable, "-c", WORKER_SCRIPT, str(port), "identity"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-        for _ in range(3)]
+    procs = _spawn_workers(3, port)
 
     killer_done = threading.Event()
 
     def kill_one_soon():
-        _time.sleep(2.0)  # let it connect and start pushing
+        # Mid-run by PROGRESS, not by the clock: with a warm compile cache
+        # the whole run can be over before any fixed sleep ends.
+        while srv.applied_updates() < 5:
+            _time.sleep(0.001)
         procs[0].kill()
         killer_done.set()
 
     threading.Thread(target=kill_one_soon, daemon=True).start()
-    steps = 20
+    steps = 200
     try:
         history = srv.serve(steps=steps)
     finally:
@@ -319,32 +363,40 @@ def test_worker_killed_midrun_survivors_finish():
 
 def test_cli_serve_and_connect_roundtrip():
     """The --serve / --connect CLI roles: a server process and a worker
-    process launched exactly as they would be on two hosts."""
+    process launched exactly as they would be on two hosts — three times
+    over, and both must end with rc 0 EVERY time: `run_multihost` joins
+    the server's connection handlers and decode pool (and the worker's
+    heartbeat) before returning, so the interpreter never exits with one of
+    our threads inside a native or JAX call — which used to abort `--serve`
+    with rc 134 AFTER it had printed ``done``."""
     env_setup = ("import os; os.environ['XLA_FLAGS']=os.environ.get("
                  "'XLA_FLAGS','')+' --xla_force_host_platform_device_count=1'"
                  ";import jax; jax.config.update('jax_platforms','cpu');"
                  "from pytorch_ps_mpi_tpu import train; train.main(")
-    server = subprocess.Popen(
-        [sys.executable, "-c", env_setup +
-         "['--model','mlp','--serve','0','--steps','10','--quota','1',"
-         "'--batch-size','32','--n-examples','128'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-    line = server.stdout.readline()
-    assert line.startswith("serving on port "), line
-    port = line.strip().rsplit(" ", 1)[1]
+    for attempt in range(3):
+        server = ChildProc(
+            [sys.executable, "-c", env_setup +
+             "['--model','mlp','--serve','0','--steps','10','--quota','1',"
+             "'--batch-size','32','--n-examples','128'])"])
+        line = server.stdout.readline()
+        assert line.startswith("serving on port "), line
+        port = line.strip().rsplit(" ", 1)[1]
 
-    worker = subprocess.Popen(
-        [sys.executable, "-c", env_setup +
-         f"['--model','mlp','--connect','127.0.0.1:{port}',"
-         "'--batch-size','32','--n-examples','128'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        worker = ChildProc(
+            [sys.executable, "-c", env_setup +
+             f"['--model','mlp','--connect','127.0.0.1:{port}',"
+             "'--batch-size','32','--n-examples','128'])"])
 
-    (s_out, s_err), (w_out, w_err) = _reap_all([server, worker],
-                                               timeout=180)
-    assert server.returncode == 0, f"server failed:\n{s_out}\n{s_err}"
-    assert worker.returncode == 0, f"worker failed:\n{w_out}\n{w_err}"
-    assert "done: 10 updates, 10 grads" in s_err
-    assert "gradients pushed" in w_err
+        (s_out, s_err), (w_out, w_err) = _reap_all([server, worker],
+                                                   timeout=180)
+        assert server.returncode == 0, \
+            f"run {attempt}: server failed:\n{s_out}\n{s_err[-3000:]}"
+        assert worker.returncode == 0, \
+            f"run {attempt}: worker failed:\n{w_out}\n{w_err[-3000:]}"
+        assert "done: 10 updates, 10 grads" in s_err
+        assert "gradients pushed" in w_err
+        # Nothing of the server's was still running when the role returned.
+        assert "still running" not in s_err
 
 
 def test_stray_connection_cannot_kill_training():
@@ -608,18 +660,16 @@ def test_cli_serve_and_connect_transformer():
                  "from pytorch_ps_mpi_tpu import train; train.main(")
     lm_args = ("'--model','transformer','--seq-len','16','--vocab','31',"
                "'--batch-size','8','--n-examples','32'")
-    server = subprocess.Popen(
+    server = ChildProc(
         [sys.executable, "-c", env_setup +
-         f"['--serve','0','--steps','4','--quota','1',{lm_args}])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"['--serve','0','--steps','4','--quota','1',{lm_args}])"])
     line = server.stdout.readline()
     assert line.startswith("serving on port "), line
     port = line.strip().rsplit(" ", 1)[1]
 
-    worker = subprocess.Popen(
+    worker = ChildProc(
         [sys.executable, "-c", env_setup +
-         f"['--connect','127.0.0.1:{port}',{lm_args}])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"['--connect','127.0.0.1:{port}',{lm_args}])"])
 
     (s_out, s_err), (w_out, w_err) = _reap_all([server, worker],
                                                timeout=240)
@@ -627,3 +677,4 @@ def test_cli_serve_and_connect_transformer():
     assert worker.returncode == 0, f"worker failed:\n{w_out}\n{w_err}"
     assert "done: 4 updates, 4 grads" in s_err
     assert "gradients pushed" in w_err
+

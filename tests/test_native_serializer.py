@@ -6,6 +6,8 @@ push them through the protocol, compare against the original
 byte pipeline instead of MPI framing.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -25,6 +27,41 @@ def roundtrip(data, **kw):
 def test_lib_builds_and_loads():
     L = lib()
     assert L.ps_max_compressed(1000) >= 1000
+
+
+def test_library_name_follows_the_source_bytes(tmp_path, monkeypatch):
+    """The .so is named after a hash of the sources and the compile
+    command, so a binary built from other sources (``_lib/`` is git-ignored
+    and travels with a copied tree) can never be the one loaded: one
+    changed source byte is a new name."""
+    import shutil
+
+    from pytorch_ps_mpi_tpu import native
+
+    loaded = os.path.basename(native._build())
+    assert loaded == f"libps_native-{native.build_id()}.so"
+    srcs = []
+    for src in native._SRCS:
+        dst = tmp_path / os.path.basename(src)
+        shutil.copy(src, dst)
+        srcs.append(str(dst))
+    monkeypatch.setattr(native, "_SRCS", srcs)
+    assert native.build_id() == loaded[len("libps_native-"):-len(".so")]
+    with open(srcs[0], "ab") as f:
+        f.write(b"\n")
+    assert os.path.basename(native.lib_path()) != loaded
+    monkeypatch.setattr(native, "_CXX", [*native._CXX, "-DNDEBUG"])
+    assert os.path.basename(native.lib_path()) != loaded
+
+
+def test_missing_compiler_is_a_typed_error(tmp_path, monkeypatch):
+    from pytorch_ps_mpi_tpu import native
+    from pytorch_ps_mpi_tpu.errors import NativeToolchainError
+
+    monkeypatch.setattr(native, "_LIBDIR", str(tmp_path / "_lib"))
+    monkeypatch.setattr(native, "_CXX", ["no-such-compiler-xyz"])
+    with pytest.raises(NativeToolchainError, match="g\\+\\+"):
+        native._build()
 
 
 def test_empty_and_tiny():

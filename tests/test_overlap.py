@@ -304,7 +304,7 @@ def test_fused_blockq_matches_explicit_stage_programs(mesh8):
     from pytorch_ps_mpi_tpu.ops.codecs import BlockQuantizeCodec
 
     w = world_size(mesh8)
-    codec = BlockQuantizeCodec()
+    codec = BlockQuantizeCodec(impl="ref")
     rng = np.random.RandomState(3)
     shapes = [(40, 7), (111,), (5, 3, 2)]
     base = OrderedDict(
@@ -315,11 +315,14 @@ def test_fused_blockq_matches_explicit_stage_programs(mesh8):
     def body(scale):
         # Rank-distinct cotangents: leaf * (rank + 1).
         cot = OrderedDict((n, base[n] * scale[0]) for n in names)
-        return OV._sync_blockq_fused(cot, "ps", codec)
+        q, scales, _ = OV._blockq_bucket_encode(cot, codec)
+        return (OV._sync_blockq_fused(cot, "ps", codec),
+                q[None], scales[None])
 
     ranks = np.arange(1, w + 1, dtype=np.float32)
-    fused = jax.jit(jax.shard_map(body, mesh=mesh8, in_specs=P("ps"),
-                                  out_specs=P(), check_vma=False))(ranks)
+    fused, fused_q, fused_s = jax.jit(jax.shard_map(
+        body, mesh=mesh8, in_specs=P("ps"),
+        out_specs=(P(), P("ps"), P("ps")), check_vma=False))(ranks)
 
     flat_len = sum(int(v.size) for v in base.values())
     rows = codec._rows_for(flat_len)
@@ -328,40 +331,47 @@ def test_fused_blockq_matches_explicit_stage_programs(mesh8):
         flat = jnp.concatenate([(base[n] * float(rank + 1)).reshape(-1)
                                 for n in names])
         x2d, _ = pk.pad_to_blocks(flat, rows)
-        q, s = pk.block_quantize(x2d, bits=codec.bits, block_rows=rows)
+        q, s = pk.block_quantize(x2d, bits=codec.bits, block_rows=rows,
+                                 impl="ref")
         qs.append(q)
         ss.append(s)
+    # The codes are the contract: bit for bit.
+    np.testing.assert_array_equal(np.asarray(fused_q), np.asarray(qs))
+    np.testing.assert_array_equal(np.asarray(fused_s), np.asarray(ss))
     out2d = pk.block_dequant_sum(jnp.stack(qs), jnp.stack(ss),
-                                 block_rows=rows)
+                                 block_rows=rows, impl="ref")
     summed = np.asarray(out2d).reshape(-1)[:flat_len]
+    # The f32 dequant-sum may contract multiply-adds (FMA) in one program
+    # and not in the other: a few ulp of the largest term, not bitwise.
+    atol = 4 * np.finfo(np.float32).eps * float(np.abs(summed).max())
     off = 0
     for n in names:
         sz = int(base[n].size)
         ref = summed[off:off + sz].reshape(base[n].shape)
-        np.testing.assert_array_equal(np.asarray(fused[n]), ref, err_msg=n)
+        np.testing.assert_allclose(np.asarray(fused[n]), ref, rtol=0,
+                                   atol=atol, err_msg=n)
         off += sz
 
 
-def test_fused_interpreter_matches_compiled_path(mesh8):
-    """``interpret=True`` routes the bucket quantize through the Pallas
-    interpreter; off-TPU the default path runs `block_quantize_ref` — the
-    two programs must agree bit-for-bit (same contract as the async fused
-    encode's escape hatch in test_bucket_stream)."""
+def test_fused_interpreter_matches_reference(mesh8):
+    """``impl="interpret"`` runs the bucket's kernels under the Pallas
+    interpreter, ``impl="ref"`` the jnp reference — the two programs must
+    agree (same contract as the async fused encode in
+    test_bucket_stream)."""
     from collections import OrderedDict
 
     from pytorch_ps_mpi_tpu.ops.codecs import BlockQuantizeCodec
 
     w = world_size(mesh8)
-    codec = BlockQuantizeCodec()
     rng = np.random.RandomState(7)
     base = OrderedDict(
         [("w", jnp.asarray(rng.randn(33, 9).astype(np.float32))),
          ("b", jnp.asarray(rng.randn(129).astype(np.float32)))])
 
-    def run(interpret):
-        sync = OV.make_bucket_sync_fn(axis="ps", world=w, codec=codec,
-                                      fused_encode=True,
-                                      interpret=interpret)
+    def run(impl):
+        sync = OV.make_bucket_sync_fn(axis="ps", world=w,
+                                      codec=BlockQuantizeCodec(impl=impl),
+                                      fused_encode=True)
 
         def body(scale):
             cot = OrderedDict((n, base[n] * scale[0]) for n in base)
@@ -372,10 +382,12 @@ def test_fused_interpreter_matches_compiled_path(mesh8):
                                      out_specs=P(),
                                      check_vma=False))(ranks)
 
-    ref, interp = run(False), run(True)
+    ref, interp = run("ref"), run("interpret")
     for n in ref:
-        np.testing.assert_array_equal(np.asarray(ref[n]),
-                                      np.asarray(interp[n]), err_msg=n)
+        big = float(np.abs(np.asarray(ref[n])).max())
+        np.testing.assert_allclose(
+            np.asarray(ref[n]), np.asarray(interp[n]), rtol=0,
+            atol=4 * np.finfo(np.float32).eps * big, err_msg=n)
 
 
 def test_fused_refuses_non_blockq_codec():
@@ -386,7 +398,7 @@ def test_fused_refuses_non_blockq_codec():
     for code in ("bf16", "sign", "topk"):
         with pytest.raises(ValueError, match="fused_encode supports"):
             OV.make_bucket_sync_fn(axis="ps", world=2,
-                                   codec=get_codec(code),
+                                   codec=get_codec(code, "cpu"),
                                    fused_encode=True)
 
 

@@ -1,11 +1,12 @@
 """Kernel-vs-reference parity for the codec compute layer.
 
-On the CPU test mesh the Pallas TPU path can't run, so these tests pin the
-*fallback* math (which the TPU kernels mirror op-for-op) and the layout
-contract (padding, packing, block framing) that both paths share.  On real
-TPU, `block_quantize` / `block_dequant_sum` dispatch to the Pallas kernels
-and the same assertions run against them (see `on_tpu` gating in
-`pytorch_ps_mpi_tpu/ops/pallas_kernels.py`).
+On the CPU test mesh the Mosaic lowering can't run, so these tests name the
+*reference* math (``impl="ref"``, which the TPU kernels mirror op-for-op)
+or the Pallas interpreter (``impl="interpret"``), and pin the layout
+contract (padding, packing, block framing) that every implementation
+shares.  Kernel == reference on the chip is ``chip_smoke.py``'s
+``kernel_parity`` phase; the dispatch rules themselves are tested at the
+end of this file.
 """
 
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ def test_block_quantize_roundtrip_error_bound():
     rng = np.random.RandomState(0)
     x = rng.randn(4 * 8 * pk.LANE).astype(np.float32)
     x2d, _ = pk.pad_to_blocks(jnp.asarray(x), block_rows=8)
-    q, scales = pk.block_quantize(x2d, bits=8, block_rows=8)
+    q, scales = pk.block_quantize(x2d, bits=8, block_rows=8, impl="ref")
     assert q.dtype == jnp.int8
     assert scales.shape == (4, 1)
     deq = (np.asarray(q, np.float32).reshape(4, -1)
@@ -45,7 +46,7 @@ def test_block_quantize_per_block_scales_differ():
     a = np.full(8 * pk.LANE, 100.0, np.float32)
     b = np.full(8 * pk.LANE, 0.01, np.float32)
     x2d = jnp.asarray(np.concatenate([a, b])).reshape(16, pk.LANE)
-    _, scales = pk.block_quantize(x2d, bits=8, block_rows=8)
+    _, scales = pk.block_quantize(x2d, bits=8, block_rows=8, impl="ref")
     s = np.asarray(scales)[:, 0]
     assert s[0] > 100 * s[1]
 
@@ -57,12 +58,12 @@ def test_block_dequant_sum_matches_manual():
     qs, ss = [], []
     for w in range(world):
         x2d = jnp.asarray(rng.randn(rows, pk.LANE).astype(np.float32))
-        q, s = pk.block_quantize(x2d, bits=8, block_rows=br)
+        q, s = pk.block_quantize(x2d, bits=8, block_rows=br, impl="ref")
         qs.append(q)
         ss.append(s)
     q = jnp.stack(qs)
     s = jnp.stack(ss)
-    out = pk.block_dequant_sum(q, s, block_rows=br)
+    out = pk.block_dequant_sum(q, s, block_rows=br, impl="ref")
     manual = sum(
         np.asarray(qs[w], np.float32).reshape(n_blocks, -1)
         * np.asarray(ss[w]) for w in range(world)).reshape(rows, pk.LANE)
@@ -102,7 +103,7 @@ def test_sign_codec_packed_wire():
 def test_blockq_codec_decode_sum(bits):
     rng = np.random.RandomState(4)
     shape = (33, 17)
-    codec = BlockQuantizeCodec(bits=bits, block_rows=8)
+    codec = BlockQuantizeCodec(bits=bits, block_rows=8, impl="ref")
     grads = [jnp.asarray(rng.randn(*shape).astype(np.float32))
              for _ in range(4)]
     codes = [codec.encode(g) for g in grads]
@@ -130,7 +131,7 @@ def test_blockq_in_ps_step(mesh8):
         return jnp.mean((pred - batch["y"]) ** 2)
 
     opt = SGD(list(params.items()), lr=0.05, mesh=mesh8,
-              code=BlockQuantizeCodec(8, block_rows=8))
+              code=BlockQuantizeCodec(8, block_rows=8, impl="ref"))
     opt.compile_step(loss_fn)
     batch = {"x": rng.randn(16, 20).astype(np.float32),
              "y": rng.randn(16, 4).astype(np.float32)}
@@ -179,7 +180,7 @@ def test_cast_codec_fused_decode_sum_matches_generic(shape):
 
     rng = np.random.RandomState(1)
     world = 5
-    codec = CastCodec()
+    codec = CastCodec(impl="ref")
     grads = [jnp.asarray(np.asarray(3 * rng.randn(*shape), np.float32))
              for _ in range(world)]
     codes = _stack_codes(codec, grads)
@@ -197,7 +198,7 @@ def test_cast_codec_accumulates_in_f32_not_wire_dtype():
     fused kernel's f32 accumulator must not."""
     from pytorch_ps_mpi_tpu.ops.codecs import CastCodec
 
-    codec = CastCodec()
+    codec = CastCodec(impl="ref")
     world, n = 64, 256
     # 64 ranks each contribute 1.0 + tiny; a bf16 accumulator would round
     # the tiny parts away long before rank 64.
@@ -242,3 +243,73 @@ def test_cast_codec_in_ps_step(mesh8):
     np.testing.assert_allclose(loss_bf, loss_id, rtol=5e-2)
     for n in p_id:
         np.testing.assert_allclose(p_bf[n], p_id[n], rtol=5e-2, atol=5e-3)
+
+
+# ---------------------------------------------------------------------------
+# kernel dispatch: chosen from the devices' platform, never silently
+# ---------------------------------------------------------------------------
+
+
+def test_impl_follows_the_platform_and_refuses_unknown_ones():
+    from pytorch_ps_mpi_tpu.errors import KernelPlatformError
+
+    assert pk.impl_for_platform("tpu") == "mosaic"
+    assert pk.impl_for_platform("cpu") == "ref"
+    assert pk.impl_for_platform("cpu", cpu="interpret") == "interpret"
+    with pytest.raises(KernelPlatformError):
+        pk.impl_for_platform("gpu")
+
+
+def test_mosaic_codec_on_cpu_mesh_is_a_typed_error(mesh2):
+    """The default codec construction means the TPU kernel; handing it to
+    an optimizer built on CPU devices raises instead of quietly running
+    the reference."""
+    from pytorch_ps_mpi_tpu import SGD
+    from pytorch_ps_mpi_tpu.errors import KernelPlatformError
+    from pytorch_ps_mpi_tpu.ops.codecs import CastCodec, get_codec
+
+    named = [("w", np.zeros((4, 4), np.float32))]
+    for codec in (BlockQuantizeCodec(), CastCodec()):
+        assert codec.impl == "mosaic"
+        with pytest.raises(KernelPlatformError, match="mosaic"):
+            SGD(named, lr=0.1, mesh=mesh2, code=codec)
+        with pytest.raises(KernelPlatformError):
+            get_codec(codec, "cpu")
+    # By name, the CPU mesh gets the reference; a TPU build gets Mosaic.
+    assert SGD(named, lr=0.1, mesh=mesh2, code="blockq").code.impl == "ref"
+    assert get_codec("blockq", "tpu").impl == "mosaic"
+    assert get_codec("bf16", "tpu").impl == "mosaic"
+
+
+def test_interpreter_and_reference_only_on_request(monkeypatch):
+    """``impl`` alone decides which implementation runs: the default
+    reaches the Mosaic lowering (which the CPU backend refuses, loudly),
+    ``"interpret"`` the kernel under the interpreter, ``"ref"`` the jnp
+    function — no other path reaches them."""
+    x2d = jnp.asarray(np.random.RandomState(0)
+                      .randn(8, pk.LANE).astype(np.float32))
+    with pytest.raises(ValueError, match="interpret mode"):
+        pk.block_quantize(x2d, bits=8, block_rows=8)
+    calls = []
+    monkeypatch.setattr(pk, "block_quantize_ref",
+                        lambda *a, **k: calls.append("ref"))
+    monkeypatch.setattr(pk, "block_quantize_tpu",
+                        lambda *a, interpret, **k: calls.append(
+                            "interpret" if interpret else "mosaic"))
+    for impl in ("interpret", "ref", "mosaic"):
+        pk.block_quantize(x2d, bits=8, block_rows=8, impl=impl)
+    assert calls == ["interpret", "ref", "mosaic"]
+    with pytest.raises(ValueError, match="impl must be"):
+        pk.block_quantize(x2d, bits=8, block_rows=8, impl="dense")
+
+
+def test_flash_attention_default_is_the_mosaic_kernel():
+    from pytorch_ps_mpi_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.zeros((1, 128, 1, 8), jnp.float32)
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_attention(q, q, q, causal=True)
+    out = flash_attention(q, q, q, causal=True, impl="interpret")
+    assert out.shape == q.shape
+    with pytest.raises(ValueError, match="impl must be"):
+        flash_attention(q, q, q, impl="ref")

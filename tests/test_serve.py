@@ -586,10 +586,10 @@ def test_nonblock_heal_keeps_poll_fast_while_ps_is_down():
 
 
 def test_drain_budget_failure_is_not_a_shed():
-    """A blown drain() budget is an engine wedge, not admission
+    """A blown drain() budget is a stuck engine, not admission
     overload (review finding): it must raise TimeoutError — a caller
     backing off-and-retrying on typed InferShedError must never be
-    told to retry against a wedge."""
+    told to retry against a stuck engine."""
     model, params = _tiny_lm()
     fe = InferenceFrontend(model, params, max_batch=1, buf_len=16,
                            max_queue=2)

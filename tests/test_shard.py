@@ -522,7 +522,7 @@ def test_cli_fleet_endurance_kill_shard(tmp_path):
     import subprocess
     import sys as _sys
 
-    from test_multihost_async import _reap_all
+    from test_multihost_async import ChildProc, _reap_all
 
     env_setup = ("import os; os.environ['XLA_FLAGS']=os.environ.get("
                  "'XLA_FLAGS','')+' --xla_force_host_platform_device_count=1'"
@@ -533,22 +533,20 @@ def test_cli_fleet_endurance_kill_shard(tmp_path):
     base = ("'--model','mlp','--steps','16','--quota','1',"
             "'--batch-size','32','--n-examples','128'")
 
-    server = subprocess.Popen(
+    server = ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--serve','0','--shards','2',{base},'--save','{ckpt}',"
-         f"'--checkpoint-every','2','--chaos','{chaos}'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         f"'--checkpoint-every','2','--chaos','{chaos}'])"])
     line = server.stdout.readline()
     assert line.startswith("serving on ports "), line
     ports = line.strip().split("ports ", 1)[1].split()
     assert len(ports) == 2
     connect = ",".join(f"127.0.0.1:{p}" for p in ports)
 
-    workers = [subprocess.Popen(
+    workers = [ChildProc(
         [_sys.executable, "-c", env_setup +
          f"['--connect','{connect}',{base},"
-         "'--reconnect-retries','100'])"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+         "'--reconnect-retries','100'])"])
         for _ in range(2)]
 
     outs = _reap_all([server] + workers, timeout=420)

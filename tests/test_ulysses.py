@@ -40,9 +40,13 @@ def test_ulysses_matches_dense(causal, sp):
 
 
 def test_ulysses_flash_inner_matches_dense():
-    """Ulysses composes with the Pallas flash kernel (interpreted off-TPU):
-    the all_to_all resharding hands it full sequences."""
-    from pytorch_ps_mpi_tpu.ops.flash_attention import flash_attention
+    """Ulysses composes with the Pallas flash kernel (under the
+    interpreter here): the all_to_all resharding hands it full sequences."""
+    import functools
+
+    from pytorch_ps_mpi_tpu.ops import flash_attention as fa
+
+    flash_attention = functools.partial(fa.flash_attention, impl="interpret")
 
     mesh = make_dp_sp_mesh(dp=1, sp=2)
     q, k, v = _qkv(4, b=1, s=256, h=2, d=8)

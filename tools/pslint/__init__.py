@@ -7,10 +7,9 @@ bug log shows chaos testing catches *late* and review catches *by luck*:
   ``# pslint: guarded-by(_lock)`` must only be touched under
   ``with self._lock`` (the ``GUARDED_BY`` idea from Clang's thread-safety
   analysis, scoped to this codebase's handler-thread/serve-loop split);
-* **jit-hygiene** (PSL2xx) — recompile/wedge hazards: ``jax.jit``/``pmap``
-  constructed inside loop bodies (the mid-fill-compile bug class),
-  host-sync calls inside jitted functions and the hot serve/step loops,
-  and ``donate_argnums`` not gated off the CPU backend;
+* **jit-hygiene** (PSL2xx) — recompile/stall hazards: ``jax.jit``/``pmap``
+  constructed inside loop bodies (the mid-fill-compile bug class) and
+  host-sync calls inside jitted functions and the hot serve/step loops;
 * **protocol/stats-drift** (PSL3xx) — wire-frame kinds/field layouts must
   match between encoder and decoder, every bumped fault counter must be
   initialized and rendered, fault snapshots must build on the shared

@@ -43,9 +43,7 @@ PSL704  read-after-donation: a value handed to a donating jitted
         handle (constructed with a LITERAL ``donate_argnums``) or to
         ``jax.device_put(.., donate=True)`` is read again afterwards —
         the buffer was consumed; the read returns garbage or raises,
-        depending on backend.  (Extends the PSL204 platform gate from
-        flags to dataflow; gated non-literal donation is the gate's
-        business, not this rule's.)
+        depending on backend.
 
 Scope and precision: the analysis is a per-function, statement-ordered
 value-flow scan (nested ``def``/``lambda`` bodies are deferred work and
@@ -230,8 +228,7 @@ class _Events:
 
 def _literal_donate_indices(call: ast.Call) -> "list[int] | None":
     """Positional indices of a LITERAL ``donate_argnums=``; None when
-    the call does not donate literally (gated donation is PSL204's
-    concern, not dataflow's)."""
+    the call does not donate literally."""
     for kw in call.keywords:
         if kw.arg != "donate_argnums":
             continue

@@ -1,13 +1,12 @@
 """Checker 2 — JIT-hygiene (PSL2xx).
 
-The recompile/wedge hazard classes the bug log paid for at runtime:
+The recompile/stall hazard classes the bug log paid for at runtime:
 
 PSL201  ``jax.jit``/``jax.pmap`` *constructed* inside a loop body or a
         handler-thread method — every construction is a fresh cache
         entry, and a compile landing mid-fill, concurrent with threaded
-        worker dispatch, wedged the pinned 0.4.x CPU runtime (the PR 4
-        ``_norm_fn`` incident).  Build programs once, at
-        ``compile_step`` time.
+        worker dispatch, stalls the fill (the PR 4 ``_norm_fn``
+        incident).  Build programs once, at ``compile_step`` time.
 PSL202  host-sync inside a jitted function: ``.item()``,
         ``np.asarray``/``np.array``, ``jax.device_get``, or
         ``float()``/``int()``/``bool()`` applied to a traced parameter —
@@ -15,14 +14,9 @@ PSL202  host-sync inside a jitted function: ``.item()``,
         devolves the program to per-call host round trips.
 PSL203  a jit-built handle (``self.X = jax.jit(...)``) *invoked* from a
         handler-thread method: the first call compiles, and a compile on
-        a conn/worker thread races the serve loop's dispatch (the wedge
-        class again).  Keep jitted-program invocation on the serve loop,
+        a conn/worker thread races the serve loop's dispatch (the same
+        stall class).  Keep jitted-program invocation on the serve loop,
         prewarmed at compile time.
-PSL204  ``donate_argnums=`` passed as a literal: donation must route
-        through a platform gate (`MPI_PS._donate`) because the pinned
-        0.4.x CPU runtime mis-executes input-output aliasing
-        (``utils/compat.py``) — a literal reaches the cpu backend
-        ungated.
 """
 
 from __future__ import annotations
@@ -126,7 +120,7 @@ def check(corpus: list[SourceModule],
         for fn in _jitted_function_defs(mod):
             _check_jitted_body(mod, fn, findings)
 
-        # PSL201 (loop half) + PSL204: walk with loop-depth tracking.
+        # PSL201 (loop half): walk with loop-depth tracking.
         class Scan(FunctionStackVisitor):
             def __init__(self):
                 super().__init__()
@@ -149,18 +143,6 @@ def check(corpus: list[SourceModule],
                         hint="hoist construction out of the loop (build "
                              "once at compile_step time and reuse the "
                              "handle)"))
-                for kw in node.keywords:
-                    if kw.arg == "donate_argnums" and isinstance(
-                            kw.value, (ast.Constant, ast.Tuple, ast.List)):
-                        findings.append(Finding(
-                            mod.path, kw.value.lineno, "PSL204", RULE,
-                            "donate_argnums passed as a literal — "
-                            "donation reaches the cpu backend ungated "
-                            "(the pinned 0.4.x CPU runtime mis-executes "
-                            "aliasing; see utils/compat.py)",
-                            hint="route through a platform gate that "
-                                 "resolves to () on cpu, e.g. "
-                                 "MPI_PS._donate(...)"))
                 self.generic_visit(node)
 
         Scan().visit(mod.tree)
@@ -196,7 +178,7 @@ def check(corpus: list[SourceModule],
                         f"{dotted_name(node.func)}() constructed in "
                         f"{cls.name}.{name}, a handler-thread method — "
                         f"the compile races the serve loop's dispatch "
-                        f"(observed to wedge the pinned CPU runtime)",
+                        f"(observed to stall the fill)",
                         hint="construct at compile_step time; handler "
                              "threads only enqueue"))
                 elif (is_self_attr(node.func)
@@ -206,7 +188,7 @@ def check(corpus: list[SourceModule],
                         f"jitted handle self.{node.func.attr} invoked "
                         f"from {cls.name}.{name} (handler-thread "
                         f"context) — a first-call compile here races "
-                        f"the serve loop (the mid-fill-compile wedge "
+                        f"the serve loop (the mid-fill-compile stall "
                         f"class)",
                         hint="invoke jitted programs from the serve "
                              "loop only, prewarmed at compile time; "
