@@ -56,6 +56,7 @@ from .ops.codecs import Codec, IdentityCodec, get_codec
 from .parallel.mesh import default_devices
 from .ps import init_ps_core
 from .utils.bytes import bytes_of
+from .utils.timing import span
 
 Params = "OrderedDict[str, jax.Array]"
 
@@ -923,53 +924,67 @@ class AsyncPS:
                 and getattr(plan, "byzantine_rank", None) == rank):
             fn = self._worker_fn_byz
         while not stop.is_set():
-            if plan is not None and plan.should_slow(rank):
-                # Deterministic straggler: this rank pays the configured
-                # delay before every gradient it computes.
-                time.sleep(plan.slow_delay_s)
-            params, version = published.snapshot()
-            # The "broadcast receive": params live on the PS device; placing
-            # them on the worker device is the param push (ICI transfer on
-            # hardware).  Committed placement makes jit run on this device.
-            params = jax.device_put(params, device)
-            batch = jax.device_put(batch_fn(rank, it), device)
-            loss, codes = fn(params, batch)
-            # The "send to rank 0": move only the *encoded* grads to the PS
-            # device — the compressed payload is what rides the interconnect.
-            codes = jax.device_put(codes, self.ps_device)
-            # Bounded put = MPI-send backpressure: a worker whose grad the PS
-            # hasn't absorbed yet blocks here instead of racing ahead, which
-            # bounds staleness at ~queue_capacity/quota updates.  (An unbounded
-            # queue lets staleness grow linearly and training diverges.)
-            item = (codes, version, rank, loss)
-            extra_flood, extra_burst = (
-                plan.overload_extras(rank, it) if plan is not None
-                else (0, 0))
-            for i in range(1 + extra_flood + extra_burst):
-                placed = False
-                while not stop.is_set():
-                    try:
-                        grad_queue.put(item, timeout=0.05)
-                        placed = True
-                        break
-                    except queue.Full:
-                        continue
-                if i >= 1 and placed:
-                    # Overload injectors (flood_rank / burst_at): the
-                    # same gradient enqueued again as genuine extra
-                    # supply.  Counted under the injector lock — worker
-                    # threads bump concurrently (every rank bursts at
-                    # the same iteration), and the base `_bump` is
-                    # deliberately lock-free for the single-consumer
-                    # serve loop.
-                    key = ("flood_injected" if i <= extra_flood
-                           else "burst_injected")
-                    with self._overload_lock:
-                        self.fault_stats[key] += 1
-            it += 1
-            if self._lockstep:
-                while consumed[rank] < it and not stop.is_set():
-                    time.sleep(0)
+            with span("async.worker_iter", rank=rank, it=it) as iter_span:
+                if plan is not None and plan.should_slow(rank):
+                    # Deterministic straggler: this rank pays the configured
+                    # delay before every gradient it computes.
+                    time.sleep(plan.slow_delay_s)
+                # The "broadcast receive": params live on the PS device;
+                # placing them on the worker device is the param push (ICI
+                # transfer on hardware).  Committed placement makes jit run
+                # on this device.
+                with span("async.snapshot"):
+                    params, version = published.snapshot()
+                    params = jax.device_put(params, device)
+                iter_span.set(version=version)
+                with span("async.draw"):
+                    batch = batch_fn(rank, it)
+                with span("async.put_batch"):
+                    batch = jax.device_put(batch, device)
+                with span("async.grad"):
+                    loss, codes = fn(params, batch)
+                # The "send to rank 0": move only the *encoded* grads to the
+                # PS device — the compressed payload is what rides the
+                # interconnect.
+                with span("async.send"):
+                    codes = jax.device_put(codes, self.ps_device)
+                # Bounded put = MPI-send backpressure: a worker whose grad the
+                # PS hasn't absorbed yet blocks here instead of racing ahead,
+                # which bounds staleness at ~queue_capacity/quota updates.
+                # (An unbounded queue lets staleness grow linearly and
+                # training diverges.)
+                item = (codes, version, rank, loss)
+                extra_flood, extra_burst = (
+                    plan.overload_extras(rank, it) if plan is not None
+                    else (0, 0))
+                retries = 0
+                with span("async.enqueue") as enqueue_span:
+                    for i in range(1 + extra_flood + extra_burst):
+                        placed = False
+                        while not stop.is_set():
+                            try:
+                                grad_queue.put(item, timeout=0.05)
+                                placed = True
+                                break
+                            except queue.Full:
+                                retries += 1
+                        if i >= 1 and placed:
+                            # Overload injectors (flood_rank / burst_at):
+                            # the same gradient enqueued again as genuine
+                            # extra supply.  Counted under the injector lock
+                            # — worker threads bump concurrently (every rank
+                            # bursts at the same iteration), and the base
+                            # `_bump` is deliberately lock-free for the
+                            # single-consumer serve loop.
+                            key = ("flood_injected" if i <= extra_flood
+                                   else "burst_injected")
+                            with self._overload_lock:
+                                self.fault_stats[key] += 1
+                enqueue_span.set(retries=retries)
+                it += 1
+                if self._lockstep:
+                    while consumed[rank] < it and not stop.is_set():
+                        time.sleep(0)
 
     def run(self, batch_fn: Callable[[int, int], Any], steps: int,
             log_every: int = 0) -> dict[str, Any]:
@@ -1064,54 +1079,63 @@ class AsyncPS:
         t_start = time.perf_counter()
         try:
             for update in range(steps):
-                if (self.fault_plan is not None
-                        and self.fault_plan.should_kill_ps(update)):
-                    from .utils.faults import SimulatedCrash
-                    raise SimulatedCrash(
-                        f"FaultPlan: PS killed before update {update}")
-                data: dict[str, float] = {}
-                # --- receive until quota (the ANY_SOURCE loop), or until
-                # quorum + deadline close the fill short — the fill loop
-                # itself is `_fill_gradients`, shared with the TCP server.
-                t0 = time.perf_counter()
-                (batch_codes, stalenesses, losses, ranks, contribs,
-                 fill_target, _short) = self._fill_gradients(
-                    receive, drain_nowait,
-                    current_version=lambda: published.version,
-                    on_consumed=ack_consumed)
-                data["comm_wait"] = time.perf_counter() - t0
+                with span("async.update", update=update):
+                    if (self.fault_plan is not None
+                            and self.fault_plan.should_kill_ps(update)):
+                        from .utils.faults import SimulatedCrash
+                        raise SimulatedCrash(
+                            f"FaultPlan: PS killed before update {update}")
+                    data: dict[str, float] = {}
+                    # --- receive until quota (the ANY_SOURCE loop), or until
+                    # quorum + deadline close the fill short — the fill loop
+                    # itself is `_fill_gradients`, shared with the TCP server.
+                    with span("async.fill") as fill:
+                        (batch_codes, stalenesses, losses, ranks, contribs,
+                         fill_target, _short) = self._fill_gradients(
+                            receive, drain_nowait,
+                            current_version=lambda: published.version,
+                            on_consumed=ack_consumed)
+                    mean_stale = float(np.mean(stalenesses))
+                    fill.set(n=len(batch_codes), ranks=list(ranks),
+                             staleness=mean_stale)
 
-                # --- reduce + step (on the PS device) ----------------------
-                t0 = time.perf_counter()
-                stacked = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *batch_codes)
-                new_params, new_state = self._apply_weighted(
-                    stacked, stalenesses, ranks, data, n_target=fill_target,
-                    contribs=contribs)
-                data["optim_step_time"] = time.perf_counter() - t0
+                    # --- reduce + step (on the PS device) ------------------
+                    with span("async.stack") as stack:
+                        stacked = jax.tree.map(
+                            lambda *xs: jnp.stack(xs), *batch_codes)
+                    with span("async.apply") as apply:
+                        new_params, new_state = self._apply_weighted(
+                            stacked, stalenesses, ranks, data,
+                            n_target=fill_target, contribs=contribs)
 
-                # --- publish (the inconsistent-read broadcast) -------------
-                t0 = time.perf_counter()
-                self.params, self.state = new_params, new_state
-                published.publish(new_params)
-                # Acknowledge consumption only after the publish, so lockstep
-                # workers always see the post-update params.
-                for r in ranks:
-                    consumed[r] += 1
-                data["isend_time"] = time.perf_counter() - t0
-                data["msg_bytes"] = float(bytes_of(batch_codes[0]))
+                    # --- publish (the inconsistent-read broadcast) ---------
+                    with span("async.publish") as publish:
+                        self.params, self.state = new_params, new_state
+                        published.publish(new_params)
+                        # Acknowledge consumption only after the publish, so
+                        # lockstep workers always see the post-update params.
+                        for r in ranks:
+                            consumed[r] += 1
+                    publish.set(version=published.version)
 
-                mean_loss = float(np.mean([float(l) for l in losses]))
-                mean_stale = float(np.mean(stalenesses))
-                history["losses"].append(mean_loss)
-                history["staleness"].append(mean_stale)
-                history["versions"].append(published.version)
-                history["contributors"].append(list(ranks))
-                history["grads_consumed"] += len(batch_codes)
-                self.timings.append(data)
-                if log_every and (update + 1) % log_every == 0:
-                    print(f"async update {update + 1:5d}  loss {mean_loss:.4f}"
-                          f"  staleness {mean_stale:.2f}")
+                    with span("async.read_loss"):
+                        mean_loss = float(np.mean([float(l) for l in losses]))
+                    # The per-update dict is a second view of the same clock
+                    # reads, not a second measurement.
+                    data["comm_wait"] = fill.duration
+                    data["optim_step_time"] = stack.duration + apply.duration
+                    data["isend_time"] = publish.duration
+                    data["msg_bytes"] = float(bytes_of(batch_codes[0]))
+                    history["losses"].append(mean_loss)
+                    history["staleness"].append(mean_stale)
+                    history["versions"].append(published.version)
+                    history["contributors"].append(list(ranks))
+                    history["grads_consumed"] += len(batch_codes)
+                    self.timings.append(data)
+                    if log_every and (update + 1) % log_every == 0:
+                        print(f"async update {update + 1:5d}  "
+                              f"loss {mean_loss:.4f}"
+                              f"  staleness {mean_stale:.2f}")
         finally:
             stop.set()
             for w in workers:

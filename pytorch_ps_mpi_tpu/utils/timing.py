@@ -16,7 +16,13 @@ counts.
 from __future__ import annotations
 
 import contextlib
+import itertools
+import threading
+import time
+from collections import deque
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 # Canonical metric keys, matching the reference step() dict (`ps.py:193`).
 STEP_METRIC_KEYS = (
@@ -391,12 +397,156 @@ def trace(logdir: str):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named host span that shows up inside `trace` output — mark data
-    loading, checkpointing, eval, etc."""
-    import jax
+# ---------------------------------------------------------------------------
+# Spans inside the program
+# ---------------------------------------------------------------------------
+# One primitive for the program's own boundaries (today the in-process
+# `AsyncPS`: its PS loop and its worker threads).  Always on, like the
+# per-step dicts, and bounded, unlike them: a flight recorder that has to be
+# switched on before the stall is the one nobody has.
 
-    return jax.profiler.TraceAnnotation(name)
+SPAN_PREFIX = "ps:"
+SPAN_LOG_CAPACITY = 65536
+# `time.thread_time()` is a system call (no vDSO serves the thread's CPU
+# clock), and on a sandboxed host one costs 5.7 us, against 0.07 us for
+# `perf_counter` (the v5e host's, PR 25).  So a read of it made on the same
+# thread less than this long ago is used again: spans that open and close
+# back to back share one read, and a span's `cpu` is off by at most this
+# much at each end.
+_CPU_READ_REUSE_S = 20e-6
+
+
+class _ThreadSpans(threading.local):
+    """Per thread: the ids of its open spans, outermost first, and its last
+    read of its own CPU clock as ``(perf_counter, thread_time)``."""
+
+    def __init__(self):
+        self.stack: "list[int]" = []
+        self.cpu_read = (float("-inf"), 0.0)
+
+    def cpu(self, now: float) -> float:
+        """The calling thread's CPU seconds, read at most
+        `_CPU_READ_REUSE_S` before ``now``."""
+        read_at, cpu = self.cpu_read
+        if now - read_at >= _CPU_READ_REUSE_S:
+            cpu = time.thread_time()
+            self.cpu_read = (now, cpu)
+        return cpu
+
+
+class SpanLog:
+    """A ring of finished spans, oldest first.  A record is a dict: ``name``,
+    ``thread`` (the thread's name), ``start`` and ``end`` on
+    ``time.perf_counter()``, ``cpu`` (the thread's own CPU seconds inside the
+    span, so that ``end - start - cpu`` is the time it was off the CPU:
+    waiting for the GIL, a lock, a queue or the device), ``id``, ``parent``
+    (the ``id`` of the enclosing span on the same thread, or None) and
+    whatever ids the span was given.
+
+    Several threads append while another reads, so every access to the ring
+    goes through one small lock, as in `RequestLatency`."""
+
+    def __init__(self, capacity: int = SPAN_LOG_CAPACITY):
+        self._lock = threading.Lock()
+        self._ring: "deque[dict[str, Any]]" = deque(maxlen=int(capacity))
+        self._ids = itertools.count(1)   # `next` is one C call: atomic
+        self._threads = _ThreadSpans()
+        self.dropped = 0                 # records that fell off the front
+        self.dropped_until: "float | None" = None   # the last one's `end`
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._ring)
+
+    def _append(self, record: "dict[str, Any]") -> None:
+        with self._lock:
+            if len(self._ring) == self._ring.maxlen:
+                self.dropped += 1
+                self.dropped_until = self._ring[0]["end"]
+            self._ring.append(record)
+
+    def records(self, name: "str | None" = None, thread: "str | None" = None,
+                since: "float | None" = None,
+                until: "float | None" = None) -> "list[dict[str, Any]]":
+        """Copies of the records, oldest first: those called ``name``, from
+        the thread called ``thread``, that started at or after ``since`` and
+        ended at or before ``until`` (each filter only where given)."""
+        with self._lock:
+            return [dict(r) for r in self._ring
+                    if (name is None or r["name"] == name)
+                    and (thread is None or r["thread"] == thread)
+                    and (since is None or r["start"] >= since)
+                    and (until is None or r["end"] <= until)]
+
+    def clear(self) -> None:
+        with self._lock:
+            self._ring.clear()
+            self.dropped = 0
+            self.dropped_until = None
+
+
+class _Span:
+    """One open span (see `span`)."""
+
+    __slots__ = ("_log", "_record", "_annotation", "_cpu0", "duration")
+
+    def __init__(self, log: SpanLog, name: str, ids: "dict[str, Any]"):
+        self._log = log
+        self._record = {**ids, "name": name}
+        self._annotation = TraceAnnotation(SPAN_PREFIX + name, **ids)
+        self.duration: "float | None" = None    # seconds, once closed
+
+    def __enter__(self) -> "_Span":
+        log, record = self._log, self._record
+        mine = log._threads
+        stack = mine.stack
+        record["thread"] = threading.current_thread().name
+        record["id"] = next(log._ids)
+        record["parent"] = stack[-1] if stack else None
+        stack.append(record["id"])
+        self._annotation.__enter__()
+        self._cpu0 = mine.cpu(time.perf_counter())
+        record["start"] = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        log, record = self._log, self._record
+        mine = log._threads
+        record["end"], record["cpu"] = end, mine.cpu(end) - self._cpu0
+        self._annotation.__exit__(*exc)
+        mine.stack.pop()
+        self.duration = end - record["start"]
+        log._append(record)
+
+    def set(self, **ids) -> None:
+        """Complete the record with what is known only after the body ran
+        (also after the span has closed)."""
+        with self._log._lock:
+            self._record.update(ids)
+
+
+_SPAN_LOG = SpanLog()
+
+
+def span_log() -> SpanLog:
+    """The process-wide span log."""
+    return _SPAN_LOG
+
+
+def span(name: str, **ids) -> _Span:
+    """A named span round one boundary of the program::
+
+        with span("async.fill", update=u) as s:
+            ...
+        s.set(n=len(codes))
+
+    On exit one record goes into `span_log()`.  While it is open the span is
+    also a ``jax.profiler.TraceAnnotation("ps:" + name, **ids)``: inside
+    `trace(logdir)` it shows on its host thread beside the device's
+    operations, on the profiler's clock; with no session it costs a flag
+    test."""
+    return _Span(_SPAN_LOG, name, ids)
 
 
 def print_summary(timings: list[dict[str, Any]], keys=None) -> None:

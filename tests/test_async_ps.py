@@ -49,6 +49,67 @@ def test_async_converges_multiworker():
     assert opt.timings[0]["msg_bytes"] > 0
 
 
+def test_run_leaves_spans_and_timings_is_a_view_of_them():
+    """Every boundary of the in-process loop is one span of the process-wide
+    log; `timings` reads the same clock reads; `history` is as it was."""
+    from pytorch_ps_mpi_tpu.utils.timing import span_log
+
+    named, X, Y = make_problem(seed=5)
+    opt = AsyncSGD(named, lr=0.05, quota=1, devices=[jax.devices()[0]])
+    opt.compile_step(loss_fn)
+    steps = 6
+    span_log().clear()
+    hist = opt.run(dataset_batch_fn(X, Y, 16, seed=5), steps=steps)
+    log = span_log().records()
+    assert span_log().dropped == 0
+
+    updates = [r for r in log if r["name"] == "async.update"]
+    assert [u["update"] for u in updates] == list(range(steps))
+    assert {u["thread"] for u in updates} == {"MainThread"}
+    for i, u in enumerate(updates):
+        children = sorted((r for r in log if r["parent"] == u["id"]),
+                          key=lambda r: r["start"])
+        assert [c["name"] for c in children] == [
+            "async.fill", "async.stack", "async.apply", "async.publish",
+            "async.read_loss"]
+        assert all(u["start"] <= c["start"] <= c["end"] <= u["end"]
+                   for c in children)
+        fill, stack, apply, publish, _ = children
+        assert fill["n"] == 1 and fill["ranks"] == [0]
+        assert fill["staleness"] == hist["staleness"][i]
+        assert publish["version"] == hist["versions"][i]
+        # one measurement, two views: exactly the same clock reads
+        t = opt.timings[i]
+        assert t["comm_wait"] == fill["end"] - fill["start"]
+        assert t["optim_step_time"] == (stack["end"] - stack["start"]) \
+            + (apply["end"] - apply["start"])
+        assert t["isend_time"] == publish["end"] - publish["start"]
+        assert t["msg_bytes"] > 0
+
+    iters = [r for r in log if r["name"] == "async.worker_iter"]
+    assert len(iters) >= steps
+    assert {r["thread"] for r in iters} == {"async-ps-worker-0"}
+    assert [r["it"] for r in iters] == list(range(len(iters)))
+    assert all(r["rank"] == 0 and r["parent"] is None for r in iters)
+    # versions only grow, and a gradient the PS used was read no later
+    assert [r["version"] for r in iters] == sorted(r["version"] for r in iters)
+    first = sorted((r for r in log if r["parent"] == iters[0]["id"]),
+                   key=lambda r: r["start"])
+    assert [c["name"] for c in first] == [
+        "async.snapshot", "async.draw", "async.put_batch", "async.grad",
+        "async.send", "async.enqueue"]
+    assert first[-1]["retries"] >= 0
+    assert all(c["thread"] == "async-ps-worker-0" for c in first)
+
+    assert set(hist) == {"losses", "staleness", "versions", "contributors",
+                         "grads_consumed", "wall_time", "fault_stats"}
+    assert all(len(hist[k]) == steps for k in
+               ("losses", "staleness", "versions", "contributors"))
+    assert hist["grads_consumed"] == steps and len(opt.timings) == steps
+    assert set(opt.timings[0]) == {"comm_wait", "optim_step_time",
+                                   "isend_time", "msg_bytes"}
+
+
 def test_async_quota_one_fully_async():
     """quota=1: update on every arriving grad.  With W workers the gradient
     delay is O(W) updates (each update drains 1 of W outstanding grads) — the

@@ -384,9 +384,9 @@ def test_tamper_flood_bump_lock_stripped_fires_psl802_races(tmp_path):
     # single-writer contract must convict at exactly the bump line.
     pkg, line = _tamper_package(
         tmp_path, "async_ps.py",
-        "                    with self._overload_lock:\n"
-        "                        self.fault_stats[key] += 1\n",
-        "                    self.fault_stats[key] += 1\n")
+        "                            with self._overload_lock:\n"
+        "                                self.fault_stats[key] += 1\n",
+        "                            self.fault_stats[key] += 1\n")
     assert _active_ids(pkg) == {("PSL802", line)}
 
 
