@@ -87,6 +87,18 @@ def make_worker_step(loss_fn: Callable, code: Codec, grad_transform=None):
     return jax.jit(worker_step)
 
 
+@jax.jit
+def _stack_codes(*codes):
+    """The fill's code trees with a leading contribution axis on every leaf,
+    as ONE program (a compile per count of contributions, as `ps_apply`).
+    Stacked leaf by leaf — an eager ``jnp.stack`` each, 161 of them for a
+    ResNet-50 — the PS thread's dispatches queue behind the gradient program
+    that a worker sharing the device has just dispatched, and the runtime
+    holds the thread until that program ends: PS loop and worker then take
+    turns instead of running side by side (PERF.md, Findings PR 27)."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *codes)
+
+
 class _Published:
     """The broadcast surface: a leaf-wise-updated params dict plus a version
     counter.  Readers take no lock (inconsistent reads by design); the version
@@ -108,6 +120,81 @@ class _Published:
         return OrderedDict((n, self.leaves[n]) for n in self.leaves), v
 
 
+class BatchDrawer:
+    """One worker's batches, drawn one iteration ahead of their use.
+
+    A helper thread (``async-ps-worker-{rank}-draw``) calls
+    ``batch_fn(rank, it)`` for ``it = 0, 1, 2, ...`` — in that order, once
+    each, all from that one thread, each call inside
+    ``span("async.draw", rank=rank, it=it)`` — and hands the batch to the
+    worker through a hand-off that holds ONE.  So at most one finished batch
+    waits while the next is being drawn: two host batches ahead of use,
+    never more.  One thread, not a pool: a second would call ``batch_fn``
+    out of order, and a ``batch_fn`` that wraps an iterator is a legitimate
+    user.
+
+    The worker it belongs to is its context manager: entering starts the
+    thread; leaving stops it, joins it and drops the batch left waiting.
+    Whatever ``batch_fn`` raises rides the hand-off in the batch's place and
+    is raised by the `take` of the iteration it belongs to, on the worker's
+    thread, where it would have been raised without the drawer."""
+
+    # Seconds between two looks at a stop flag while blocked on the
+    # hand-off, as `async.enqueue` polls the gradient queue.
+    _POLL = 0.05
+
+    def __init__(self, batch_fn: Callable[[int, int], Any], rank: int):
+        self._batch_fn, self._rank = batch_fn, rank
+        self._handoff: "queue.Queue" = queue.Queue(maxsize=1)
+        self._closed = threading.Event()
+        self._thread = threading.Thread(
+            target=self._draw_loop, daemon=True,
+            name=f"async-ps-worker-{rank}-draw")
+
+    def __enter__(self) -> "BatchDrawer":
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._closed.set()
+        self._thread.join(timeout=5.0)
+        try:
+            self._handoff.get_nowait()
+        except queue.Empty:
+            pass
+
+    def _draw_loop(self) -> None:
+        it, failed = 0, False
+        while not (failed or self._closed.is_set()):
+            try:
+                with span("async.draw", rank=self._rank, it=it):
+                    item = (self._batch_fn(self._rank, it), None)
+            except BaseException as exc:   # re-raised by `take`
+                item, failed = (None, exc), True
+            while not self._closed.is_set():
+                try:
+                    self._handoff.put(item, timeout=self._POLL)
+                    break
+                except queue.Full:
+                    pass
+            del item    # the hand-off's, then the worker's: not held here
+            it += 1
+
+    def take(self, stop: threading.Event) -> "tuple[Any, bool]":
+        """``(batch, ready)``: the next batch in order, and whether it was
+        already waiting.  ``(None, False)`` once ``stop`` is set."""
+        ready = not self._handoff.empty()   # one taker: what is there stays
+        while not stop.is_set():
+            try:
+                batch, exc = self._handoff.get(timeout=self._POLL)
+            except queue.Empty:
+                continue
+            if exc is not None:
+                raise exc
+            return batch, ready
+        return None, False
+
+
 class AsyncPS:
     """Host-driven asynchronous parameter server (AsySG-InCon).
 
@@ -118,7 +205,12 @@ class AsyncPS:
         history = opt.run(batch_fn, steps=500)
 
     ``batch_fn(rank, it) -> batch`` supplies worker ``rank``'s ``it``-th local
-    batch (the analogue of each MPI rank reading its own data shard).
+    batch (the analogue of each MPI rank reading its own data shard).  Each
+    rank's calls come in order of ``it`` (0, 1, 2, ...), once each, all from
+    one helper thread of that rank's worker (`BatchDrawer`), up to two
+    iterations ahead of the gradient that uses the batch — so ``batch_fn`` may
+    wrap an iterator, is drawn up to two batches past the end of a ``run``,
+    and must not depend on the parameters: it runs beside the updates.
 
     ``quota`` is the number of gradients the PS consumes per update
     (`/root/reference/README.md:66-70` hard-codes 32); gradients left in the
@@ -909,14 +1001,15 @@ class AsyncPS:
                      grad_queue: "queue.Queue", stop: threading.Event,
                      consumed: list[int], errors: list):
         try:
-            self._worker_body(rank, device, batch_fn, published, grad_queue,
-                              stop, consumed)
+            with BatchDrawer(batch_fn, rank) as drawer:
+                self._worker_body(rank, device, drawer, published,
+                                  grad_queue, stop, consumed)
         except Exception as exc:  # propagate to the PS loop, don't die silent
             errors.append((rank, exc))
 
-    def _worker_body(self, rank: int, device, batch_fn, published: _Published,
-                     grad_queue: "queue.Queue", stop: threading.Event,
-                     consumed: list[int]):
+    def _worker_body(self, rank: int, device, drawer: BatchDrawer,
+                     published: _Published, grad_queue: "queue.Queue",
+                     stop: threading.Event, consumed: list[int]):
         it = 0
         plan = self.fault_plan
         fn = self._worker_fn
@@ -929,16 +1022,20 @@ class AsyncPS:
                     # Deterministic straggler: this rank pays the configured
                     # delay before every gradient it computes.
                     time.sleep(plan.slow_delay_s)
+                with span("async.await_batch", it=it) as await_span:
+                    batch, ready = drawer.take(stop)
+                await_span.set(ready=ready)
+                if stop.is_set():
+                    break
                 # The "broadcast receive": params live on the PS device;
                 # placing them on the worker device is the param push (ICI
                 # transfer on hardware).  Committed placement makes jit run
-                # on this device.
+                # on this device.  Read only once the batch is in hand: the
+                # parameters do not age while the worker waits for data.
                 with span("async.snapshot"):
                     params, version = published.snapshot()
                     params = jax.device_put(params, device)
                 iter_span.set(version=version)
-                with span("async.draw"):
-                    batch = batch_fn(rank, it)
                 with span("async.put_batch"):
                     batch = jax.device_put(batch, device)
                 with span("async.grad"):
@@ -1101,8 +1198,7 @@ class AsyncPS:
 
                     # --- reduce + step (on the PS device) ------------------
                     with span("async.stack") as stack:
-                        stacked = jax.tree.map(
-                            lambda *xs: jnp.stack(xs), *batch_codes)
+                        stacked = _stack_codes(*batch_codes)
                     with span("async.apply") as apply:
                         new_params, new_state = self._apply_weighted(
                             stacked, stalenesses, ranks, data,
@@ -1217,7 +1313,9 @@ def dataset_batch_fn(x: np.ndarray, y: np.ndarray, batch_size: int,
                      *, seed: int = 0) -> Callable[[int, int], dict]:
     """Build a ``batch_fn`` sampling random minibatches per (rank, it) — each
     worker draws from its own deterministic stream, the analogue of per-rank
-    data shards under ``mpirun``."""
+    data shards under ``mpirun``.  `AsyncPS.run` calls it in order of ``it``,
+    once each, on a helper thread of the rank's worker, up to two iterations
+    ahead of use; the sample depends on (seed, rank, it) alone."""
     n = x.shape[0]
 
     def batch_fn(rank: int, it: int) -> dict:
@@ -1234,7 +1332,9 @@ def lm_batch_fn(toks: np.ndarray, batch_size: int,
                 *, seed: int = 0) -> Callable[[int, int], dict]:
     """`dataset_batch_fn` for token rows ``[n, S+1]``: each worker draws its
     own deterministic row sample and builds the {tokens, targets, positions}
-    dict (`models.transformer.lm_batch`)."""
+    dict (`models.transformer.lm_batch`).  Called as `dataset_batch_fn` is:
+    in order of ``it``, once each, on a helper thread of the rank's worker,
+    up to two iterations ahead of use."""
     from .models.transformer import lm_batch
 
     n = toks.shape[0]

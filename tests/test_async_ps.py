@@ -6,6 +6,9 @@ Workers are virtual CPU devices driven by host threads; the tests exercise the
 real async machinery (thread-dispatched jitted programs, cross-device
 transfers, the unlocked publish/snapshot surface)."""
 
+import threading
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -91,15 +94,25 @@ def test_run_leaves_spans_and_timings_is_a_view_of_them():
     assert {r["thread"] for r in iters} == {"async-ps-worker-0"}
     assert [r["it"] for r in iters] == list(range(len(iters)))
     assert all(r["rank"] == 0 and r["parent"] is None for r in iters)
-    # versions only grow, and a gradient the PS used was read no later
-    assert [r["version"] for r in iters] == sorted(r["version"] for r in iters)
+    # versions only grow, and a gradient the PS used was read no later; only
+    # the iteration that `stop` caught waiting for its batch read none
+    assert all("version" in r for r in iters[:-1])
+    versions = [r["version"] for r in iters if "version" in r]
+    assert versions == sorted(versions)
     first = sorted((r for r in log if r["parent"] == iters[0]["id"]),
                    key=lambda r: r["start"])
     assert [c["name"] for c in first] == [
-        "async.snapshot", "async.draw", "async.put_batch", "async.grad",
-        "async.send", "async.enqueue"]
+        "async.await_batch", "async.snapshot", "async.put_batch",
+        "async.grad", "async.send", "async.enqueue"]
+    assert first[0]["it"] == 0 and first[0]["ready"] in (True, False)
     assert first[-1]["retries"] >= 0
     assert all(c["thread"] == "async-ps-worker-0" for c in first)
+    # the draws are the drawer thread's, one iteration ahead, in order
+    draws = [r for r in log if r["name"] == "async.draw"]
+    assert {r["thread"] for r in draws} == {"async-ps-worker-0-draw"}
+    assert [r["it"] for r in draws] == list(range(len(draws)))
+    assert all(r["rank"] == 0 and r["parent"] is None for r in draws)
+    assert len(iters) <= len(draws) <= len(iters) + 2
 
     assert set(hist) == {"losses", "staleness", "versions", "contributors",
                          "grads_consumed", "wall_time", "fault_stats"}
@@ -204,6 +217,139 @@ def test_async_worker_failure_surfaces():
 
     with pytest.raises(RuntimeError, match="worker"):
         opt.run(bad_batch_fn, steps=1)
+
+
+def _draw_threads():
+    return [t.name for t in threading.enumerate() if t.name.endswith("-draw")]
+
+
+@pytest.mark.parametrize("lockstep", [True, False])
+def test_batches_are_drawn_in_order_once_each_on_one_thread(lockstep):
+    """`batch_fn(rank, it)` is called for it = 0, 1, 2, ... in that order,
+    once each, from one thread per rank, and never more than two past the
+    last batch its worker used (in lockstep: the last gradient the PS
+    consumed)."""
+    from pytorch_ps_mpi_tpu.utils.timing import span_log
+
+    named, X, Y = make_problem(seed=7)
+    inner = dataset_batch_fn(X, Y, 16, seed=7)
+    calls, lock = [], threading.Lock()
+
+    def batch_fn(rank, it):
+        with lock:
+            calls.append((rank, it, threading.current_thread().name))
+        return inner(rank, it)
+
+    opt = AsyncSGD(named, lr=0.02, quota=1, devices=jax.devices()[:3])
+    assert opt.num_workers == 2
+    opt._lockstep = lockstep
+    opt.compile_step(loss_fn)
+    span_log().clear()
+    hist = opt.run(batch_fn, steps=12)
+    assert hist["grads_consumed"] == 12 and not _draw_threads()
+
+    for rank in range(opt.num_workers):
+        worker = f"async-ps-worker-{rank}"
+        mine = [c for c in calls if c[0] == rank]
+        assert [it for _, it, _ in mine] == list(range(len(mine)))
+        assert {name for *_, name in mine} <= {worker + "-draw"}
+        # a batch the worker took is one it asked for, in the same order
+        iters = span_log().records("async.worker_iter", thread=worker)
+        took = [r["it"] for r in iters if "version" in r]
+        assert took == list(range(len(took)))
+        # one batch waits while the next is drawn: two ahead of the last
+        # one the worker took (which `stop` may have made it drop), no more
+        assert len(took) <= len(mine) <= len(iters) + 2
+        if lockstep:
+            # the worker takes one batch more after the PS's last ack (as it
+            # drew one more before there was a drawer), the drawer two
+            consumed = sum(r == rank for rs in hist["contributors"]
+                           for r in rs)
+            assert consumed <= len(took) <= consumed + 1
+
+
+def test_next_batch_is_drawn_while_the_worker_works():
+    """With a `batch_fn` that takes 30 ms, the draw of `it + 1` starts before
+    iteration `it` ends; with one that returns at once, the batch is there
+    when the worker asks (`await_batch.ready`)."""
+    from pytorch_ps_mpi_tpu.utils.timing import span_log
+
+    named, X, Y = make_problem(seed=8)
+    inner = dataset_batch_fn(X, Y, 16, seed=8)
+
+    def slow_batch_fn(rank, it):
+        time.sleep(0.03)
+        return inner(rank, it)
+
+    opt = AsyncSGD(named, lr=0.02, quota=1, devices=[jax.devices()[0]])
+    opt.compile_step(loss_fn)
+    span_log().clear()
+    opt.run(slow_batch_fn, steps=8)
+    log = span_log()
+    iters = {r["it"]: r for r in log.records("async.worker_iter")}
+    draws = {r["it"]: r for r in log.records("async.draw")}
+    assert len(iters) >= 8
+    for it, r in iters.items():
+        if it + 1 in iters:      # not the iteration `stop` cut short
+            assert draws[it + 1]["start"] < r["end"]
+            assert draws[it + 1]["end"] - draws[it + 1]["start"] >= 0.03
+    awaits = log.records("async.await_batch")
+    assert not awaits[0]["ready"]     # nothing is drawn before the run
+
+    span_log().clear()
+    opt.run(lambda rank, it: inner(0, 0), steps=20)
+    ready = [r["ready"] for r in span_log().records("async.await_batch")]
+    assert len(ready) >= 20 and sum(ready) >= len(ready) // 2
+
+
+def test_batch_fn_failure_surfaces_at_its_iteration_and_no_drawer_outlives_run():
+    from pytorch_ps_mpi_tpu.errors import WorkerFailedError
+    from pytorch_ps_mpi_tpu.utils.timing import span_log
+
+    named, X, Y = make_problem(seed=9)
+    inner = dataset_batch_fn(X, Y, 16, seed=9)
+
+    def batch_fn(rank, it):
+        if it == 3:
+            raise KeyError("row 3 is missing")
+        return inner(rank, it)
+
+    opt = AsyncSGD(named, lr=0.02, quota=1, devices=[jax.devices()[0]])
+    opt.compile_step(loss_fn)
+    span_log().clear()
+    with pytest.raises(WorkerFailedError, match="worker 0") as failure:
+        opt.run(batch_fn, steps=50)
+    assert isinstance(failure.value.__cause__, KeyError)
+    assert "row 3 is missing" in str(failure.value.__cause__)
+    assert not _draw_threads()
+    # the three batches before it were used, and nothing was drawn after it
+    assert [r["it"] for r in span_log().records("async.draw")] == [0, 1, 2, 3]
+    iters = span_log().records("async.worker_iter")
+    assert [r["it"] for r in iters if "version" in r] == [0, 1, 2]
+
+    # the next run starts its drawer again at it = 0
+    hist = opt.run(inner, steps=5)
+    assert hist["grads_consumed"] == 5 and not _draw_threads()
+
+
+@pytest.mark.parametrize("n", [1, 3])
+def test_the_fill_is_stacked_by_one_program(n):
+    """`run()` stacks a fill's code trees with one jitted call; leaf for
+    leaf it is the eager `jnp.stack` it replaced, whatever the codec's code
+    looks like (a tuple of arrays here)."""
+    from pytorch_ps_mpi_tpu.async_ps import _stack_codes
+
+    rng = np.random.RandomState(n)
+    codec = QuantizeCodec(8)
+    codes = [{"w": codec.encode(jnp.asarray(rng.randn(6, 3), jnp.float32)),
+              "b": codec.encode(jnp.asarray(rng.randn(3), jnp.float32))}
+             for _ in range(n)]
+    got = _stack_codes(*codes)
+    want = jax.tree.map(lambda *xs: jnp.stack(xs), *codes)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.shape[0] == n and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_dataset_batch_fn_large_seed_and_distinct_streams():
