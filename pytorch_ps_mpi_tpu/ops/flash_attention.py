@@ -61,6 +61,15 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps masked-row math
                  # finite without jnp.where laundering inside the kernel
 
 
+def _named(kernel: str) -> dict:
+    """A stable name for one `pallas_call`, twice: `name=` (the Mosaic
+    kernel's own name) and `metadata=`, which is what reaches the device
+    trace — an event of a Mosaic call is named by its HLO instruction, and
+    of the two only the metadata is in it
+    (``frontend_attributes={kernel_metadata={"kernel":"flash_fwd"}}``)."""
+    return {"name": kernel, "metadata": {"kernel": kernel}}
+
+
 def _pad_to(x, size, axis):
     want = -(-x.shape[axis] // size) * size
     if want == x.shape[axis]:
@@ -133,15 +142,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
 def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
               blk_q=None, blk_k=None):
-    """``q3,k3,v3: [BH, S_pad, D_pad]`` already padded to BLOCK/lane tiles;
-    returns ``(out [BH, S_pad, D_pad], lse [BH, S_pad])``.  ``true_len``
-    masks the padded K tail so it carries no softmax mass.
+    """``q3,k3: [BH, S_pad, D_pad]``, ``v3: [BH, S_pad, Dv_pad]`` already
+    padded to BLOCK/lane tiles (``Dv_pad`` may differ from ``D_pad``: the
+    accumulator and the output take v's width); returns ``(out [BH, S_pad,
+    Dv_pad], lse [BH, S_pad])``.  ``true_len`` masks the padded K tail so
+    it carries no softmax mass.
 
     Tile sizes clamp to the (padded) sequence: big BLOCK_Q×BLOCK_K tiles
     amortize grid-step overhead and keep the MXU fed (the 128×128 version
     measured ~2.4× slower than XLA dense at S=4096); short sequences fall
     back to one tile."""
     bh, s_pad, d = q3.shape
+    dv = v3.shape[-1]
     blk_q = min(BLOCK_Q if blk_q is None else blk_q, s_pad)
     blk_k = min(BLOCK_K if blk_k is None else blk_k, s_pad)
     n_q, n_k = -(-s_pad // blk_q), -(-s_pad // blk_k)
@@ -159,22 +171,23 @@ def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, blk_k, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_q, BLOCK), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_pad_q, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, s_pad_q, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, s_pad_q, BLOCK), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_q, d), jnp.float32),      # acc
+            pltpu.VMEM((blk_q, dv), jnp.float32),     # acc
             pltpu.VMEM((blk_q, BLOCK), jnp.float32),  # m (lane-replicated)
             pltpu.VMEM((blk_q, BLOCK), jnp.float32),  # l
         ],
         interpret=interpret,
+        **_named("flash_fwd"),
     )(q3, k3, v3)
     return out, lse
 
@@ -203,7 +216,7 @@ def _flash_fwd_res(q, k, v, causal, scale, interpret):
     v3 = _pad_to(_pad_to(_to_bh(v), BLOCK, 1), BLOCK, 2)
     out3, lse3 = _fwd_call(q3, k3, v3, causal=causal, scale=scale,
                            true_len=s, interpret=interpret)
-    out = _from_bh(out3[:, :s, :d], b, h)
+    out = _from_bh(out3[:, :s, :v.shape[-1]], b, h)
     lse = lse3[:, :s, 0].reshape(b, h, s)
     return out, (q, k, v, out, lse)
 
@@ -293,11 +306,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
               interpret, blk_q=None, blk_k=None):
-    """``q3,k3,v3,do3: [BH, S_pad, D_pad]``; ``lse2, delta2:
-    [BH, S_pad, BLOCK]`` f32, lane-replicated (same MIN_BLOCK_SIZE trick as
+    """``q3,k3: [BH, S_pad, D_pad]``; ``v3,do3: [BH, S_pad, Dv_pad]``;
+    ``lse2, delta2: [BH, S_pad, BLOCK]`` f32, lane-replicated (same MIN_BLOCK_SIZE trick as
     the forward's lse output — Mosaic wants (8k, 128k) tiles, the kernels
     read lane 0).  Returns ``(dq, dk, dv)`` padded like the inputs."""
     bh, s_pad, d = q3.shape
+    dv = v3.shape[-1]
     blk_q = min(BWD_BLOCK_Q if blk_q is None else blk_q, s_pad)
     blk_k = min(BWD_BLOCK_K if blk_k is None else blk_k, s_pad)
     n_q, n_k = -(-s_pad // blk_q), -(-s_pad // blk_k)
@@ -320,24 +334,25 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, j, i: (b, i, 0)),   # q
             pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0)),   # k
-            pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0)),   # v
-            pl.BlockSpec((1, blk_q, d), lambda b, j, i: (b, i, 0)),   # dout
+            pl.BlockSpec((1, blk_k, dv), lambda b, j, i: (b, j, 0)),  # v
+            pl.BlockSpec((1, blk_q, dv), lambda b, j, i: (b, i, 0)),  # dout
             pl.BlockSpec((1, blk_q, BLOCK), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, blk_q, BLOCK), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, blk_k, dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, n_k * blk_k, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, n_k * blk_k, d), v3.dtype),
+            jax.ShapeDtypeStruct((bh, n_k * blk_k, dv), v3.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_k, d), jnp.float32),
-            pltpu.VMEM((blk_k, d), jnp.float32),
+            pltpu.VMEM((blk_k, dv), jnp.float32),
         ],
         interpret=interpret,
+        **_named("flash_bwd_dkdv"),
     )(q3, k3, v3, do3, lse2, delta2)
 
     dq3 = pl.pallas_call(
@@ -346,8 +361,8 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),   # q
             pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),   # k
-            pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),   # v
-            pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),   # dout
+            pl.BlockSpec((1, blk_k, dv), lambda b, i, j: (b, j, 0)),  # v
+            pl.BlockSpec((1, blk_q, dv), lambda b, i, j: (b, i, 0)),  # dout
             pl.BlockSpec((1, blk_q, BLOCK), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_q, BLOCK), lambda b, i, j: (b, i, 0)),
         ],
@@ -355,6 +370,7 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
         out_shape=jax.ShapeDtypeStruct((bh, n_q * blk_q, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         interpret=interpret,
+        **_named("flash_bwd_dq"),
     )(q3, k3, v3, do3, lse2, delta2)
     return dq3[:, :s_pad], dk3[:, :s_pad], dv3[:, :s_pad]
 
@@ -378,8 +394,8 @@ def _flash_bwd(causal, scale, interpret, res, dout):
     dq3, dk3, dv3 = _bwd_call(q3, k3, v3, do3, rep(lse2), rep(delta2),
                               causal=causal, scale=scale, true_len=s,
                               interpret=interpret)
-    back = lambda x3: _from_bh(x3[:, :s, :d], b, h).astype(q.dtype)
-    return back(dq3), back(dk3), back(dv3)
+    back = lambda x3, w: _from_bh(x3[:, :s, :w], b, h).astype(q.dtype)
+    return back(dq3, d), back(dk3, d), back(dv3, v.shape[-1])
 
 
 _flash.defvjp(_flash_fwd_vjp, _flash_bwd)
@@ -387,8 +403,11 @@ _flash.defvjp(_flash_fwd_vjp, _flash_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: float | None = None, impl: str = "mosaic"):
-    """Exact attention, O(S·BLOCK) memory.  ``q,k,v: [B, S, H, D]`` →
-    ``[B, S, H, D]`` — drop-in for `ring_attention.dense_attention`
+    """Exact attention, O(S·BLOCK) memory.  ``q,k: [B, S, H, D]``,
+    ``v: [B, S, H, Dv]`` → ``[B, S, H, Dv]``; ``Dv`` may differ from ``D``
+    (latent attention trains with a 192-wide q / k and a 128-wide v), each
+    padded to its own multiple of the 128-lane tile, so v is never widened
+    to q's width — drop-in for `ring_attention.dense_attention`
     (`/root/reference` has no attention at all; this is the long-context
     hot-op layer of the TPU framework).  ``impl="interpret"`` runs the
     kernels under the Pallas interpreter (the CPU mesh, by name); the

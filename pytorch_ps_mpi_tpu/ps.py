@@ -57,7 +57,8 @@ from .optim.rules import RULES
 from .parallel.mesh import PS_AXIS, make_ps_mesh, replicated
 from .parallel import collectives
 from .utils.bytes import bytes_of
-from .utils.timing import STEP_METRIC_KEYS
+from .utils.timing import (STEP_METRIC_KEYS, counter_log,
+                           register_program)
 
 Params = "OrderedDict[str, jax.Array]"
 
@@ -436,6 +437,8 @@ class MPI_PS:
         self.steps_completed = 0
         self.aux = {}            # model aux state (e.g. BatchNorm batch_stats)
         self._has_aux = False
+        self._has_counters = False   # aux carries a "counters" sub-tree
+        self._step_programs = {}     # batch signature -> compiled step
         self._accum = 1
         self._remat = False
         self._step_fn = None
@@ -663,6 +666,8 @@ class MPI_PS:
         if overlap:
             loss_fn = self._overlap_wrap(loss_fn)
 
+        counters = self._has_counters
+
         def core(params, state, aux, batch, extras):
             # With overlap, `grads` leave the backward ALREADY cross-rank
             # summed (the bucket hooks ran the exchange in-flight).
@@ -718,8 +723,11 @@ class MPI_PS:
                 skipped = 1.0 - ok.astype(jnp.float32)
             else:
                 skipped = jnp.float32(0.0)
-            return (new_params, new_state, new_aux,
-                    lax.pmean(loss, self.reduce_axes), skipped, new_extras)
+            out = (new_params, new_state, new_aux,
+                   lax.pmean(loss, self.reduce_axes), skipped, new_extras)
+            # The counters once more, as an output nothing donates: the
+            # copy in `new_aux` is handed to the next step and dies there.
+            return out + ((new_aux["counters"],) if counters else ())
 
         state_specs = self._state_specs()
         # Donating params/state/aux (and the carried extras) lets XLA update
@@ -735,10 +743,13 @@ class MPI_PS:
             donate = (0, 1, 2, 4)
         else:
             def spmd_step(params, state, aux, batch):
-                return core(params, state, aux, batch, OrderedDict())[:5]
+                out = core(params, state, aux, batch, OrderedDict())
+                return out[:5] + out[6:]
             in_specs = (P(), state_specs, P(), self.batch_spec)
             out_specs = (P(), state_specs, P(), P(), P())
             donate = (0, 1, 2)
+        if counters:
+            out_specs += (P(),)
         return jax.jit(jax.shard_map(
             spmd_step, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs,
@@ -992,6 +1003,18 @@ class MPI_PS:
         new_aux)`` — for models carrying non-trained state (BatchNorm batch
         statistics), which the step cross-rank averages and threads through.
 
+        **Counters.**  An ``aux`` that is a dict with a ``"counters"`` entry
+        says that this sub-tree is not state but what the step counted
+        (``new_aux["counters"]``, e.g. the expert load of each MoE layer).
+        The fused step then returns it once more as an output of its own,
+        which no later step donates, and `step()` appends it to
+        `utils.timing.counter_log()` as device arrays, unread (source
+        ``"MPI_PS.step"``): no host sync on the training path; a reader
+        fetches them afterwards.  Like all of ``aux`` the counters are
+        **averaged over the ranks** (`_grads_and_aux`), so on several chips
+        a count read there is the mean over the chips, not their sum.
+        (``profile=True`` runs the phases apart and logs no counters.)
+
         ``accum_steps=K`` enables gradient accumulation: each rank's batch
         shard splits into K microbatches swept sequentially by a
         ``lax.scan``, trading K× more steps of compute latency for 1/K the
@@ -1022,11 +1045,14 @@ class MPI_PS:
         self._remat = remat
         self._has_aux = has_aux
         self._warm = False  # next step's dispatch time is trace+compile
+        self._step_programs = {}
         if aux is not None:
             rep = replicated(self.mesh)
             # copy=True for the same donation-aliasing reason as params.
             self.aux = jax.tree.map(
                 lambda x: jax.device_put(jnp.array(x, copy=True), rep), aux)
+        self._has_counters = (has_aux and isinstance(self.aux, dict)
+                              and "counters" in self.aux)
         built = jax.checkpoint(loss_fn) if remat else loss_fn
         if self.profile:
             self._phase_fns = self._make_phase_fns(built, has_aux)
@@ -1081,13 +1107,16 @@ class MPI_PS:
             if self._count_fused_sync:
                 self.fault_stats["fused_sync_encodes"] += 1
         else:
+            args = (self.params, self.state, self.aux, batch) + (
+                (self.extras,) if self.extras else ())
             start = time.perf_counter()
-            if self.extras:
-                out = self._step_fn(self.params, self.state, self.aux,
-                                    batch, self.extras)
-            else:
-                out = self._step_fn(self.params, self.state, self.aux, batch)
+            out = self._step_program(args, batch)(*args)
             dispatch = time.perf_counter() - start
+            del args    # donated: the new values are in `out`
+            if self._has_counters:
+                *out, counters = out
+                counter_log().append("MPI_PS.step", self.steps_completed,
+                                     counters)
             if not self._warm:
                 # First call traces+compiles the SPMD program; that one-time
                 # cost is the TPU analogue of the reference's collective
@@ -1129,6 +1158,25 @@ class MPI_PS:
         self._maybe_check_consensus(data)
         self.timings.append(data)
         return loss, data
+
+    def _step_program(self, args, batch):
+        """The fused step compiled for this batch's shapes: lowered and
+        compiled once, ahead of its first call, and called from then on (a
+        batch of other shapes gets a program of its own, as under
+        `jax.jit`).  Compiling here, and not inside the jitted call, leaves
+        the compiled program in hand: its text is what says under which
+        `jax.named_scope` each HLO instruction was traced, and a device
+        trace names an operation by its instruction only (see
+        `utils.timing.program_scopes`).  The registry is given the
+        program's `as_text`, not this object: it holds no parameters."""
+        leaves, tree = jax.tree.flatten(batch)
+        key = (tree, tuple((x.shape, x.dtype) for x in leaves))
+        program = self._step_programs.get(key)
+        if program is None:
+            program = self._step_fn.lower(*args).compile()
+            self._step_programs[key] = program
+            register_program("MPI_PS.step", program.as_text)
+        return program
 
     def _profiled_step(self, batch, data):
         fns = self._phase_fns

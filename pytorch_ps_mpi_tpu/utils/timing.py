@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import re
 import threading
 import time
 from collections import deque
@@ -547,6 +548,107 @@ def span(name: str, **ids) -> _Span:
     operations, on the profiler's clock; with no session it costs a flag
     test."""
     return _Span(_SPAN_LOG, name, ids)
+
+
+# ---------------------------------------------------------------------------
+# Counters that leave a jitted step unread
+# ---------------------------------------------------------------------------
+# What a step counts on the device (the expert load of a mixture-of-experts
+# layer) is worth reading only after the fact.  The step hands the arrays
+# over as they are — no `float()`, no callback, no host sync — and whoever
+# wants the numbers fetches them later.
+
+COUNTER_LOG_CAPACITY = 4096
+
+
+class CounterLog:
+    """A ring of counter records, oldest first.  A record is a dict:
+    ``source`` (who appended it, e.g. ``"MPI_PS.step"``), ``step`` (the
+    source's own count) and ``values`` (a pytree of **device arrays**, not
+    yet read: ``jax.device_get(record["values"])`` waits for the step that
+    made them).  The arrays are outputs of their step that nothing donates,
+    so they stay readable however many steps follow."""
+
+    def __init__(self, capacity: int = COUNTER_LOG_CAPACITY):
+        self._lock = threading.Lock()
+        self._ring: "deque[dict[str, Any]]" = deque(maxlen=int(capacity))
+        self.dropped = 0
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._ring)
+
+    def append(self, source: str, step: int, values: Any) -> None:
+        with self._lock:
+            if len(self._ring) == self._ring.maxlen:
+                self.dropped += 1
+            self._ring.append({"source": source, "step": step,
+                               "values": values})
+
+    def records(self, source: "str | None" = None) -> "list[dict[str, Any]]":
+        with self._lock:
+            return [dict(r) for r in self._ring
+                    if source is None or r["source"] == source]
+
+    def clear(self) -> None:
+        with self._lock:
+            self._ring.clear()
+            self.dropped = 0
+
+
+_COUNTER_LOG = CounterLog()
+
+
+def counter_log() -> CounterLog:
+    """The process-wide counter log (beside `span_log()`)."""
+    return _COUNTER_LOG
+
+
+# ---------------------------------------------------------------------------
+# Which named scope a device operation belongs to
+# ---------------------------------------------------------------------------
+# A device trace names an operation by its HLO instruction (``fusion.412``),
+# and the `jax.named_scope` it was traced under is in that instruction's
+# ``op_name`` metadata, which only the compiled program's text carries.  A
+# program that runs under named scopes registers its compiled executable's
+# ``as_text``; the text is made and parsed when somebody asks, never on the
+# training path.
+
+_PROGRAMS: "dict[str, Any]" = {}
+_PROGRAMS_LOCK = threading.Lock()
+_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name="([^"]*)"', re.MULTILINE)
+
+
+def register_program(name: str, text_fn) -> None:
+    """``text_fn()`` returns the compiled program's HLO text (after XLA's
+    passes, with metadata): the ``as_text`` of a `jax.stages.Compiled`.  The
+    newest registration under a name wins; it is kept, and with it the
+    compiled program (not its arguments), until the next one."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS[name] = {"text_fn": text_fn, "scopes": None}
+
+
+def program_scopes(name: str) -> "dict[str, str] | None":
+    """``{HLO instruction name: op_name}`` of the program registered under
+    ``name`` — ``op_name`` holds the named scopes the operation was traced
+    under, as in ``jit(step)/.../kda/while/body/dot_general`` — or None
+    where no such program is registered.  The first call fetches the text
+    and parses it; the result is kept."""
+    with _PROGRAMS_LOCK:
+        entry = _PROGRAMS.get(name)
+    if entry is None:
+        return None
+    if entry["scopes"] is None:
+        entry["scopes"] = dict(_OP_NAME.findall(entry["text_fn"]()))
+    return entry["scopes"]
+
+
+def in_scope(op_name: str, scope: str) -> bool:
+    """Whether ``scope`` is one whole component of the ``op_name`` path,
+    bare or wrapped by a transformation (``transpose(jvp(kda))``)."""
+    return re.search(rf"(?:^|[/(]){re.escape(scope)}(?:[/)]|$)",
+                     op_name) is not None
 
 
 def print_summary(timings: list[dict[str, Any]], keys=None) -> None:

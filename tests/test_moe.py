@@ -251,3 +251,132 @@ def test_moe_checkpoint_roundtrip(tmp_path, mesh8):
     for n in opt.params:
         np.testing.assert_array_equal(np.asarray(opt.params[n]),
                                       np.asarray(fresh.params[n]))
+
+
+# -- the share-aware dropless layer ------------------------------------------
+
+from perfbench.models.kimi_linear import _moe_layer as reference_moe_layer
+from pytorch_ps_mpi_tpu.models import moe
+from pytorch_ps_mpi_tpu.models.moe import ShareOfExperts
+from pytorch_ps_mpi_tpu.utils.flatten import named_params
+
+E, TOP_K, SCALE = 16, 4, 2.446
+
+
+def _share(held):
+    return ShareOfExperts(d_model=8, d_expert=12, n_experts=E, held=held,
+                          top_k=TOP_K, scale=SCALE, d_shared=12)
+
+
+@pytest.fixture(autouse=True)
+def _small_blocks(monkeypatch):
+    """74 tokens make ~19 assignments an expert: blocks of 8 rows give every
+    expert several blocks and a ragged last one, which the production
+    constant (one mostly empty block each) would not."""
+    monkeypatch.setattr(moe, "BLOCK_ROWS", 8)
+
+
+def _sizes(held):
+    return {"top_k": TOP_K, "routed_scale": SCALE, "n_shared": 1,
+            "experts_held": tuple(held)}
+
+
+@pytest.fixture(scope="module")
+def whole_layer():
+    """All 16 experts in one layer, and a batch."""
+    layer = _share(tuple(range(E)))
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 37, 8), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    return params, x
+
+
+def _cut(params, held):
+    """The parameters a chip holding `held` has: the whole router, its own
+    experts' weights."""
+    take = lambda w: w[jnp.asarray(held)]
+    return {**params, "w_gate": take(params["w_gate"]),
+            "w_up": take(params["w_up"]), "w_down": take(params["w_down"])}
+
+
+@pytest.fixture(autouse=True)
+def _f32_matmuls():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.mark.parametrize("held", [(4, 5, 6, 7), (0, 9, 15), (3,)])
+@pytest.mark.parametrize("block_rows", [8, None])   # None: as it is run
+def test_share_matches_the_masked_loop_over_its_experts(whole_layer, held,
+                                                        block_rows,
+                                                        monkeypatch):
+    params, x = whole_layer
+    mine = _cut(params, held)
+    if block_rows is None:
+        monkeypatch.undo()
+    got, load = _share(held).apply({"params": mine}, x)
+    want = reference_moe_layer(_sizes(held), named_params(mine), x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert load.shape == (len(held) + 1,)
+    assert float(load[-1]) == float(load[:-1].sum()) <= 2 * 37 * TOP_K
+
+
+def test_share_gradients_match_the_masked_loop(whole_layer):
+    params, x = whole_layer
+    held = (4, 5, 6, 7)
+    mine = _cut(params, held)
+    f = lambda p, x: jnp.sum(jnp.sin(_share(held).apply({"params": p}, x)[0]))
+    g = lambda p, x: jnp.sum(jnp.sin(reference_moe_layer(
+        _sizes(held), named_params(p), x)))
+    got, got_x = jax.grad(f, argnums=(0, 1))(mine, x)
+    want, want_x = jax.grad(g, argnums=(0, 1))(mine, x)
+    np.testing.assert_allclose(np.asarray(got_x), np.asarray(want_x),
+                               rtol=1e-4, atol=1e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5, err_msg=str(path))
+
+
+def test_every_token_on_one_held_expert_drops_nothing(whole_layer):
+    """A selection bias that sends all 74 tokens to expert 5: 74 assignments
+    on one expert of four held, ten blocks of eight rows, none lost."""
+    params, x = whole_layer
+    held = (4, 5, 6, 7)
+    mine = {**_cut(params, held),
+            "e_score_correction_bias": jnp.zeros(E).at[5].set(100.0)}
+    got, load = _share(held).apply({"params": mine}, x)
+    want = reference_moe_layer(_sizes(held), named_params(mine), x)
+    assert float(load[1]) == 2 * 37
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_no_token_routed_here_leaves_the_shared_expert(whole_layer):
+    params, x = whole_layer
+    held = (4, 5, 6, 7)
+    away = jnp.zeros(E).at[jnp.asarray(held)].set(-100.0)
+    mine = {**_cut(params, held), "e_score_correction_bias": away}
+    got, load = _share(held).apply({"params": mine}, x)
+    assert float(load[-1]) == 0.0
+    want = reference_moe_layer(_sizes(()), named_params(mine), x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(whole_layer):
+    """16 experts over 4 shares: the four shares' routed parts, plus the
+    shared expert once, are the uncut reference layer."""
+    params, x = whole_layer
+    flat = named_params(params)
+    whole = reference_moe_layer(_sizes(range(E)), flat, x)
+    shared = reference_moe_layer(_sizes(()), flat, x)
+    shares = [tuple(range(4 * r, 4 * r + 4)) for r in range(4)]
+    routed, loads = [], []
+    for held in shares:
+        y, load = _share(held).apply({"params": _cut(params, held)}, x)
+        routed.append(y - shared)
+        loads.append(float(load[-1]))
+    np.testing.assert_allclose(np.asarray(sum(routed) + shared),
+                               np.asarray(whole), rtol=1e-5, atol=1e-5)
+    assert sum(loads) == 2 * 37 * TOP_K       # every assignment, once

@@ -244,3 +244,85 @@ def test_loss_decreases_multistep(mesh2):
     losses = [opt.step(batch)[0] for _ in range(20)]
     assert losses[-1] < 0.9 * losses[0]
     assert len(opt.timings) == 20
+
+
+# -- counters on the aux channel, and the step's compiled text ---------------
+
+
+def counting_loss(params, aux, batch):
+    """An aux-style loss that counts: rows with a positive first feature."""
+    del aux
+    seen = jnp.sum(batch["x"][:, 0] > 0).astype(jnp.float32)
+    with jax.named_scope("scope_under_test"):
+        loss = loss_fn(params, batch)
+    return loss, {"counters": {"positive_rows": seen[None]}}
+
+
+@pytest.mark.parametrize("n_dev", [1, 8])
+def test_counters_leave_the_step_unread_and_survive_donation(mesh8, n_dev):
+    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
+    from pytorch_ps_mpi_tpu.utils.timing import counter_log
+
+    mesh = mesh8 if n_dev == 8 else make_ps_mesh(devices=jax.devices()[:1])
+    named, batch = make_problem()
+    opt = SGD(named, lr=0.1, mesh=mesh)
+    opt.compile_step(counting_loss, has_aux=True,
+                     aux={"counters": {"positive_rows": np.zeros(1, "f")}})
+    counter_log().clear()
+    for _ in range(4):          # each step donates the aux of the one before
+        opt.step(batch, block=False)
+    records = counter_log().records("MPI_PS.step")
+    assert [r["step"] for r in records] == [0, 1, 2, 3]
+    assert all(isinstance(r["values"]["positive_rows"], jax.Array)
+               for r in records)
+    # aux is averaged over the ranks, and so are the counters: on eight
+    # chips each sees four rows, and the log holds the mean of their counts
+    want = float(np.sum(batch["x"][:, 0] > 0)) / n_dev
+    for r in records:           # the first is still readable after the last
+        assert float(r["values"]["positive_rows"][0]) == pytest.approx(want)
+    assert float(opt.aux["counters"]["positive_rows"][0]) \
+        == pytest.approx(want)
+
+
+def test_a_step_without_counters_logs_none(mesh8):
+    from pytorch_ps_mpi_tpu.utils.timing import counter_log
+
+    named, batch = make_problem()
+    opt = SGD(named, lr=0.1, mesh=mesh8)
+    opt.compile_step(loss_fn)
+    counter_log().clear()
+    opt.step(batch)
+    assert counter_log().records() == []
+
+
+def test_the_step_compiles_once_and_registers_its_text(mesh8, caplog):
+    """One compile a batch shape, ahead of the first call; the registry is
+    given the compiled program's text and nothing that holds the optimizer;
+    a batch of another shape gets a program of its own."""
+    import gc
+    import logging
+    import weakref
+
+    from pytorch_ps_mpi_tpu.utils.timing import in_scope, program_scopes
+
+    named, batch = make_problem()
+    opt = SGD(named, lr=0.1, mesh=mesh8)
+    opt.compile_step(counting_loss, has_aux=True,
+                     aux={"counters": {"positive_rows": np.zeros(1, "f")}})
+    with jax.log_compiles(), caplog.at_level(logging.WARNING):
+        for _ in range(3):
+            opt.step(batch)
+        compiles = [r for r in caplog.records
+                    if "Finished XLA compilation of jit(spmd_step)"
+                    in r.getMessage()]
+    assert len(compiles) == 1 and len(opt._step_programs) == 1
+    scopes = program_scopes("MPI_PS.step")
+    assert any(in_scope(op_name, "scope_under_test")
+               for op_name in scopes.values())
+    half = {k: v[:len(v) // 2] for k, v in batch.items()}
+    loss, _ = opt.step(half)
+    assert np.isfinite(loss) and len(opt._step_programs) == 2
+    gone = weakref.ref(opt)
+    del opt
+    gc.collect()
+    assert gone() is None       # the registry does not keep the optimizer

@@ -178,3 +178,47 @@ def test_log_is_bounded_under_appends_from_several_threads(log):
     assert len(records) + log.dropped == per_thread * n_threads
     assert len({r["id"] for r in records}) == len(records)
     assert all(r["parent"] is None for r in records)
+
+
+# -- counters that leave a step unread, and scopes of compiled programs ------
+
+
+def test_counter_log_is_bounded_and_filters_by_source():
+    from pytorch_ps_mpi_tpu.utils.timing import CounterLog
+    log = CounterLog(capacity=3)
+    for i in range(5):
+        log.append("a" if i % 2 else "b", i, {"n": i})
+    assert len(log) == 3 and log.dropped == 2
+    assert [r["step"] for r in log.records()] == [2, 3, 4]
+    assert [r["values"]["n"] for r in log.records("b")] == [2, 4]
+    log.clear()
+    assert len(log) == 0 and log.dropped == 0
+
+
+def test_program_scopes_reads_op_names_and_keeps_them():
+    from pytorch_ps_mpi_tpu.utils import timing
+    calls = []
+    text = """
+HloModule jit_step
+%fused (p: f32[4]) -> f32[4] {
+  ROOT %multiply.1 = f32[4]{0} multiply(%p, %p), metadata={op_name="jit(step)/kda/mul" source_file="x.py" source_line=3}
+}
+ENTRY %main {
+  %fusion.7 = f32[4]{0} fusion(%a), kind=kLoop, calls=%fused, metadata={op_name="jit(step)/transpose(jvp(kda))/while/body/mul"}
+  dot.3 = f32[4,4]{1,0} dot(%a, %b), metadata={op_name="jit(step)/block_1/moe/dot_general"}
+  %copy.1 = f32[4]{0} copy(%a)
+}"""
+    timing.register_program("test.program",
+                            lambda: calls.append(1) or text)
+    scopes = timing.program_scopes("test.program")
+    assert scopes == {
+        "multiply.1": "jit(step)/kda/mul",
+        "fusion.7": "jit(step)/transpose(jvp(kda))/while/body/mul",
+        "dot.3": "jit(step)/block_1/moe/dot_general"}
+    assert timing.program_scopes("test.program") is scopes and calls == [1]
+    assert timing.program_scopes("no.such.program") is None
+    assert timing.in_scope(scopes["fusion.7"], "kda")
+    assert timing.in_scope(scopes["multiply.1"], "kda")
+    assert not timing.in_scope(scopes["dot.3"], "kda")
+    assert timing.in_scope(scopes["dot.3"], "moe")
+    assert not timing.in_scope("jit(step)/kdanot/mul", "kda")
