@@ -28,6 +28,8 @@ for all steps, and prints compile seconds, seconds per step and peak HBM):
                         attention, through SGD(...).compile_step().step();
                         then train.main --model transformer --attn flash
   kernel_parity         every Pallas kernel against its jnp reference
+  kda_kernels           the KDA recurrence's kernels against kda_chunked at
+                        [1, 8192, 32, 128]: o and the five gradients
   async_inprocess       train.main --async-ps (transformer, flash) and the
                         AsyncSGD ResNet-18 program
   tcp_pair              the TCP roles: --serve with the CPU forced in its
@@ -62,8 +64,8 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 PHASES = ("sync_resnet18", "sync_resnet18_blockq", "lm_flash",
-          "kernel_parity", "async_inprocess", "tcp_pair", "multichip",
-          "cache_reuse")
+          "kernel_parity", "kda_kernels", "async_inprocess", "tcp_pair",
+          "multichip", "cache_reuse")
 # Seconds a phase may take before its process group is killed.  The sum
 # stays under the 1200 s the whole script is allowed.
 PHASE_TIMEOUT_S = {"lm_flash": 420, "kernel_parity": 300,
@@ -96,6 +98,7 @@ FULL = dict(
     async_lm_seq=256, async_lm_batch=8, async_updates=20,
     async_resnet_batch=512, async_resnet_updates=24,
     flash_shapes=((2, 1024, 16, 64), (2, 777, 16, 64)),
+    kda_shape=(1, 8192, 32, 128),
     mlp_batch=512)
 TINY = dict(
     resnet_batch=8, resnet_steps=10,
@@ -105,6 +108,7 @@ TINY = dict(
     async_lm_seq=128, async_lm_batch=2, async_updates=20,
     async_resnet_batch=8, async_resnet_updates=24,
     flash_shapes=((1, 256, 2, 64), (1, 200, 2, 64)),
+    kda_shape=(1, 200, 2, 128),
     mlp_batch=64)
 
 
@@ -526,6 +530,74 @@ def phase_kernel_parity(run: Run) -> None:
     run.done(impl=impl, checks=checks)
 
 
+def phase_kda_kernels(run: Run) -> None:
+    """The KDA recurrence as kernels (`ops/kda_pallas.py`) against the plain
+    `kda_chunked`, on this device, from the same bf16 inputs: the largest
+    difference of o and of each of the five gradients, as a share of the
+    plain code's largest entry.  Both round their products' inputs to bf16,
+    at different places, so they differ by rounding; the same plain code in
+    f32 says how far either is from the recurrence itself."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_ps_mpi_tpu.ops.kda import kda_chunked
+    from pytorch_ps_mpi_tpu.ops.kda_pallas import kda_kernels
+
+    b, s, h, d = shape = run.sizes["kda_shape"]
+    rng = np.random.RandomState(0)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    q, k = (f32(unit(rng.randn(*shape))) for _ in range(2))
+    v, tgt = f32(rng.randn(*shape)), f32(rng.randn(*shape))
+    # log-decays from a thousandth to two a token and channel
+    g = f32(-np.exp(rng.uniform(np.log(1e-3), np.log(2.0), shape)))
+    beta = f32(1.0 / (1.0 + np.exp(-rng.randn(b, s, h))))
+    exact = (q * d ** -0.5, k, v, g, beta)
+    rounded = (*(x.astype(jnp.bfloat16) for x in exact[:3]), g, beta)
+
+    def both(fn):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum((o.astype(jnp.float32) - tgt) ** 2), o
+        return jax.jit(jax.value_and_grad(loss, argnums=range(5),
+                                          has_aux=True))
+
+    def worst(got, want):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        return float(np.abs(got - want).max() / np.abs(want).max())
+
+    def compare(got, want):
+        ((_, o_g), g_g), ((_, o_w), g_w) = got, want
+        return {"o": worst(o_g, o_w), **{
+            "d" + n: worst(a, c)
+            for n, a, c in zip(("q", "k", "v", "g", "beta"), g_g, g_w)}}
+
+    kernels = both(functools.partial(kda_kernels, impl=run.impl))(*rounded)
+    plain = both(kda_chunked)(*rounded)
+    with jax.default_matmul_precision("highest"):
+        truth = both(kda_chunked)(*exact)
+    against_plain = compare(kernels, plain)
+    against_f32 = compare(kernels, truth)
+    plain_against_f32 = compare(plain, truth)
+    print(f"[kda_kernels] {list(shape)} bf16, max relative error of the "
+          f"kernels against kda_chunked: {against_plain}; against "
+          f"kda_chunked in f32: {against_f32} (kda_chunked itself: "
+          f"{plain_against_f32})", flush=True)
+    for name, err in against_plain.items():
+        if not err <= 4e-2:
+            raise AssertionError(
+                f"kda kernels {name}: {err:g} of the plain code's largest "
+                f"entry")
+    for name, err in against_f32.items():
+        if not err <= 2 * max(plain_against_f32[name], 5e-3):
+            raise AssertionError(
+                f"kda kernels {name}: {err:g} from the f32 recurrence, the "
+                f"plain code {plain_against_f32[name]:g}")
+    run.done(impl=run.impl, shape=list(shape), against_plain=against_plain,
+             against_f32=against_f32, plain_against_f32=plain_against_f32)
+
+
 def async_lm_argv(run: Run) -> list:
     """The transformer the async phases train — async_inprocess and the TCP
     worker share it, so the worker finds its step program in the cache."""
@@ -678,6 +750,7 @@ CHILD_PHASES = {
     "sync_resnet18_blockq": phase_sync_resnet18_blockq,
     "lm_flash": phase_lm_flash,
     "kernel_parity": phase_kernel_parity,
+    "kda_kernels": phase_kda_kernels,
     "async_inprocess": phase_async_inprocess,
     "multichip": phase_multichip,
     "cache_reuse": phase_cache_reuse,

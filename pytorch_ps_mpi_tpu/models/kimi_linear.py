@@ -13,7 +13,9 @@ the model takes the `lm_batch` contract.  The head is untied.
   q and k L2-normalised per head and q scaled by ``d_k^-1/2``; the log-decay
   of each channel ``g = -exp(A_log) * softplus(W_f2 W_f1 x + dt_bias)``,
   the write strength ``beta = sigmoid(W_b x)``; the recurrence itself is
-  `ops.kda.kda_chunked`; the output is ``W_o [RMSNorm(o) * sigmoid(W_g2
+  the ``kda`` callable (`ops.kda.kda_attention`: Pallas kernels on the chip
+  at widths that are whole lane tiles, `ops.kda.kda_chunked` elsewhere);
+  the output is ``W_o [RMSNorm(o) * sigmoid(W_g2
   W_g1 x)]``.
 * **MLA** (`LatentAttention`): q of ``nope + rope`` columns a head; keys and
   values from one 512-wide latent (RMSNorm'd) plus ``rope`` columns shared by
@@ -42,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.kda import kda_chunked
+from ..ops.kda import kda_attention
 from ..parallel.ring_attention import dense_attention
 from .moe import ShareOfExperts, SwiGLU, bias_free_dense as _dense
 
@@ -79,6 +81,7 @@ class KDAttention(nn.Module):
     gate_rank: int
     eps: float
     dtype: jnp.dtype
+    kda: Callable = kda_attention
 
     @nn.compact
     def __call__(self, x):
@@ -112,7 +115,7 @@ class KDAttention(nn.Module):
         beta = jax.nn.sigmoid(
             _dense(h, self.dtype, "b_proj")(x).astype(jnp.float32))
         with jax.named_scope("kda"):
-            o = kda_chunked(q, k, v, g, beta)
+            o = self.kda(q, k, v, g, beta)
         o = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                        param_dtype=jnp.float32, name="o_norm")(o)
         gate = _dense(width, self.dtype, "g_b")(
@@ -211,6 +214,7 @@ class KimiBlock(nn.Module):
     attn_fn: Callable
     linear: bool     # KDA, else MLA
     dense: bool      # a dense SwiGLU, else the expert layer
+    kda_fn: Callable = kda_attention
 
     def setup(self):
         c = self.cfg
@@ -220,7 +224,7 @@ class KimiBlock(nn.Module):
         if self.linear:
             self.attn = KDAttention(
                 c.d_model, c.kda_heads, c.kda_head_dim, c.conv_size,
-                c.gate_rank, c.eps, c.dtype)
+                c.gate_rank, c.eps, c.dtype, self.kda_fn)
         else:
             self.attn = LatentAttention(
                 c.d_model, c.n_heads, c.kv_lora_rank, c.qk_nope_dim,
@@ -244,6 +248,7 @@ class KimiLinearLM(nn.Module):
 
     cfg: KimiLinearConfig
     attn: Callable = None              # default: causal dense attention
+    kda: Callable = kda_attention      # the recurrence of the KDA layers
 
     @nn.compact
     def __call__(self, tokens, positions=None):
@@ -259,7 +264,7 @@ class KimiLinearLM(nn.Module):
         loads = []
         for i in range(c.n_layers):
             x, load = KimiBlock(c, attn, linear=(i + 1) in c.kda_layers,
-                                dense=i < c.first_k_dense,
+                                dense=i < c.first_k_dense, kda_fn=self.kda,
                                 name=f"block_{i}")(x)
             if load is not None:
                 loads.append(load)

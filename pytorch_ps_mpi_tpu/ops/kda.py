@@ -36,6 +36,11 @@ saved once a chunk for the backward pass (``S / C * d_k * d_v`` a head, not
 the scan's body and round the intra-chunk matrices, which run a few chunks
 at a time so that the element-by-element diagonal blocks never exist for
 the whole sequence at once).
+
+`kda_chunked` is what runs off the chip and what every kernel test compares
+with.  On the chip, at head widths of whole lane tiles, the same recurrence
+runs as the Pallas kernels of `ops/kda_pallas.py`; `kda_attention`, at the
+end of this file, picks between them from what the program can observe.
 """
 
 from __future__ import annotations
@@ -46,6 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.scipy.linalg import solve_triangular
+
+from . import kda_pallas
 
 CHUNK = 32       # tokens a step of the state scan
 SUB_CHUNK = 8    # side of the blocks computed element by element
@@ -222,3 +229,32 @@ def kda_chunked(q, k, v, g, beta):
     _, out = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), ops)
     out = out.transpose(1, 0, 3, 2, 4)                      # [B, N, C, H, Dv]
     return out.reshape(b, n_pad * chunk, h, dv)[:, :s]
+
+
+def kda_attention(q, k, v, g, beta, *, impl: "str | None" = None):
+    """The KDA recurrence by the implementation the program can see it
+    needs; arguments and result as `kda_chunked`.
+
+    With ``impl=None`` two things decide, neither of them a setting.  The
+    widths: the kernels of `kda_pallas` take ``Dk`` and ``Dv`` that are whole
+    lane tiles (the published 128); any other width is `kda_chunked`.  And
+    the platform the program is lowered for (`lax.platform_dependent`, not
+    the process's default backend): a TPU gets the Mosaic kernels with their
+    hand-written backward, everything else `kda_chunked`, differentiated by
+    JAX — neither stands in for the other on its own platform.  Tests name
+    ``impl``: ``"ref"`` is `kda_chunked`, ``"mosaic"`` and ``"interpret"``
+    are the kernels (`ops.pallas_kernels.use_interpreter`)."""
+    if impl == "ref" or (impl is None and not kda_pallas.supports(q, v)):
+        return kda_chunked(q, k, v, g, beta)
+    if impl is not None:
+        return kda_pallas.kda_kernels(q, k, v, g, beta, impl=impl)
+    return _for_the_platform(q, k, v, g, beta)
+
+
+@jax.jit
+def _for_the_platform(q, k, v, g, beta):
+    """Both implementations are traced, and differentiated, whatever the
+    platform, and the one that is lowered is picked then: under `jax.jit`
+    the layers of a model that call this at one shape share that work."""
+    return lax.platform_dependent(
+        q, k, v, g, beta, tpu=kda_pallas.kda_kernels, default=kda_chunked)

@@ -618,6 +618,10 @@ _PROGRAMS: "dict[str, Any]" = {}
 _PROGRAMS_LOCK = threading.Lock()
 _OP_NAME = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name="([^"]*)"', re.MULTILINE)
+# An instruction is one line of the text, but for a Pallas call that carries
+# `metadata=`: its ``kernel_metadata={`` is printed over three lines, the
+# ``op_name`` on the last.  Such continuation lines start with ``"`` or ``}``.
+_CONTINUATION = re.compile(r'\n(?=["}])')
 
 
 def register_program(name: str, text_fn) -> None:
@@ -640,7 +644,8 @@ def program_scopes(name: str) -> "dict[str, str] | None":
     if entry is None:
         return None
     if entry["scopes"] is None:
-        entry["scopes"] = dict(_OP_NAME.findall(entry["text_fn"]()))
+        text = _CONTINUATION.sub(" ", entry["text_fn"]())
+        entry["scopes"] = dict(_OP_NAME.findall(text))
     return entry["scopes"]
 
 

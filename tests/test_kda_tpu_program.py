@@ -1,0 +1,116 @@
+"""The KDA layer as the chip's compiler sees it, without the chip: a toy
+`KDAttention` differentiated and compiled for a described v5e.  What the
+benchmark's `kda_ms_step` rests on is pinned here: the program built for a
+TPU gets the Mosaic kernels though this process's backend is the CPU, every
+kernel call of the forward, the rematerialised forward and the backward
+stays under the program's ``kda`` scope and carries its kernel's name, and
+no loop of the plain code is left under that scope.  And the kernels compile
+at the cell's shape.
+
+This is the one test file that describes a TPU topology (the
+`on-chip-measurement` guide, section 2): only inside a fixture, never while
+a module is imported.
+"""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from pytorch_ps_mpi_tpu.models.kimi_linear import KDAttention
+from pytorch_ps_mpi_tpu.ops import kda_pallas
+from pytorch_ps_mpi_tpu.utils import timing
+
+KERNELS = {"kda_fwd", "kda_bwd"}
+_CALL = re.compile(
+    r'^\s*%?([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+    r'[^\n]*kernel_metadata=\{\s*"kernel":"(\w+)"', re.MULTILINE)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A program compiled for a described chip can be written to the
+    # persistent cache but not read back without one.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.fixture(scope="module")
+def program(one_chip):
+    """`jax.grad` of a rematerialised toy layer (2 heads of 128, 256
+    tokens), compiled for the chip and registered as `MPI_PS.step`
+    registers its program."""
+    layer = KDAttention(d_model=256, n_heads=2, head_dim=128, conv_size=4,
+                        gate_rank=32, eps=1e-5, dtype=jnp.bfloat16)
+    x = jnp.zeros((1, 256, 256), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        y = jax.checkpoint(layer.apply)(params, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    compiled = jax.jit(jax.grad(loss)).lower(
+        _shapes(params, one_chip), _shapes(x, one_chip)).compile()
+    timing.register_program("test.kda_tpu", compiled.as_text)
+    return compiled.as_text(), timing.program_scopes("test.kda_tpu")
+
+
+def test_every_kernel_call_is_under_the_kda_scope_by_name(program):
+    text, scopes = program
+    calls = _CALL.findall(text)
+    assert {kernel for _, kernel in calls} == KERNELS
+    # forward, the forward again under `jax.checkpoint`, and the backward
+    assert sorted(kernel for _, kernel in calls) == [
+        "kda_bwd", "kda_fwd", "kda_fwd"]
+    for name, kernel in calls:
+        assert name.startswith(kernel), (name, kernel)
+        assert name in scopes, f"{name}: the registry did not read it"
+        assert timing.in_scope(scopes[name], "kda"), scopes[name]
+    backward = [scopes[n] for n, kernel in calls if kernel == "kda_bwd"]
+    assert "transpose(" in backward[0]
+
+
+def test_no_loop_of_the_plain_code_is_left_under_the_scope(program):
+    _, scopes = program
+    loops = [n for n, op in scopes.items()
+             if timing.in_scope(op, "kda") and "while" in op]
+    assert loops == []
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_the_kernels_compile_at_the_cells_shape(one_chip, what):
+    """``[2, 8192, 32, 128]`` in bf16, decays and beta in f32: tiling and
+    VMEM are the chip's compiler's to refuse."""
+    b, s, h, d = 2, 8192, 32, 128
+    wide = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    args = (wide, wide, wide,
+            jax.ShapeDtypeStruct((b, s, h, d), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((b, s, h), jnp.float32, sharding=one_chip))
+    fn = functools.partial(kda_pallas.kda_kernels, impl="mosaic")
+    if what == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(kda_pallas.kda_kernels(*a).astype(
+            jnp.float32)), argnums=range(5))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert {kernel for _, kernel in _CALL.findall(text)} <= KERNELS
+    assert ("kda_bwd" if what == "backward" else "kda_fwd") in text

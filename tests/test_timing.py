@@ -222,3 +222,30 @@ ENTRY %main {
     assert not timing.in_scope(scopes["dot.3"], "kda")
     assert timing.in_scope(scopes["dot.3"], "moe")
     assert not timing.in_scope("jit(step)/kdanot/mul", "kda")
+
+
+def test_program_scopes_reads_a_pallas_call_printed_over_three_lines():
+    """A Pallas call that carries `metadata=` has its ``kernel_metadata={``
+    printed over three lines with the ``op_name`` on the last, and so has
+    every read of its tuple; the computation's closing brace starts a line
+    too and joins nothing."""
+    from pytorch_ps_mpi_tpu.utils import timing
+    text = """
+ENTRY %main {
+  %kda_fwd.2 = (bf16[1,256,256]{2,1,0}, f32[1,2,1,128,128]{4,3,2,1,0}) custom-call(%a, %b), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"kernel":"kda_fwd"
+}}, metadata={op_name="jit(step)/jvp(KDAttention)/kda/cond/branch_0_fun/kda_fwd/pallas_call" stack_frame_id=81}, backend_config={"custom_call_config":{"body":"TUzv"}}
+  %pallas_call.13 = bf16[1,256,256]{2,1,0} get-tuple-element(%kda_fwd.2), index=0, frontend_attributes={kernel_metadata={
+"kernel":"kda_fwd"
+}}, metadata={op_name="jit(step)/jvp(KDAttention)/kda/cond/branch_0_fun/kda_fwd/pallas_call"}
+  ROOT %add.1 = f32[4]{0} add(%a, %a), metadata={op_name="jit(step)/head_loss/add"}
+}
+%other {
+  %copy.1 = f32[4]{0} copy(%a)
+}"""
+    timing.register_program("test.pallas", lambda: text)
+    scopes = timing.program_scopes("test.pallas")
+    assert sorted(scopes) == ["add.1", "kda_fwd.2", "pallas_call.13"]
+    assert timing.in_scope(scopes["kda_fwd.2"], "kda")
+    assert timing.in_scope(scopes["pallas_call.13"], "kda")
+    assert scopes["add.1"] == "jit(step)/head_loss/add"
