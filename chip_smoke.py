@@ -382,7 +382,7 @@ def phase_lm_flash(run: Run) -> None:
     b = lm_batch(synthetic_lm(batch, seq_len=seq,
                               vocab=sz["lm"]["vocab_size"], seed=0))
     losses = [opt.step(b)[0] for _ in range(sz["lm_steps"])]
-    flash = {"_fwd_kernel", "_bwd_dkdv_kernel", "_bwd_dq_kernel"}
+    flash = {"flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"}
     fields = sync_opt_fields(run, opt, losses, "lm d%d x L%d" % (
         sz["lm"]["d_model"], sz["lm"]["n_layers"]))
     fields["mosaic_kernels"] = mosaic_kernels(run, opt, b, flash)
@@ -434,8 +434,9 @@ def phase_kernel_parity(run: Run) -> None:
             raise AssertionError(f"{what}: max err {err:g} > {bound:g}")
         return err
 
-    # (n elements, block rows, world): bench.py's awkward ones, then
-    # ResNet-18's largest and smallest leaf at this world size.
+    # (n elements, block rows, world): awkward ones (a whole tile, a ragged
+    # tail, fewer elements than a block), then ResNet-18's largest and
+    # smallest leaf at this world size.
     world = max(run.n, 2)
     cases = [(512 * 128, 512, 1), (100_000, 512, 4), (37, 8, 2),
              (3 * 512 * 128 + 5, 512, 8),
@@ -653,7 +654,8 @@ def phase_async_inprocess(run: Run) -> None:
         return cross_entropy(logits, batch["y"])
 
     # Default quota: one gradient per worker per update (1 on one chip, as
-    # in bench.py's program).  Gradients SUM, so lr scales down with it.
+    # in the `resnet50-async-1chip` cell).  Gradients SUM, so lr scales
+    # down with it.
     workers = max(1, run.n - 1)
     opt = AsyncSGD(list(params.items()), lr=0.02 / workers, momentum=0.5)
     opt.compile_step(loss_fn)

@@ -2398,7 +2398,7 @@ class AsyncPSWorker:
         # Bucket-streamed gradient production (v11): None = whole-tree
         # pushes (the legacy path, still the degenerate (0, 1) frame);
         # an int enables bucket streaming at that size (0 = auto-tune
-        # from the roofline data, `parallel.overlap.auto_bucket_bytes`).
+        # by `parallel.overlap.auto_bucket_bytes`).
         # ``fused_encode`` selects the per-bucket encode compiled INTO
         # the grad program (`parallel.overlap.make_async_bucket_step`)
         # vs the host-boundary per-bucket encode fallback; it is the

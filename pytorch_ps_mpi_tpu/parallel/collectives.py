@@ -134,9 +134,9 @@ def rank(axis: str = PS_AXIS):
 # (`/root/reference/ps.py:140-147`) because each parameter's pickled payload
 # is a separate MPI message.  Transliterated to XLA that becomes one
 # all-gather/all-reduce per code leaf (~130 for ResNet-18), each too small to
-# fill the ICI links and each a separate scheduling barrier — the r3
-# OVERLAP_EVIDENCE.json showed XLA scheduling all 130 synchronously.  The
-# TPU-idiomatic form is a few LARGE flat transfers: concatenate same-dtype
+# fill the ICI links and each a separate scheduling barrier (the compiled
+# v5e-8 schedule ran all 130 synchronously).  The TPU-idiomatic form is a
+# few LARGE flat transfers: concatenate same-dtype
 # leaves into buckets of ~bucket_bytes, run ONE collective per bucket, and
 # slice the results back out.  Fewer, larger collectives saturate ICI and
 # give XLA's latency-hiding scheduler few enough pieces to hoist compute
@@ -152,9 +152,8 @@ DEFAULT_BUCKET_BYTES = 4 << 20  # 4 MiB: ~ICI bandwidth-delay product scale
 # carrying bucket_bytes/16 (256 KiB at the default) amortizes a
 # collective's issue latency on its own (~25 us of wire at 10 GB/s vs
 # ~10 us/hop), so packing it into a shared bucket buys nothing and pays
-# the concatenate-in / slice-out memcpy both ways — measured at ~11 ms
-# of pure overhead per step on the w8 gradsync payload (28.5 -> 14.6 ms
-# once the multi-MB matrices go solo; BUCKET_EVIDENCE.json).
+# the concatenate-in / slice-out memcpy both ways (about half of an
+# eight-rank exchange's time on the host CPU; not measured on the chip).
 _SOLO_DIVISOR = 16
 
 
@@ -253,11 +252,11 @@ def _allreduce_rs_ag(x, axis, world: int):
     bucket so XLA's async scheduler can pipeline them against compute.
     The motivation: XLA's all-reduce combiner merges every psum bucket
     into ONE end-of-backward tuple all-reduce and PJRT exposes no
-    combiner-threshold knob (`benchmarks/PSUM_OVERLAP_PROBE.json`), which
-    serializes the whole exchange after the last gradient; the ZeRO
-    path's rs+ag lowering demonstrably keeps per-bucket overlap
-    (`benchmarks/OVERLAP_EVIDENCE.json` ``lm_flagship_zero``).  This
-    realizes the reference's per-parameter pipelining intent
+    combiner-threshold knob, which serializes the whole exchange after
+    the last gradient; the combiner leaves rs+ag pairs alone, so they
+    stay one per bucket in the compiled schedule (whether that hides
+    them on the chip is not measured).  This realizes the reference's
+    per-parameter pipelining intent
     (`/root/reference/ps.py:125-127,140-147`) for the identity/psum path."""
     n = x.size
     pad = (-n) % world

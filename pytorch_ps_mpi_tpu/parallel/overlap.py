@@ -7,8 +7,8 @@ traffic for late-layer gradients rides under the still-running early-layer
 backward.  Our fused SPMD step so far synchronized *after* ``jax.grad``
 returned: the gradient collectives sit behind a data dependency on the whole
 gradient tree, and for the identity/psum path XLA's all-reduce combiner then
-merges every bucket into ONE end-of-backward tuple all-reduce
-(`benchmarks/PSUM_OVERLAP_PROBE.json`) — zero overlap, idle ICI while the
+merges every bucket into ONE end-of-backward tuple all-reduce (PJRT exposes
+no combiner-threshold option to stop it) — zero overlap, idle ICI while the
 MXU works through backward, and idle MXU while the wire drains.
 
 This module is the reference's pipelining intent rebuilt for XLA: the
@@ -29,23 +29,21 @@ Two reducers for the identity path:
   all-gather.  Mathematically the same sum an all-reduce performs on the
   wire, but the all-reduce COMBINER pass does not touch rs/ag ops, so the
   per-bucket collectives survive into the final schedule instead of being
-  re-merged into one end-of-backward op (the `lm_flagship_decomposed`
-  evidence in `benchmarks/OVERLAP_EVIDENCE.json`).
+  re-merged into one end-of-backward op.  Whether that buys time on the
+  chip is not measured.
 * ``psum`` — one all-reduce per bucket; cheapest dispatch on backends with
   no combiner pathology (the virtual-CPU test mesh), and still issued
   inside backward.
 
 The bucket-size knob trades schedule granularity against per-collective
-efficiency; ``auto_bucket_bytes`` picks it from the committed roofline data
-(`benchmarks/ROOFLINE.json`) and every constructed plan is recorded through
-`utils.timing.record_overlap_schedule` so a run's chosen schedule is
-inspectable after the fact.
+efficiency; ``auto_bucket_bytes`` picks it from the payload, the world size
+and the v5e's published HBM bandwidth, and every constructed plan is
+recorded through `utils.timing.record_overlap_schedule` so a run's chosen
+schedule is inspectable after the fact.
 """
 
 from __future__ import annotations
 
-import json
-import os
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Any, Callable
@@ -59,10 +57,6 @@ from . import collectives
 from .collectives import _allreduce_rs_ag, _plan_buckets
 
 Params = "OrderedDict[str, jax.Array]"
-
-_ROOFLINE_DEFAULT = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "benchmarks", "ROOFLINE.json")
 
 # Bounds for the tuned bucket size: below ~1 MiB a bucket's wire time stops
 # amortizing collective issue overhead; above ~32 MiB the first bucket
@@ -105,38 +99,27 @@ class OverlapPlan:
 # a world-sized ring serializes ~(world-1) hops of link latency per
 # collective; O(10us) per hop is the v5e-class ballpark.
 PER_HOP_LATENCY_S = 10e-6
+# The v5e's published HBM bandwidth; ICI per link is taken as roughly an
+# order of magnitude under it.
+HBM_BYTES_PER_S = 819e9
 
 
-def auto_bucket_bytes(total_bytes: int, *, world: int = 8,
-                      roofline_path: str | None = None) -> int:
-    """Pick a bucket size from the committed roofline data.
+def auto_bucket_bytes(total_bytes: int, *, world: int = 8) -> int:
+    """Pick a bucket size from the payload and the world size.
 
-    Two constraints, both deterministic given the JSON:
+    Two constraints, both a pure function of the arguments:
 
     * **granularity** — aim for ~`TARGET_BUCKETS` buckets so the scheduler
       has enough pieces to pipeline (one bucket = no overlap; hundreds =
       per-op dispatch overhead, the per-param pathology all over again);
     * **latency floor** — a bucket must carry enough bytes that its wire
-      time (at an ICI bandwidth estimated as a fraction of the measured
+      time (at an ICI bandwidth estimated as a tenth of the published
       HBM peak) dominates the collective's serial latency, which grows
       with the ring: ~(world-1) hops of per-hop latency for the rs+ag
       lowering.  Below that, splitting finer buys overlap the latency
       immediately eats.
-
-    Falls back to sane constants when the roofline file is absent (CI
-    checkouts without benchmark artifacts).
     """
-    path = roofline_path if roofline_path is not None else _ROOFLINE_DEFAULT
-    hbm_bytes_per_s = 819e9  # v5e datasheet-scale default
-    try:
-        with open(path) as f:
-            hbm_bytes_per_s = float(
-                json.load(f)["peaks"]["hbm_bytes_per_s"])
-    except (OSError, KeyError, ValueError):
-        pass
-    # ICI per-link bandwidth is roughly an order of magnitude under HBM on
-    # the v5e-class parts this repo benchmarks.
-    ici_bytes_per_s = hbm_bytes_per_s / 10.0
+    ici_bytes_per_s = HBM_BYTES_PER_S / 10.0
     hops = max(int(world) - 1, 1)
     latency_floor = int(ici_bytes_per_s * PER_HOP_LATENCY_S * hops)
     granularity = max(1, int(total_bytes) // TARGET_BUCKETS)
@@ -146,13 +129,12 @@ def auto_bucket_bytes(total_bytes: int, *, world: int = 8,
 
 def plan_overlap(named_arrays, bucket_bytes: int | None = None, *,
                  world: int = 8, record: bool = True,
-                 roofline_path: str | None = None,
                  solo_bytes: int = 0) -> OverlapPlan:
     """Partition named gradient leaves into an `OverlapPlan`.
 
     ``named_arrays`` is a name->array mapping (params; gradients share
-    shapes/dtypes).  ``bucket_bytes=None``/0 auto-tunes from the
-    roofline data.  ``solo_bytes`` (default 0 = the pack-everything
+    shapes/dtypes).  ``bucket_bytes=None``/0 auto-tunes
+    (`auto_bucket_bytes`).  ``solo_bytes`` (default 0 = the pack-everything
     plan) lets large leaves stand alone; the right default DIFFERS by
     consumer, so this planner keeps packing — the custom-vjp hook
     engine wants GRANULARITY (more buckets = more schedule pieces to
@@ -172,8 +154,7 @@ def plan_overlap(named_arrays, bucket_bytes: int | None = None, *,
     total = sum(x.size * jnp.dtype(x.dtype).itemsize for x in leaves)
     tuned = not bucket_bytes
     if tuned:
-        bucket_bytes = auto_bucket_bytes(total, world=world,
-                                         roofline_path=roofline_path)
+        bucket_bytes = auto_bucket_bytes(total, world=world)
     plan_idx = _plan_buckets(leaves, bucket_bytes, int(solo_bytes))
     plan = OverlapPlan(
         buckets=tuple(tuple(names[i] for i in idxs) for idxs in plan_idx),

@@ -18,13 +18,11 @@ decode-sum, update — is **one jitted SPMD program** over a
 
 * backward hooks + a 200-thread encode pool (`ps.py:63-66,85,98-101`) existed
   to overlap encoding with backward; here the gradient exchange is bucketed
-  (`bucket_mb`, `parallel/collectives.py`) into a few large flat transfers,
-  and the XLA:TPU backend fuses chunks of those collectives INTO the
-  backward-pass compute fusions (async collective fusion) — measured in the
-  compiled v5e-8 schedule, `benchmarks/OVERLAP_EVIDENCE.json`: 38
-  backward fusions each advance a collective chunk, and only 3 sync
-  all-gathers remain at the top level (vs 130 in the per-param lowering) —
-  the thread pool's overlap, compiled instead of scheduled by hand;
+  (`bucket_mb`, `parallel/collectives.py`) into a few large flat transfers
+  (a handful of collectives where the per-parameter lowering has 130 for
+  ResNet-18), which XLA's scheduler may run beside the backward pass — the
+  thread pool's overlap, left to the compiler; on the chip the default
+  lowering's all-reduces are all exposed (PERF.md, `gpt2m-sync-dp4`);
 * the ``Iallgather``-of-sizes protocol (`ps.py:140-147`) existed because
   pickled payloads have unknown sizes; codec outputs have static shapes, so
   gradient exchange is a single ``all_gather`` (or, for the identity codec, a
@@ -171,15 +169,13 @@ class MPI_PS:
         for batch in data:
             loss, metrics = opt.step(batch)
 
-    ``code=`` plugs a gradient codec (`ops.codecs`), ``profile=True`` splits
-    the step into separately-timed phases to populate the per-phase metrics
-    the way the reference's host-side timers did.
+    ``code=`` plugs a gradient codec (`ops.codecs`).
     """
 
     def __init__(self, named_params, *, optim: str = "sgd",
                  code: Codec | str | None = None, mesh: Mesh | None = None,
                  axis: "str | tuple" = PS_AXIS, batch_spec: P | None = None,
-                 profile: bool = False, zero: bool = False,
+                 zero: bool = False,
                  skip_nonfinite: bool = False, clip_norm: float | None = None,
                  error_feedback: bool = False, ema_decay: float | None = None,
                  bucket_mb: float | None =
@@ -190,9 +186,7 @@ class MPI_PS:
                  fused_encode: bool = False,
                  consensus_every: int = 0,
                  consensus_policy: str = "abort",
-                 names=(), use_mpi: bool = True, cuda: bool = False,
                  **hyper):
-        del use_mpi, cuda, names  # accepted for API parity; meaningless on TPU
         self.optim = optim
         self.mesh = mesh if mesh is not None else make_ps_mesh()
         # The codec's kernels follow the mesh's devices, not the process's
@@ -222,7 +216,6 @@ class MPI_PS:
         # shard the sequence dim.
         self.batch_spec = (batch_spec if batch_spec is not None
                            else P(self.axes))
-        self.profile = profile
         # Gradient bucketing: the cross-rank exchange concatenates same-dtype
         # code leaves into flat buckets of <= bucket_mb MiB and runs ONE
         # collective per bucket instead of one per parameter (the reference's
@@ -238,14 +231,12 @@ class MPI_PS:
                              if bucket_mb else None)
         # Identity-path overlap knob: XLA's all-reduce combiner merges all
         # psum buckets into ONE end-of-backward tuple all-reduce (no PJRT
-        # threshold knob exists — benchmarks/PSUM_OVERLAP_PROBE.json),
-        # serializing the exchange after the last gradient.  With
-        # ``decompose_allreduce=True`` each bucket lowers as explicit
-        # reduce-scatter + all-gather (the same sum an all-reduce performs
-        # on the wire), which the combiner leaves per-bucket so the async
-        # scheduler can overlap them with backward compute — the ZeRO
-        # path's demonstrated overlap (OVERLAP_EVIDENCE.json
-        # ``lm_flagship_zero``) for replicated-state training.
+        # threshold knob exists), serializing the exchange after the last
+        # gradient.  With ``decompose_allreduce=True`` each bucket lowers
+        # as explicit reduce-scatter + all-gather (the same sum an
+        # all-reduce performs on the wire), which the combiner leaves
+        # per-bucket so the async scheduler can overlap them with backward
+        # compute.  Not measured on the chip.
         self.decompose_allreduce = bool(decompose_allreduce)
         # WHEN the cross-rank gradient sum happens (`parallel/overlap.py`):
         #   "post"     — after backward, one collective per parameter (the
@@ -372,7 +363,7 @@ class MPI_PS:
         # The overlap engine's bucket schedule is a compile-time decision
         # over the (static) parameter shapes; build it once here and record
         # it so the chosen schedule is inspectable (`utils/timing.py`).
-        # bucket_mb=0/None auto-tunes from benchmarks/ROOFLINE.json.
+        # bucket_mb=0/None auto-tunes (`overlap.auto_bucket_bytes`).
         self.overlap_plan = None
         if sync_mode == "overlap":
             from .parallel import overlap as _overlap
@@ -442,7 +433,6 @@ class MPI_PS:
         self._accum = 1
         self._remat = False
         self._step_fn = None
-        self._phase_fns = None
         self._loss_fn = None
         self._warm = False
 
@@ -533,8 +523,7 @@ class MPI_PS:
         return new_params, new_state
 
     def _grads_and_aux(self, loss_fn, has_aux: bool, params, aux, batch):
-        """Per-rank gradients + synced aux — the shared front half of both
-        the fused step and the profile-mode backward phase.
+        """Per-rank gradients + synced aux — the front half of the step.
 
         Gradients here are *per-rank* (each rank grads its own batch shard);
         the cross-rank sum happens later, explicitly, like the reference's
@@ -690,15 +679,17 @@ class MPI_PS:
                 d_sum = None
             if self.zero:
                 # Identity + zero skips the full sum entirely: the
-                # reduce-scatter inside _zero_updates IS the sync.
+                # reduce-scatter inside _zero_sync IS the sync.
                 # Overlap mode instead arrives with the full sum in hand
                 # (paid inside backward); the chunk slice is free.
                 if overlap:
                     d_sum = grads
                 elif not use_ef:
                     d_sum = None if identity else self._summed_grads(grads)
-                new_params, new_state = self._zero_updates(
-                    params, state, None if overlap else grads, d_sum)
+                d_chunks = self._zero_sync(
+                    None if overlap else grads, d_sum)
+                new_params, new_state = self._zero_apply(
+                    params, state, d_chunks)
             else:
                 if overlap:
                     d_ps = grads
@@ -817,183 +808,6 @@ class MPI_PS:
             for n, p in params.items())
         return new_params, new_state
 
-    def _zero_updates(self, params, state, grads, d_full):
-        """Fused sync + update (see `_zero_sync` / `_zero_apply`; split so
-        profile mode can time the two phases separately)."""
-        return self._zero_apply(params, state,
-                                self._zero_sync(grads, d_full))
-
-    def _make_phase_fns(self, loss_fn, has_aux: bool):
-        """Phase-split step for profile mode: each phase its own jitted SPMD
-        program, so the reference's per-phase wall-clock metrics
-        (`ps.py:116-191`) are genuinely measurable (at the cost of fusion).
-
-        Works on any mesh AND any feature combination the fused step
-        supports — zero, error_feedback, ema_decay, skip_nonfinite,
-        clip_norm (r2 VERDICT: the flagship combos previously had no phase
-        observability at all).  Aux state (BatchNorm) is synced inside the
-        backward phase, and extra (non-data) axes are collapsed there too,
-        so rank-varying trees between phases vary only over the data axes
-        and travel with an explicit leading world-size dim (per-shard slice
-        [1, ...]) — each phase is a clean P(axes)-sharded boundary.
-
-        Returns a dict of jitted phase programs:
-
-        * ``grad``   — backward (+ the cross-rank finiteness consensus flag
-          when skip_nonfinite; the flag is MATERIALIZED to the host between
-          phases, so a skipped step genuinely skips the later phases — the
-          phase-split analogue of the fused step's ``jnp.where`` gating);
-        * ``encode`` — codec encode (EF variant folds the residual in and
-          returns the new one); ``None`` when there is nothing to encode
-          (identity codec without EF);
-        * ``sync``   — cross-rank exchange + decode-sum (+ clip); in zero
-          mode produces the per-rank owner chunks (reduce-scatter for the
-          identity path);
-        * ``update`` — optimizer update (zero mode: chunk update + the
-          params all-gather-back, which is why zero's ``optim_step_time``
-          includes one collective — documented, not hidden);
-        * ``ema``    — EMA weight-average maintenance (or ``None``).
-
-        Phases that only consume their inputs (sync's codes, update's
-        params/state, ema's old average) DONATE them, matching the fused
-        step: without donation each phase writes a second full copy of its
-        tree to HBM before the old one frees.
-
-        ``sync_mode="overlap"`` folds the exchange INTO the backward
-        program (that is the point of the mode), so ``backward_time``
-        includes the cross-rank sum, ``encode`` is ``None``, and ``sync``
-        shrinks to clip (replicated-state) or the chunk slice (zero).
-        """
-        mesh, axis = self.mesh, self.axis
-        smap = partial(jax.shard_map, mesh=mesh, check_vma=False)
-        identity = isinstance(self.code, IdentityCodec)
-        use_ef = self.error_feedback
-        skip = self.skip_nonfinite
-        overlap = self.sync_mode == "overlap"
-        if overlap:
-            loss_fn = self._overlap_wrap(loss_fn)
-        meta = {n: (p.shape, p.dtype) for n, p in self.params.items()}
-        state_specs = self._state_specs()
-
-        def grad_body(params, aux, batch):
-            loss, grads, new_aux = self._grads_and_aux(
-                loss_fn, has_aux, params, aux, batch)
-            if skip:
-                # Consensus on the RAW gradients, before any residual mixes
-                # in (a NaN batch must not poison the carried EF residual).
-                # Overlap mode: the summed gradient (identity-only combo,
-                # enforced at construction) — NaN/inf propagates.
-                bad = sum(jnp.sum(~jnp.isfinite(g)).astype(jnp.float32)
-                          for g in jax.tree.leaves(grads))
-                ok = lax.psum(bad, self.reduce_axes) == 0
-            else:
-                ok = jnp.bool_(True)
-            if overlap:
-                # Grads left the backward already summed -> replicated;
-                # no leading per-rank world dim to carry between phases.
-                return loss[None], grads, new_aux, ok
-            return (loss[None], jax.tree.map(lambda g: g[None], grads),
-                    new_aux, ok)
-        grad_fn = jax.jit(smap(
-            grad_body, in_specs=(P(), P(), self.batch_spec),
-            out_specs=(P(axis), P() if overlap else P(axis), P(), P())))
-
-        if overlap:
-            encode_fn = None  # the exchange already ran inside backward
-        elif use_ef:
-            def encode_body(grads, ef):
-                g = OrderedDict((n, x[0]) for n, x in grads.items())
-                d = OrderedDict(
-                    (n, x + ef[n][0].astype(x.dtype)) for n, x in g.items())
-                codes = self._encode_all(d)
-                new_ef = OrderedDict(
-                    (n, (d[n] - self.code.decode(
-                        codes[n], shape=meta[n][0], dtype=meta[n][1])
-                        ).astype(jnp.float32)[None])
-                    for n in d)
-                return jax.tree.map(lambda c: c[None], codes), new_ef
-            encode_fn = jax.jit(smap(
-                encode_body, in_specs=(P(axis), P(axis)),
-                out_specs=(P(axis), P(axis))),
-                donate_argnums=(0, 1))
-        elif identity:
-            encode_fn = None  # nothing to encode; sync consumes raw grads
-        else:
-            def encode_body(grads):
-                codes = self._encode_all(
-                    OrderedDict((n, g[0]) for n, g in grads.items()))
-                return jax.tree.map(lambda c: c[None], codes)
-            encode_fn = jax.jit(smap(
-                encode_body, in_specs=P(axis), out_specs=P(axis)),
-                donate_argnums=(0,))
-
-        sync_in = P() if overlap else P(axis)
-        if self.zero:
-            def sync_body(codes):
-                if overlap:
-                    # Already the full cross-rank sum; the owner chunk is
-                    # a slice (+ clip), no collective left to run.
-                    d_chunks = self._zero_sync(None, codes)
-                else:
-                    stripped = jax.tree.map(lambda c: c[0], codes)
-                    if identity and not use_ef:
-                        d_chunks = self._zero_sync(stripped, None)
-                    else:
-                        d_chunks = self._zero_sync(
-                            None, self._sync_codes(stripped, meta))
-                return jax.tree.map(lambda c: c[None], d_chunks)
-            sync_fn = jax.jit(smap(
-                sync_body, in_specs=sync_in, out_specs=P(axis)),
-                donate_argnums=(0,))
-
-            def update_body(params, state, d_chunks):
-                d = OrderedDict(
-                    (n, c[0]) for n, c in d_chunks.items())
-                return self._zero_apply(params, state, d)
-            update_fn = jax.jit(smap(
-                update_body, in_specs=(P(), state_specs, P(axis)),
-                out_specs=(P(), state_specs)),
-                donate_argnums=(0, 1))
-        else:
-            def sync_body(codes):
-                if overlap:
-                    d_ps = codes  # summed inside backward
-                else:
-                    codes = jax.tree.map(lambda c: c[0], codes)
-                    if identity and not use_ef:
-                        d_ps = collectives.psum_tree_bucketed(
-                            codes, self.axis,
-                            bucket_bytes=self.bucket_bytes,
-                            decompose=self.decompose_allreduce)
-                    else:
-                        d_ps = self._sync_codes(codes, meta)
-                if self.clip_norm is not None:
-                    d_ps = self._clip_tree(d_ps)
-                return d_ps
-            sync_fn = jax.jit(smap(
-                sync_body, in_specs=sync_in, out_specs=P()),
-                donate_argnums=(0,))
-
-            update_fn = jax.jit(smap(
-                lambda params, state, d_ps: self._apply_updates(
-                    params, state, d_ps),
-                in_specs=(P(), P(), P()), out_specs=(P(), P())),
-                donate_argnums=(0, 1))
-
-        ema_fn = None
-        if self.ema_decay is not None:
-            decay = self.ema_decay
-            ema_fn = jax.jit(smap(
-                lambda ema, p: jax.tree.map(
-                    lambda e, q: (decay * e
-                                  + (1.0 - decay) * q.astype(e.dtype)),
-                    ema, p),
-                in_specs=(P(), P()), out_specs=P()),
-                donate_argnums=(0,))
-
-        return {"grad": grad_fn, "encode": encode_fn, "sync": sync_fn,
-                "update": update_fn, "ema": ema_fn}
-
     def compile_step(self, loss_fn: Callable, *, has_aux: bool = False,
                      aux=None, accum_steps: int = 1,
                      remat: bool = False) -> None:
@@ -1013,7 +827,6 @@ class MPI_PS:
         fetches them afterwards.  Like all of ``aux`` the counters are
         **averaged over the ranks** (`_grads_and_aux`), so on several chips
         a count read there is the mean over the chips, not their sum.
-        (``profile=True`` runs the phases apart and logs no counters.)
 
         ``accum_steps=K`` enables gradient accumulation: each rank's batch
         shard splits into K microbatches swept sequentially by a
@@ -1054,10 +867,7 @@ class MPI_PS:
         self._has_counters = (has_aux and isinstance(self.aux, dict)
                               and "counters" in self.aux)
         built = jax.checkpoint(loss_fn) if remat else loss_fn
-        if self.profile:
-            self._phase_fns = self._make_phase_fns(built, has_aux)
-        else:
-            self._step_fn = self._make_spmd_step(built, has_aux)
+        self._step_fn = self._make_spmd_step(built, has_aux)
 
     # -- the step ------------------------------------------------------------
 
@@ -1101,56 +911,47 @@ class MPI_PS:
         if closure is not None:  # API parity with `ps.py:110-112`
             closure()
 
-        if self.profile:
-            loss = self._profiled_step(batch, data)
-            self.steps_completed += 1
-            if self._count_fused_sync:
-                self.fault_stats["fused_sync_encodes"] += 1
+        args = (self.params, self.state, self.aux, batch) + (
+            (self.extras,) if self.extras else ())
+        start = time.perf_counter()
+        out = self._step_program(args, batch)(*args)
+        dispatch = time.perf_counter() - start
+        del args    # donated: the new values are in `out`
+        if self._has_counters:
+            *out, counters = out
+            counter_log().append("MPI_PS.step", self.steps_completed,
+                                 counters)
+        if not self._warm:
+            # First call traces+compiles the SPMD program; that one-time
+            # cost is the TPU analogue of the reference's collective
+            # "prepare" (`ps.py:140`) — keep it out of isend_time so the
+            # per-step dispatch metric stays meaningful.
+            data["iallgather_prepare_time"] = dispatch
+            self._warm = True
         else:
-            args = (self.params, self.state, self.aux, batch) + (
-                (self.extras,) if self.extras else ())
-            start = time.perf_counter()
-            out = self._step_program(args, batch)(*args)
-            dispatch = time.perf_counter() - start
-            del args    # donated: the new values are in `out`
-            if self._has_counters:
-                *out, counters = out
-                counter_log().append("MPI_PS.step", self.steps_completed,
-                                     counters)
-            if not self._warm:
-                # First call traces+compiles the SPMD program; that one-time
-                # cost is the TPU analogue of the reference's collective
-                # "prepare" (`ps.py:140`) — keep it out of isend_time so the
-                # per-step dispatch metric stays meaningful.
-                data["iallgather_prepare_time"] = dispatch
-                self._warm = True
-            else:
-                data["isend_time"] = dispatch
-            # Reassign BEFORE blocking: the dispatch donated the old
-            # params/state buffers, so between dispatch and reassignment
-            # `self.params` points at deleted arrays — and block_until_ready
-            # is where nearly all step wall-time is spent.  Holding the NEW
-            # futures during the wait means an interrupt-triggered
-            # state_dict() (Ctrl-C checkpointing) always sees live buffers.
-            if self.extras:
-                (self.params, self.state, self.aux, loss, skipped,
-                 self.extras) = out
-            else:
-                self.params, self.state, self.aux, loss, skipped = out
-            self.steps_completed += 1
-            if self._count_fused_sync:
-                self.fault_stats["fused_sync_encodes"] += 1
-            if block:
-                start = time.perf_counter()
-                jax.block_until_ready(out)
-                data["comm_wait"] = time.perf_counter() - start
-            if block:
-                # Only when synced: with block=False the flag is still a
-                # device future, and storing a live array would break the
-                # dict[str, float] timings contract (and pin the buffer).
-                data["nonfinite_skip"] = float(skipped)
-
+            data["isend_time"] = dispatch
+        # Reassign BEFORE blocking: the dispatch donated the old
+        # params/state buffers, so between dispatch and reassignment
+        # `self.params` points at deleted arrays — and block_until_ready
+        # is where nearly all step wall-time is spent.  Holding the NEW
+        # futures during the wait means an interrupt-triggered
+        # state_dict() (Ctrl-C checkpointing) always sees live buffers.
+        if self.extras:
+            (self.params, self.state, self.aux, loss, skipped,
+             self.extras) = out
+        else:
+            self.params, self.state, self.aux, loss, skipped = out
+        self.steps_completed += 1
+        if self._count_fused_sync:
+            self.fault_stats["fused_sync_encodes"] += 1
         if block:
+            start = time.perf_counter()
+            jax.block_until_ready(out)
+            data["comm_wait"] = time.perf_counter() - start
+            # Only when synced: with block=False the flag is still a
+            # device future, and storing a live array would break the
+            # dict[str, float] timings contract (and pin the buffer).
+            data["nonfinite_skip"] = float(skipped)
             loss = float(loss)
         # Consensus cadence AFTER the step's reassignments: the fingerprint
         # program reads (does not donate) the new params, so it composes
@@ -1177,56 +978,6 @@ class MPI_PS:
             self._step_programs[key] = program
             register_program("MPI_PS.step", program.as_text)
         return program
-
-    def _profiled_step(self, batch, data):
-        fns = self._phase_fns
-        identity = isinstance(self.code, IdentityCodec)
-
-        t0 = time.perf_counter()
-        loss, grads, new_aux, ok = jax.block_until_ready(
-            fns["grad"](self.params, self.aux, batch))
-        data["backward_time"] = time.perf_counter() - t0
-
-        if self.skip_nonfinite and not bool(ok):
-            # Cross-rank consensus said skip: params/state/aux/extras all
-            # carry forward unchanged (the fused step's `jnp.where` gating,
-            # realized here by genuinely not running the later phases).
-            data["nonfinite_skip"] = 1.0
-            return jnp.mean(loss)
-        self.aux = new_aux
-        data["nonfinite_skip"] = 0.0
-
-        t0 = time.perf_counter()
-        if fns["encode"] is None:
-            codes = grads
-        elif self.error_feedback:
-            codes, new_ef = jax.block_until_ready(
-                fns["encode"](grads, self.extras["ef"]))
-            self.extras["ef"] = new_ef
-        else:
-            codes = jax.block_until_ready(fns["encode"](grads))
-        data["code_wait"] = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        pending = fns["sync"](codes)
-        data["isend_time"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        d_ps = jax.block_until_ready(pending)
-        data["comm_wait"] = time.perf_counter() - t0
-        # decode is fused with the gather in sync_fn; report it there.
-        data["decode_time"] = data["comm_wait"] if not identity else 0.0
-
-        t0 = time.perf_counter()
-        self.params, self.state = jax.block_until_ready(
-            fns["update"](self.params, self.state, d_ps))
-        data["optim_step_time"] = time.perf_counter() - t0
-
-        if fns["ema"] is not None:
-            t0 = time.perf_counter()
-            self.extras["ema"] = jax.block_until_ready(
-                fns["ema"](self.extras["ema"], self.params))
-            data["ema_time"] = time.perf_counter() - t0
-        return jnp.mean(loss)
 
     # -- replica-consensus SDC guard -----------------------------------------
 
@@ -1328,8 +1079,7 @@ class MPI_PS:
         return {"ok": False, "mismatched": bad, "first_leaf": first}
 
     def _maybe_check_consensus(self, data: dict) -> None:
-        """The in-step cadence hook: shared tail of the fused and profile
-        step paths."""
+        """The in-step cadence hook, at the tail of `step`."""
         if (self.consensus_every
                 and self.steps_completed % self.consensus_every == 0):
             out = self.check_consensus()

@@ -245,7 +245,7 @@ def main(argv=None):
                         "'overlap' = each bucket's collective is issued "
                         "INSIDE the backward pass via per-bucket "
                         "custom-vjp hooks (--bucket-mb 0 auto-tunes the "
-                        "bucket size from benchmarks/ROOFLINE.json)")
+                        "bucket size, parallel.overlap.auto_bucket_bytes)")
     p.add_argument("--overlap-reducer", default="rs_ag",
                    choices=["rs_ag", "psum"],
                    help="--sync-mode overlap, identity codec: lower each "
@@ -276,7 +276,7 @@ def main(argv=None):
                         "k ships while later buckets still compute, and "
                         "the PS decodes bucket b while b+1 is on the "
                         "wire.  N = target bucket payload bytes; 0 "
-                        "auto-tunes from benchmarks/ROOFLINE.json "
+                        "auto-tunes from the payload and the world size "
                         "(parallel.overlap.auto_bucket_bytes)")
     p.add_argument("--fused-encode", action="store_true",
                    help="with --async-bucket-bytes: compile the "

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — decided in one place.
 
-Every entry point that compiles (`train.main`, ``bench.py``,
-``chip_smoke.py`` phases, ``tests/conftest.py``) calls
+Every entry point that compiles (`train.main`, ``chip_smoke.py`` phases,
+``perfbench/run.py``, ``tests/conftest.py``) calls
 `configure_compile_cache` once, before its first compile:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set in the environment: JAX already uses

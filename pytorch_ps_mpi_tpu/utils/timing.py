@@ -8,9 +8,9 @@ from ``step()`` (`/root/reference/ps.py:116,136-148,160-168,191`) with keys
 pretty-printer (`mpi_comms.py:176-184`).  This module reproduces that
 contract — a metrics dict per step, an accumulator, and a summary printer —
 with the caveat that under XLA the phases fuse into one compiled program, so
-per-phase device time comes from optional phase-split execution (profile mode)
-while the default path reports host-side dispatch/block times and static byte
-counts.
+the step reports host-side dispatch/block times and static byte counts, and
+per-phase device time comes from a device trace (`program_scopes` below maps
+the trace's instructions to the program's named scopes).
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ from jax.profiler import TraceAnnotation
 
 # Canonical metric keys, matching the reference step() dict (`ps.py:193`).
 STEP_METRIC_KEYS = (
-    "code_wait",              # encode phase (host wall-clock or phase-split)
+    "code_wait",              # encode phase (0.0 where the step is one program)
     "iallgather_prepare_time",  # trace+compile of the SPMD program (one-time)
     "isend_time",             # collective dispatch latency
     "comm_wait",              # block_until_ready on the synced grads

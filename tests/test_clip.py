@@ -89,19 +89,6 @@ def test_huge_clip_is_noop(mesh8):
                                    rtol=1e-6, atol=1e-7, err_msg=n)
 
 
-def test_profile_mode_clips_in_sync_phase(mesh8):
-    named, batch = make_problem(seed=3)
-    clip = 1.5
-    prof = SGD(named, lr=0.05, mesh=mesh8, profile=True, clip_norm=clip)
-    prof.compile_step(loss_fn)
-    prof.step(batch)
-    want, norm = manual_clipped_step(named, batch, lr=0.05, clip=clip)
-    assert norm > clip
-    for n in want:
-        np.testing.assert_allclose(np.asarray(prof.params[n]), want[n],
-                                   rtol=2e-5, atol=1e-6, err_msg=n)
-
-
 def test_invalid_clip_rejected(mesh8):
     named, _ = make_problem()
     for bad in (0.0, -1.0, float("nan")):

@@ -227,12 +227,12 @@ def test_worker_trains_through_compressed_parm(codec):
         assert fs["parm_encodes"] >= 1
         assert fs["parm_bytes_raw"] > 0
         # Compressed wire: strictly below raw even with segment/meta
-        # overhead on this tiny MLP (the 0.5x gate runs at benchmark
-        # scale in WIRE_EVIDENCE.json).
+        # overhead on this tiny MLP (a 0.5x ratio needs payloads large
+        # enough to amortize that overhead, which this one is not).
         assert fs["parm_bytes_wire"] < fs["parm_bytes_raw"]
-        # The byte sentinel never tripped on a compressed frame (the
-        # checks>0 armed gate runs in WIRE_EVIDENCE.json, where credit
-        # stalls force the parked-flush path it instruments).
+        # The byte sentinel never tripped on a compressed frame (checks
+        # may be 0 here: only a credit stall forces the parked-flush
+        # path the sentinel instruments).
         assert fs["sentinel_trips"] == 0
         for n, p in srv.params.items():
             assert np.isfinite(np.asarray(p)).all(), n

@@ -371,3 +371,29 @@ def test_reduce_scatter_flats_bucketed_padding_correct(mesh8):
     for name in sizes:
         np.testing.assert_allclose(np.asarray(got2[name]),
                                    np.asarray(got[name]), rtol=1e-6)
+
+
+def test_plan_buckets_groups_by_dtype_and_caps_bytes():
+    from pytorch_ps_mpi_tpu.parallel.collectives import _plan_buckets
+
+    import jax.numpy as jnp
+
+    leaves = [jnp.zeros(100, jnp.float32),    # 400 B
+              jnp.zeros(50, jnp.int32),       # 200 B
+              jnp.zeros(200, jnp.float32),    # 800 B
+              jnp.zeros(5000, jnp.float32),   # 20 kB > cap: own bucket
+              jnp.zeros(10, jnp.float32)]     # 40 B
+    plan = _plan_buckets(leaves, bucket_bytes=1500)
+    # Every leaf appears exactly once.
+    flat = sorted(i for b in plan for i in b)
+    assert flat == [0, 1, 2, 3, 4]
+    for b in plan:
+        dtypes = {str(leaves[i].dtype) for i in b}
+        assert len(dtypes) == 1  # same-dtype buckets only
+        if len(b) > 1:  # multi-leaf buckets respect the cap
+            assert sum(leaves[i].size * leaves[i].dtype.itemsize
+                       for i in b) <= 1500
+    # The oversized leaf is alone in its bucket.
+    assert [3] in plan
+    # Deterministic: same input, same plan.
+    assert plan == _plan_buckets(leaves, bucket_bytes=1500)

@@ -245,32 +245,3 @@ def test_ef_and_ema_compose():
     assert opt.ef_state["w"].shape[0] == 2
     sd = opt.state_dict()
     assert sd["ef"] is not None and sd["ema"] is not None
-
-
-def test_ef_ema_profile_matches_fused():
-    """Phase-split profile mode composes with error_feedback + ema_decay
-    (r2 VERDICT missing #3): identical trajectory to the fused step —
-    params, the carried per-rank residual, and the EMA weights — with the
-    per-phase metrics populated (code_wait covers the EF encode, ema_time
-    the average maintenance)."""
-    kw = dict(code=TopKCodec(fraction=0.5), error_feedback=True,
-              ema_decay=0.9)
-    fused = _mlp_opt(4, **kw)
-    prof = _mlp_opt(4, profile=True, **kw)
-    for b in _batches(4, 5):
-        loss_f, _ = fused.step(b)
-        loss_p, data = prof.step(b)
-        np.testing.assert_allclose(loss_p, loss_f, rtol=1e-5, atol=1e-6)
-    for n in fused.params:
-        np.testing.assert_allclose(np.asarray(prof.params[n]),
-                                   np.asarray(fused.params[n]),
-                                   rtol=1e-5, atol=1e-6, err_msg=n)
-        np.testing.assert_allclose(np.asarray(prof.ef_state[n]),
-                                   np.asarray(fused.ef_state[n]),
-                                   rtol=1e-5, atol=1e-6, err_msg=n)
-        np.testing.assert_allclose(np.asarray(prof.ema_params[n]),
-                                   np.asarray(fused.ema_params[n]),
-                                   rtol=1e-5, atol=1e-6, err_msg=n)
-    assert data["code_wait"] > 0
-    assert data["ema_time"] > 0
-    assert data["comm_wait"] > 0

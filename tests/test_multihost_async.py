@@ -125,8 +125,7 @@ def test_two_worker_processes_train_over_tcp(code):
     # limited host oscillates (identity) or outright diverges (int8
     # quantization noise x momentum — the classic lossy-compression
     # pathology).  This test is the TCP protocol/convergence oracle, not a
-    # momentum stress test; the staleness pathology is bench.py's
-    # `async_virtual` territory.
+    # momentum stress test.
     srv = AsyncSGDServer(list(params.items()), lr=0.05, momentum=0.5,
                          quota=2, code=None if code == "identity" else code)
     srv.compile_step(mlp_loss_fn)
