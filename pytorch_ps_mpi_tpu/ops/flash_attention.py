@@ -1,23 +1,39 @@
-"""Flash attention — Pallas TPU kernel for the attention hot op.
+"""Flash attention — Pallas TPU kernels for the attention hot op.
 
 Dense softmax attention materializes the ``[S, S]`` score matrix in HBM;
 at long context that matrix IS the memory bill.  This module computes
-exact attention with O(S · BLOCK) live memory:
+exact attention with O(S · tile) live memory, in three Mosaic calls whose
+names the device trace and the benchmark's readers know (`KERNELS`):
 
-* **Forward** (`_fwd_kernel`): one Pallas kernel, grid ``(B·H, q_blocks,
-  k_blocks)`` with the k sweep minor — for each 128-row q tile the kernel
-  holds a running row-max ``m``, normalizer ``l`` and unnormalized
-  accumulator in VMEM scratch (TPU grids run sequentially, so scratch
-  carries across the k sweep), rescaling per visiting k tile: the same
-  streaming softmax as `parallel.ring_attention`, here at tile granularity
-  on one chip.  Scores ride the MXU via ``jnp.dot`` in f32.
-* **Backward**: two Pallas kernels (FlashAttention-2 decomposition) under
-  ``jax.custom_vjp`` — `_bwd_dkdv_kernel` sweeps q tiles per k tile
-  (grid ``(B·H, k_blocks, q_blocks)``), `_bwd_dq_kernel` sweeps k tiles
-  per q tile — each recomputing ``P`` from the saved per-row logsumexp
-  (``exp(s - lse)``, no second softmax) and accumulating in VMEM scratch,
-  so the backward never materializes ``[S, S]`` either.  Fully-masked
-  causal tiles skip their MXU work in both kernels, same as the forward.
+* **Forward** (`flash_fwd`, `_fwd_kernel`): grid ``(B·H, q_tiles,
+  k_tiles)`` with the k sweep minor.  For each q tile the kernel holds a
+  running row-max ``m``, normalizer ``l`` and unnormalized accumulator in
+  VMEM scratch (TPU grids run sequentially, so scratch carries across the
+  k sweep) and rescales them per visiting block of k: the same streaming
+  softmax as `parallel.ring_attention`, here on one chip.  Operands go into
+  the MXU as they come (bf16), products accumulate in f32, the softmax
+  statistics are f32.
+* **Backward**: two kernels (FlashAttention-2 decomposition) under
+  ``jax.custom_vjp`` — `flash_bwd_dkdv` sweeps q per block of k (grid
+  ``(B·H, k_tiles, q_tiles)``), `flash_bwd_dq` sweeps k per block of q —
+  each recomputing ``P`` from the saved per-row logsumexp (``exp(s - lse)``,
+  no second softmax) and accumulating in VMEM scratch, so the backward never
+  materializes ``[S, S]`` either.
+
+**Two levels of tiling, the inner one following the mask.**  The grid tile
+(what a `BlockSpec` copies into VMEM) is large — a head's whole sequence
+where that fits — so the grid has few steps; inside it each kernel walks
+compute *sub-blocks* with loops whose bounds come from the tile's place in
+the grid (`_k_segments`, `_q_segments`).  A sub-block wholly above the
+causal diagonal, or wholly in the padding, is never entered; one wholly
+under the diagonal runs the *interior* body, which builds no iota and makes
+no compare or select; only the sub-blocks the diagonal or the padded tail
+crosses run the masked body, and the ``< seq_len`` tests are compiled only
+where something is padded.  ``causal=False`` is interior everywhere but
+the tail.  Where a head is one tile the bounds are Python ints and the
+loops unroll; otherwise they are device loops.  `tile_plan` is the one
+source of both levels' sizes, from the shape, and counts with the kernels'
+own bounds how many sub-blocks a head enters, masks and skips.
 
 Composition: `flash_attention` is a drop-in for
 `parallel.ring_attention.dense_attention` (``[B, S, H, D]`` in/out,
@@ -34,10 +50,10 @@ kernel logic, just slow) — by name only, for the CPU test mesh;
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from jax.experimental import pallas as pl
@@ -45,20 +61,130 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import use_interpreter
 
-BLOCK_Q = 512    # q tile rows per grid step (VMEM acc: BLOCK_Q x D f32)
-BLOCK_K = 1024   # k/v tile rows per grid step (scores: BLOCK_Q x BLOCK_K)
-# Backward tiles are square and smaller: the bwd body keeps ~4 blk_q x blk_k
-# f32 intermediates (s, p, dp, ds) live at once, so 512x512 (4 x 1 MB)
-# fits VMEM with double buffering where the fwd's 512x1024 would not.
-BWD_BLOCK_Q = 512
-BWD_BLOCK_K = 512
-# Tile sizes from an on-chip sweep at [4, 4096, 8, 128] bf16 causal:
-# (512, 1024) 1.36 ms/call vs (512, 512) 2.94, (256, 512) 3.34,
-# (1024, 512) 2.37, (512, 2048) 1.57 — bigger k tiles amortize the
-# rescale/bookkeeping VPU work between MXU calls; XLA dense: 4.6 ms.
-BLOCK = 128      # lane tile the lse output rides; also the padding unit
+BLOCK = 128      # lane tile the row statistics ride; also the padding unit
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked-row math
                  # finite without jnp.where laundering inside the kernel
+KERNELS = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T, no transpose materialised
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+class Tiles(NamedTuple):
+    """One kernel's two levels: the grid tile a `BlockSpec` brings into VMEM
+    (``tile_q`` rows of q / dO / the row statistics, ``tile_k`` rows of
+    k / v) and the compute sub-block the kernel walks inside it."""
+    tile_q: int
+    tile_k: int
+    sub_q: int
+    sub_k: int
+
+
+class Counts(NamedTuple):
+    """Sub-blocks of one head: ``entered`` (computed), ``masked`` (those of
+    the entered that pay iota, compare and select: the diagonal and the
+    padded tail) and ``skipped`` (above the diagonal or past the tail)."""
+    entered: int
+    masked: int
+    skipped: int
+
+
+class Plan(NamedTuple):
+    tiles: dict    # kernel name -> Tiles
+    counts: dict   # kernel name -> Counts
+
+
+# Tiles and sub-blocks from a chip sweep (TPU v5e, bf16, causal; ms a call,
+# 4–20 calls back to back on the host clock, the best of three rounds).
+# "before" = the single-level tiles this table replaces (forward 512 x 1024,
+# backward 512 x 512, from one forward sweep at [4, 4096, 8, 128]).  A row
+# is (own, other | own, other): the kernel's own side is q for `flash_fwd`
+# and `flash_bwd_dq`, k for `flash_bwd_dkdv`; tile first, sub-block after the
+# bar.  * = a head is one tile, so every loop bound is static and unrolls.
+#
+#   [BH, S_pad, D_pad / Dv_pad]            flash_fwd  _bwd_dq  _bwd_dkdv
+#   [128, 1024, 128 / 128]  before          0.734   0.897   0.990
+#    *(1024, 1024 |  128,  128)              0.401   0.513   0.541
+#    *(1024, 1024 |  256,  256)              0.394   0.477   0.678
+#    *(1024, 1024 |  512,  512)              0.400   0.477   0.593
+#    *(1024, 1024 | 1024, 1024)              0.472   0.584   0.769
+#     ( 512, 1024 |  256,  256)              0.810   0.764   1.126
+#     ( 512,  512 |  512,  512)              0.629   0.906   0.861
+#     ( 512,  512 |  128,  128)              1.945   1.847   2.240
+#   [64, 2048, 128 / 128]  before           1.268   1.434   1.750
+#    *(2048, 2048 |  128,  128)                -       -     0.878
+#    *(2048, 2048 |  256,  256)              0.569   0.697   1.184
+#    *(2048, 2048 |  512,  512)              0.607   0.733   0.972
+#     (1024, 2048 |  512,  512)              0.797   0.929     -
+#     (1024, 1024 |  512,  512)                -       -     1.265
+#   [32, 4096, 128 / 128]  before           2.047   2.363   3.152
+#     (1024, 4096 |  512,  512)              1.258   1.528   2.138
+#     (2048, 4096 |  512,  512)                -       -     1.960
+#     (2048, 2048 |  512,  512)              1.291   1.742   2.008
+#     (1024, 2048 |  512,  512)              1.334   1.792   2.051
+#     (1024, 1024 |  512,  512)              1.493   2.118   2.234
+#     (1024, 1024 |  256,  256)              2.546   2.819   3.052
+#   [64, 8192, 256 / 128]  before           18.98   23.91   30.99
+#     (1024, 8192 |  512,  512)              11.50   16.82   20.84
+#     (2048, 8192 |  512,  512)              11.45   16.84   19.89
+#     (2048, 2048 |  512,  512)              12.70   19.38   20.97
+#     (1024, 2048 |  512,  512)              13.43   19.99   21.32
+#     (1024, 2048 |  256,  512)              14.26   21.01   24.38
+#     (1024, 1024 |  256,  256)              22.85   24.48   29.88
+#     (1024, 1024 |  128,  128)              55.99   58.72   77.62
+#
+# What it taught.  (1) A device loop (traced bounds) costs far more than
+# the work it skips unless its sub-blocks are large: 512 x 512 there; two
+# sub-blocks an iteration bought nothing (forward, dq: -1 %) or lost (dkdv:
+# +10 to +70 %).  (2) Where a head is one tile everything is static, the
+# compiler schedules across sub-blocks and small ones win: at S_pad 2048 the
+# same sub-blocks under a device loop take 1.3–1.4 times as long.  (3) The
+# other side whole in VMEM (k / v for the q-major kernels, q / dO / row
+# statistics for dkdv) beats any split of it up to S_pad 8192.  (4) The
+# interior body alone, at the old tiles, is worth 13 % (dkdv 0.990 -> 0.861).
+# (5) dkdv's 256 x 256 is worse than both its neighbours in every unrolled
+# shape (its two transposed products; not looked into).
+_WHOLE_HEAD = 2048   # S_pad up to which a head is one tile (swept to here)
+_WHOLE_SIDE = 8192   # ... and the other operand is, beyond it (swept to here)
+_STATIC_SUB = {"flash_fwd": (256, 256), "flash_bwd_dq": (256, 256),
+               "flash_bwd_dkdv": (128, 128)}
+_LOOP_SUB = (512, 512)
+_LOOP_TILE = {"flash_fwd": 1024, "flash_bwd_dq": 1024, "flash_bwd_dkdv": 2048}
+# One level (sub-block = grid tile), the sizes from before the sweep: what
+# the sweep did not cover falls here and is no slower than it was.
+_ONE_LEVEL = {"flash_fwd": (512, 1024), "flash_bwd_dq": (512, 512),
+              "flash_bwd_dkdv": (512, 512)}
+
+
+def tile_plan(s_pad, d_pad, dv_pad, causal, *, true_len=None,
+              blk_q=None, blk_k=None) -> Plan:
+    """Grid tiles and sub-blocks of the three kernels, from what the code
+    can see: the padded length, the two padded widths and the mask — and,
+    counted with the kernels' own bounds, how many sub-blocks a head enters,
+    masks and skips (``true_len`` under ``s_pad`` adds the padded tail).
+    ``blk_q`` / ``blk_k`` force the sub-block (the tests' way to small
+    ones); the grid tile stays the shape's, in whole sub-blocks."""
+    true_len = s_pad if true_len is None else true_len
+    swept = causal and s_pad >= 1024 and max(d_pad, dv_pad) <= 256
+    tiles, counts = {}, {}
+    for kernel in KERNELS:
+        if not swept:
+            tq, tk = sq, sk = _ONE_LEVEL[kernel]
+        elif s_pad <= _WHOLE_HEAD:
+            tq, tk, (sq, sk) = s_pad, s_pad, _STATIC_SUB[kernel]
+        else:   # its own side in tiles, the other side whole
+            tq = tk = min(s_pad, _WHOLE_SIDE)
+            sq, sk = _LOOP_SUB
+            if kernel == "flash_bwd_dkdv":
+                tk = _LOOP_TILE[kernel]
+            else:
+                tq = _LOOP_TILE[kernel]
+        sq = min(sq if blk_q is None else blk_q, s_pad)
+        sk = min(sk if blk_k is None else blk_k, s_pad)
+        t = Tiles(-(-min(tq, s_pad) // sq) * sq, -(-min(tk, s_pad) // sk) * sk,
+                  sq, sk)
+        tiles[kernel] = t
+        counts[kernel] = _count(kernel, t, s_pad, causal, true_len)
+    return Plan(tiles, counts)
 
 
 def _named(kernel: str) -> dict:
@@ -79,65 +205,294 @@ def _pad_to(x, size, axis):
     return jnp.pad(x, pad)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, seq_len, n_k, blk_q, blk_k):
-    iq, ik = pl.program_id(1), pl.program_id(2)
+# -- which sub-blocks a kernel enters ----------------------------------------
+# Every bound below is worked out on Python ints when the grid has one tile
+# a head (the loops then unroll, and `tile_plan` counts with the same code)
+# and on traced int32 scalars from the program ids otherwise.
 
-    @pl.when(ik == 0)
+
+def _static(*xs):
+    return all(isinstance(x, int) for x in xs)
+
+
+def _span(x, d, n, up=False):
+    """``x / d`` rounded down (``up``: up), held to ``[0, n]``."""
+    if _static(x):
+        return min(n, max(0, -(-x // d) if up else x // d))
+    return jnp.minimum(n, lax.div(jnp.maximum(x, 0) + (d - 1 if up else 0), d))
+
+
+def _least(a, b):
+    return min(a, b) if _static(a, b) else jnp.minimum(a, b)
+
+
+def _most(a, b):
+    return max(a, b) if _static(a, b) else jnp.maximum(a, b)
+
+
+def _unless(cond, x, other):
+    """``other`` where ``cond`` holds, else ``x``."""
+    if isinstance(cond, bool):
+        return other if cond else x
+    return jnp.where(cond, other, x)
+
+
+def _k_segments(q0, k0, n, t, causal, seq_len, q_tail):
+    """For the q rows ``[q0, q0 + sub_q)`` and the ``n`` k sub-blocks of the
+    tile that starts at column ``k0``: ``(start, stop, masked)`` runs of
+    sub-blocks to enter, in order — the interior ones (wholly under the
+    diagonal, no padded column), then the masked ones; what follows lies
+    above the diagonal or in the padding and is never entered.  ``seq_len``
+    is None when nothing is padded; ``q_tail`` (the backward) also masks
+    padded q rows, whose logsumexp is ``NEG_INF``."""
+    mid = hi = n
+    if causal:
+        mid = _span(q0 + 1 - k0, t.sub_k, n)
+        hi = _span(q0 + t.sub_q - k0, t.sub_k, n, up=True)
+    if seq_len is not None:
+        mid = _least(mid, _span(seq_len - k0, t.sub_k, n))
+        hi = _least(hi, _span(seq_len - k0, t.sub_k, n, up=True))
+        if q_tail:
+            mid = _unless(q0 + t.sub_q > seq_len, mid, 0)
+            hi = _unless(q0 >= seq_len, hi, 0)
+    mid = _least(mid, hi)
+    return [(0, mid, False), (mid, hi, True)]
+
+
+def _q_segments(k0, q0, n, t, causal, seq_len):
+    """The same for `flash_bwd_dkdv`, which holds the k rows ``[k0, k0 +
+    sub_k)`` and walks the ``n`` q sub-blocks of the tile that starts at row
+    ``q0``: above the diagonal nothing, on it masked, under it interior,
+    then (only where something is padded) the masked tail."""
+    lo, mid, full, hi = 0, 0, n, n
+    if causal:
+        lo = _span(k0 - q0, t.sub_q, n)
+        mid = _span(k0 + t.sub_k - 1 - q0, t.sub_q, n, up=True)
+    if seq_len is not None:
+        full = _span(seq_len - q0, t.sub_q, n)
+        hi = _span(seq_len - q0, t.sub_q, n, up=True)
+        mid = _unless(k0 + t.sub_k > seq_len, mid, hi)   # padded columns
+        hi = _unless(k0 >= seq_len, hi, 0)               # nothing but
+        full = _least(full, hi)
+    mid = _least(mid, hi)
+    lo, full = _least(lo, mid), _most(mid, full)
+    segments = [(lo, mid, True), (mid, full, False)]
+    if seq_len is not None:
+        segments.append((full, hi, True))
+    return segments
+
+
+def _entered(kernel, t, s_q, s_k, causal, seq_len):
+    """``(q0, k0, masked)`` of every sub-block of a head that ``kernel``
+    enters, by the kernel's own bounds on Python ints."""
+    if kernel == "flash_bwd_dkdv":
+        for k0 in range(0, s_k, t.sub_k):
+            for q0 in range(0, s_q, t.tile_q):
+                for start, stop, masked in _q_segments(
+                        k0, q0, t.tile_q // t.sub_q, t, causal, seq_len):
+                    for a in range(start, stop):
+                        yield q0 + a * t.sub_q, k0, masked
+    else:
+        for q0 in range(0, s_q, t.sub_q):
+            for k0 in range(0, s_k, t.tile_k):
+                for start, stop, masked in _k_segments(
+                        q0, k0, t.tile_k // t.sub_k, t, causal, seq_len,
+                        q_tail=kernel == "flash_bwd_dq"):
+                    for c in range(start, stop):
+                        yield q0, k0 + c * t.sub_k, masked
+
+
+def _count(kernel, t, s_pad, causal, true_len) -> Counts:
+    s_q, s_k = (-(-s_pad // t.tile_q) * t.tile_q,
+                -(-s_pad // t.tile_k) * t.tile_k)
+    masks = [masked for _, _, masked in _entered(
+        kernel, t, s_q, s_k, causal, _seq_len(true_len, s_q, s_k))]
+    return Counts(len(masks), sum(masks),
+                  (s_q // t.sub_q) * (s_k // t.sub_k) - len(masks))
+
+
+def _loop(start, stop, body):
+    """``body(c)`` for c in ``[start, stop)``: unrolled where the bounds are
+    Python ints, a device loop where they come from the program ids."""
+    if _static(start, stop):
+        for c in range(start, stop):
+            body(c)
+    else:
+        lax.fori_loop(jnp.int32(start), jnp.int32(stop),
+                      lambda c, _: body(c), None)
+
+
+def _when(cond, fn):
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _rows(c, size):
+    """The ``c``-th run of ``size`` rows of a tile."""
+    return pl.ds(c * size if _static(c) else pl.multiple_of(c * size, size),
+                 size)
+
+
+def _lanes(x, n):
+    """A lane-replicated ``(rows, BLOCK)`` statistic at ``n`` columns."""
+    return x if n == BLOCK else jnp.tile(x, (1, n // BLOCK))
+
+
+def _masker(t, causal, seq_len, q_tail):
+    """``keep(q0, k0)`` for the masked sub-block whose corner is row ``q0``,
+    column ``k0``: one compare on an iota difference built once a grid step,
+    and the ``< seq_len`` tests only where something is padded."""
+    row = lax.broadcasted_iota(jnp.int32, (t.sub_q, t.sub_k), 0)
+    col = lax.broadcasted_iota(jnp.int32, (t.sub_q, t.sub_k), 1)
+    diff = row - col
+
+    def keep(q0, k0):
+        mask = diff >= k0 - q0 if causal else None
+        if seq_len is not None:
+            tail = col < seq_len - k0             # padded K tail: no mass
+            if q_tail:
+                tail &= row < seq_len - q0
+            mask = tail if mask is None else mask & tail
+        return mask
+    return keep
+
+
+def _tile_ids(n_qt, n_kt, q_axis, k_axis):
+    """The tile's place in the grid; the int 0 where a head has one tile, so
+    that every bound after it is a Python int."""
+    return (pl.program_id(q_axis) if n_qt > 1 else 0,
+            pl.program_id(k_axis) if n_kt > 1 else 0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, scale, causal, seq_len, t, n_qt, n_kt):
+    iq, ik = _tile_ids(n_qt, n_kt, 1, 2)
+    dv = v_ref.shape[-1]
+
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+    _when(ik == 0, _init)
 
-    def _accumulate():
-        # Matmuls consume the native (bf16) operands — the MXU's fast path —
-        # and accumulate in f32 via preferred_element_type; only the
-        # softmax bookkeeping lives in f32.
-        q = q_ref[0]                          # (BLK_Q, D)
-        k = k_ref[0]                          # (BLK_K, D)
-        v = v_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    keep = _masker(t, causal, seq_len, q_tail=False)
+    for a in range(t.tile_q // t.sub_q):
+        rows = _rows(a, t.sub_q)
+        q0, k0 = iq * t.tile_q + a * t.sub_q, ik * t.tile_k
+        q = q_ref[0, rows, :]                 # (sub_q, D)
 
-        k_pos = ik * blk_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = k_pos < seq_len                # padded K tail: no mass
-        if causal:
-            q_pos = iq * blk_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask &= q_pos >= k_pos
-        s = jnp.where(mask, s, NEG_INF)
+        def sub_block(c, masked):
+            # Matmuls consume the native (bf16) operands — the MXU's fast
+            # path — and accumulate in f32 via preferred_element_type; only
+            # the softmax bookkeeping lives in f32.  m and l stay
+            # lane-replicated (sub_q, BLOCK), so no lane is ever sliced.
+            cols = _rows(c, t.sub_k)
+            k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+            s = lax.dot_general(q, k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = jnp.where(keep(q0, k0 + c * t.sub_k), s, NEG_INF)
+            m_prev, l_prev = m_ref[rows, :], l_ref[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)   # <= 1, finite by NEG_INF
+            p = jnp.exp(s - _lanes(m_new, t.sub_k))   # masked entries → 0
+            l_ref[rows, :] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[rows, :] = m_new
+            acc_ref[rows, :] = (
+                acc_ref[rows, :] * _lanes(alpha, dv)
+                + jnp.dot(p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32))
 
-        m_prev = m_ref[:, 0]                  # (BLOCK,)
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)       # <= 1, finite by NEG_INF
-        p = jnp.exp(s - m_new[:, None])       # masked entries → 0
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.dot(p.astype(v.dtype), v,
-                                  preferred_element_type=jnp.float32))
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        for start, stop, masked in _k_segments(
+                q0, k0, t.tile_k // t.sub_k, t, causal, seq_len, q_tail=False):
+            _loop(start, stop, functools.partial(sub_block, masked=masked))
 
-    if causal:
-        # Tiles strictly above the diagonal are fully masked: skip their
-        # MXU work entirely (≈half the grid at long context).  The tile
-        # intersects the diagonal iff its first q row >= its first k row
-        # minus (blk_k - 1), i.e. some (q_pos >= k_pos) pair exists.
-        pl.when((iq + 1) * blk_q - 1 >= ik * blk_k)(_accumulate)
-    else:
-        _accumulate()
-
-    @pl.when(ik == n_k - 1)
     def _finish():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / _lanes(safe, dv)).astype(o_ref.dtype)
         # Per-row logsumexp: the single residual the backward needs.
-        # Lane-replicated to a (BLOCK, BLOCK) tile: Mosaic requires output
+        # Lane-replicated to a (rows, BLOCK) tile: Mosaic requires output
         # blocks whose last two dims are (8k, 128k), so a per-row vector
         # rides a full lane tile (the in-tree kernel's MIN_BLOCK_SIZE
         # trick); the caller reads lane 0.
-        lse = (m_ref[:, 0] + jnp.log(safe)).astype(jnp.float32)
-        lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
+        lse_ref[0] = m_ref[...] + jnp.log(safe)
+    _when(ik == n_kt - 1, _finish)
+
+
+def _vmem(block_bytes, scratch_bytes, t):
+    """`compiler_params` for a kernel whose double-buffered blocks, scratch
+    and a sub-block's f32 intermediates pass the default scoped VMEM (16 MiB
+    on the v5e); nothing where they do not."""
+    need = 2 * block_bytes + scratch_bytes + 8 * 4 * t.sub_q * t.sub_k
+    if need <= 12 << 20:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(2 * need, 96 << 20))}
+
+
+def _seq_len(true_len, *padded):
+    """None where nothing is padded: the tail tests are then not compiled."""
+    return None if all(true_len == s for s in padded) else true_len
+
+
+def _kv_index(t, causal, n_kt):
+    """Block index of k / v for the q-major kernels.  Above the diagonal the
+    kernel enters nothing; holding the index at the last tile it needs
+    spares the copy of a tile nobody reads."""
+    if causal and n_kt > 1:
+        return lambda b, i, j: (
+            b, jnp.minimum(j, ((i + 1) * t.tile_q - 1) // t.tile_k), 0)
+    return lambda b, i, j: (b, j, 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "t", "causal", "scale", "true_len", "interpret"))
+def _fwd_tiles(q3, k3, v3, *, t, causal, scale, true_len, interpret):
+    """The forward call at the tiles ``t``, under `jax.jit` so that the
+    layers of a model share one trace of the kernel."""
+    bh, s_pad, d = q3.shape
+    dv = v3.shape[-1]
+    q3 = _pad_to(q3, t.tile_q, 1)
+    k3, v3 = _pad_to(k3, t.tile_k, 1), _pad_to(v3, t.tile_k, 1)
+    s_q, s_k = q3.shape[1], k3.shape[1]
+    n_qt, n_kt = s_q // t.tile_q, s_k // t.tile_k
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal,
+        seq_len=_seq_len(true_len, s_q, s_k), t=t, n_qt=n_qt, n_kt=n_kt)
+    kv = _kv_index(t, causal, n_kt)
+    size = q3.dtype.itemsize
+    out, lse = pl.pallas_call(
+        kernel,
+        grid=(bh, n_qt, n_kt),
+        in_specs=[
+            pl.BlockSpec((1, t.tile_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, t.tile_k, d), kv),
+            pl.BlockSpec((1, t.tile_k, dv), kv),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, t.tile_q, dv), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, t.tile_q, BLOCK), lambda b, i, j: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s_q, dv), q3.dtype),
+            jax.ShapeDtypeStruct((bh, s_q, BLOCK), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((t.tile_q, dv), jnp.float32),     # acc
+            pltpu.VMEM((t.tile_q, BLOCK), jnp.float32),  # m (lane-replicated)
+            pltpu.VMEM((t.tile_q, BLOCK), jnp.float32),  # l
+        ],
+        interpret=interpret,
+        **_vmem(size * (t.tile_q * (d + dv) + t.tile_k * (d + dv))
+                + 4 * t.tile_q * BLOCK,
+                4 * t.tile_q * (dv + 2 * BLOCK), t),
+        **_named("flash_fwd"),
+    )(q3, k3, v3)
+    return out[:, :s_pad], lse[:, :s_pad]
 
 
 def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
@@ -145,51 +500,13 @@ def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
     """``q3,k3: [BH, S_pad, D_pad]``, ``v3: [BH, S_pad, Dv_pad]`` already
     padded to BLOCK/lane tiles (``Dv_pad`` may differ from ``D_pad``: the
     accumulator and the output take v's width); returns ``(out [BH, S_pad,
-    Dv_pad], lse [BH, S_pad])``.  ``true_len`` masks the padded K tail so
-    it carries no softmax mass.
-
-    Tile sizes clamp to the (padded) sequence: big BLOCK_Q×BLOCK_K tiles
-    amortize grid-step overhead and keep the MXU fed (the 128×128 version
-    measured ~2.4× slower than XLA dense at S=4096); short sequences fall
-    back to one tile."""
-    bh, s_pad, d = q3.shape
-    dv = v3.shape[-1]
-    blk_q = min(BLOCK_Q if blk_q is None else blk_q, s_pad)
-    blk_k = min(BLOCK_K if blk_k is None else blk_k, s_pad)
-    n_q, n_k = -(-s_pad // blk_q), -(-s_pad // blk_k)
-    s_pad_q, s_pad_k = n_q * blk_q, n_k * blk_k
-    if s_pad_q != s_pad:
-        q3 = _pad_to(q3, blk_q, 1)
-    if s_pad_k != s_pad:
-        k3, v3 = _pad_to(k3, blk_k, 1), _pad_to(v3, blk_k, 1)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               seq_len=true_len, n_k=n_k,
-                               blk_q=blk_q, blk_k=blk_k)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_q, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, BLOCK), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_pad_q, dv), q3.dtype),
-            jax.ShapeDtypeStruct((bh, s_pad_q, BLOCK), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, dv), jnp.float32),     # acc
-            pltpu.VMEM((blk_q, BLOCK), jnp.float32),  # m (lane-replicated)
-            pltpu.VMEM((blk_q, BLOCK), jnp.float32),  # l
-        ],
-        interpret=interpret,
-        **_named("flash_fwd"),
-    )(q3, k3, v3)
-    return out, lse
+    Dv_pad], lse [BH, S_pad, BLOCK])``.  ``true_len`` masks the padded K
+    tail so it carries no softmax mass.  Tiles and sub-blocks are
+    `tile_plan`'s; ``blk_q`` / ``blk_k`` force the sub-block."""
+    plan = tile_plan(q3.shape[1], q3.shape[2], v3.shape[2], causal,
+                     blk_q=blk_q, blk_k=blk_k)
+    return _fwd_tiles(q3, k3, v3, t=plan.tiles["flash_fwd"], causal=causal,
+                      scale=scale, true_len=true_len, interpret=interpret)
 
 
 def _to_bh(x):
@@ -225,160 +542,177 @@ def _flash_fwd_vjp(q, k, v, causal, scale, interpret):
     return _flash_fwd_res(q, k, v, causal, scale, interpret)
 
 
-def _bwd_probs(q, k, do, v, lse_col, delta_col, *, scale, causal, seq_len,
-               q0, k0):
-    """Shared bwd tile math: recomputed ``p`` from the saved logsumexp and
-    ``ds`` — the (blk_q, blk_k) pieces both backward kernels need.  Masking
-    happens BEFORE the exp: padded q rows carry lse = -inf-ish, and
-    ``exp(s - lse)`` would overflow where the forward's own mask kept it
-    finite."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    q_pos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    mask = (k_pos < seq_len) & (q_pos < seq_len)
-    if causal:
-        mask &= q_pos >= k_pos
-    p = jnp.exp(jnp.where(mask, s - lse_col, NEG_INF))
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_col) * scale
+def _bwd_probs(q, k, v, do, lse, delta, keep, scale):
+    """Shared bwd sub-block math: recomputed ``p`` from the saved logsumexp
+    and ``ds`` — the (sub_q, sub_k) pieces both backward kernels need.
+    ``lse`` and ``delta`` are lane-replicated ``(sub_q, BLOCK)``.  Masking
+    (``keep`` is None in the interior) happens BEFORE the exp: padded q rows
+    carry lse = -inf-ish, and ``exp(s - lse)`` would overflow where the
+    forward's own mask kept it finite."""
+    n = k.shape[0]
+    e = lax.dot_general(q, k, _NT,
+                        preferred_element_type=jnp.float32) * scale
+    e = e - _lanes(lse, n)
+    if keep is not None:
+        e = jnp.where(keep, e, NEG_INF)
+    p = jnp.exp(e)
+    dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+    ds = p * (dp - _lanes(delta, n)) * scale
     return p, ds
 
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, scale, causal, seq_len, n_q, blk_q, blk_k):
-    j, i = pl.program_id(1), pl.program_id(2)   # k tile major, q sweep minor
+                     *, scale, causal, seq_len, t, n_qt, n_kt):
+    iq, ik = _tile_ids(n_qt, n_kt, 2, 1)   # k tile major, q sweep minor
 
-    @pl.when(i == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+    _when(iq == 0, _init)
 
-    def _accumulate():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        p, ds = _bwd_probs(
-            q, k, do, v, lse_ref[0][:, :1], delta_ref[0][:, :1],
-            scale=scale, causal=causal, seq_len=seq_len,
-            q0=i * blk_q, k0=j * blk_k)
-        dv_acc[...] += jnp.dot(p.astype(do.dtype).T, do,
-                               preferred_element_type=jnp.float32)
-        dk_acc[...] += jnp.dot(ds.astype(q.dtype).T, q,
-                               preferred_element_type=jnp.float32)
+    keep = _masker(t, causal, seq_len, q_tail=True)
+    for c in range(t.tile_k // t.sub_k):
+        cols = _rows(c, t.sub_k)
+        q0, k0 = iq * t.tile_q, ik * t.tile_k + c * t.sub_k
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
 
-    if causal:
-        pl.when((i + 1) * blk_q - 1 >= j * blk_k)(_accumulate)
-    else:
-        _accumulate()
+        def sub_block(a, masked):
+            rows = _rows(a, t.sub_q)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            p, ds = _bwd_probs(
+                q, k, v, do, lse_ref[0, rows, :], delta_ref[0, rows, :],
+                keep(q0 + a * t.sub_q, k0) if masked else None, scale)
+            dv_acc[cols, :] += lax.dot_general(
+                p.astype(do.dtype), do, _TN,
+                preferred_element_type=jnp.float32)
+            dk_acc[cols, :] += lax.dot_general(
+                ds.astype(q.dtype), q, _TN,
+                preferred_element_type=jnp.float32)
 
-    @pl.when(i == n_q - 1)
+        for start, stop, masked in _q_segments(
+                k0, q0, t.tile_q // t.sub_q, t, causal, seq_len):
+            _loop(start, stop, functools.partial(sub_block, masked=masked))
+
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    _when(iq == n_qt - 1, _finish)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc,
-                   *, scale, causal, seq_len, n_k, blk_q, blk_k):
-    i, j = pl.program_id(1), pl.program_id(2)   # q tile major, k sweep minor
+                   *, scale, causal, seq_len, t, n_qt, n_kt):
+    iq, ik = _tile_ids(n_qt, n_kt, 1, 2)   # q tile major, k sweep minor
 
-    @pl.when(j == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+    _when(ik == 0, _init)
 
-    def _accumulate():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        _, ds = _bwd_probs(
-            q, k, do, v, lse_ref[0][:, :1], delta_ref[0][:, :1],
-            scale=scale, causal=causal, seq_len=seq_len,
-            q0=i * blk_q, k0=j * blk_k)
-        dq_acc[...] += jnp.dot(ds.astype(k.dtype), k,
-                               preferred_element_type=jnp.float32)
+    keep = _masker(t, causal, seq_len, q_tail=True)
+    for a in range(t.tile_q // t.sub_q):
+        rows = _rows(a, t.sub_q)
+        q0, k0 = iq * t.tile_q + a * t.sub_q, ik * t.tile_k
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        lse, delta = lse_ref[0, rows, :], delta_ref[0, rows, :]
 
-    if causal:
-        pl.when((i + 1) * blk_q - 1 >= j * blk_k)(_accumulate)
-    else:
-        _accumulate()
+        def sub_block(c, masked):
+            cols = _rows(c, t.sub_k)
+            k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+            _, ds = _bwd_probs(
+                q, k, v, do, lse, delta,
+                keep(q0, k0 + c * t.sub_k) if masked else None, scale)
+            dq_acc[rows, :] += jnp.dot(ds.astype(k.dtype), k,
+                                       preferred_element_type=jnp.float32)
 
-    @pl.when(j == n_k - 1)
+        for start, stop, masked in _k_segments(
+                q0, k0, t.tile_k // t.sub_k, t, causal, seq_len, q_tail=True):
+            _loop(start, stop, functools.partial(sub_block, masked=masked))
+
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+    _when(ik == n_kt - 1, _finish)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "t_dkdv", "t_dq", "causal", "scale", "true_len", "interpret"))
+def _bwd_tiles(q3, k3, v3, do3, lse2, delta2, *, t_dkdv, t_dq, causal, scale,
+               true_len, interpret):
+    """The two backward calls at their tiles, under `jax.jit` like
+    `_fwd_tiles`.  Each pads its q-aligned and its k-aligned operands to
+    whole tiles of its own: a block past the array would read undefined
+    bytes on the chip (0 * non-finite garbage = NaN through the
+    accumulators even though the mask zeroes p); outputs are sliced back."""
+    bh, s_pad, d = q3.shape
+    dv = v3.shape[-1]
+    size = q3.dtype.itemsize
+
+    def call(kernel, name, t, q_major, outs):
+        """One backward call; ``outs`` are ``(side, width)`` of its outputs
+        (and of their f32 accumulators), a side being ``"q"`` or ``"k"``."""
+        q, do, lse, delta = (_pad_to(x, t.tile_q, 1)
+                             for x in (q3, do3, lse2, delta2))
+        k, v = _pad_to(k3, t.tile_k, 1), _pad_to(v3, t.tile_k, 1)
+        s_q, s_k = q.shape[1], k.shape[1]
+        n_qt, n_kt = s_q // t.tile_q, s_k // t.tile_k
+        if q_major:      # grid (b, q tile, k tile)
+            grid, qi = (bh, n_qt, n_kt), lambda b, i, j: (b, i, 0)
+            ki = _kv_index(t, causal, n_kt)
+        else:            # grid (b, k tile, q tile); as `_kv_index`, mirrored
+            grid, ki = (bh, n_kt, n_qt), lambda b, j, i: (b, j, 0)
+            if causal and n_qt > 1:
+                qi = lambda b, j, i: (
+                    b, jnp.maximum(i, j * t.tile_k // t.tile_q), 0)
+            else:
+                qi = lambda b, j, i: (b, i, 0)
+        side = {"q": (t.tile_q, s_q, qi), "k": (t.tile_k, s_k, ki)}
+        spec = lambda where, width: pl.BlockSpec(
+            (1, side[where][0], width), side[where][2])
+        blocks = (size * (t.tile_q + t.tile_k) * (d + dv)
+                  + 2 * 4 * t.tile_q * BLOCK
+                  + size * sum(side[w][0] * n for w, n in outs))
+        return pl.pallas_call(
+            functools.partial(
+                kernel, scale=scale, causal=causal, t=t, n_qt=n_qt,
+                n_kt=n_kt, seq_len=_seq_len(true_len, s_q, s_k)),
+            grid=grid,
+            in_specs=[spec("q", d), spec("k", d), spec("k", dv),
+                      spec("q", dv), spec("q", BLOCK), spec("q", BLOCK)],
+            out_specs=[spec(w, n) for w, n in outs],
+            out_shape=[jax.ShapeDtypeStruct((bh, side[w][1], n), q3.dtype)
+                       for w, n in outs],
+            scratch_shapes=[pltpu.VMEM((side[w][0], n), jnp.float32)
+                            for w, n in outs],
+            interpret=interpret,
+            **_vmem(blocks, 4 * sum(side[w][0] * n for w, n in outs), t),
+            **_named(name),
+        )(q, k, v, do, lse, delta)
+
+    dk3, dv3 = call(_bwd_dkdv_kernel, "flash_bwd_dkdv", t_dkdv, False,
+                    [("k", d), ("k", dv)])
+    dq3, = call(_bwd_dq_kernel, "flash_bwd_dq", t_dq, True, [("q", d)])
+    return dq3[:, :s_pad], dk3[:, :s_pad], dv3[:, :s_pad]
 
 
 def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
               interpret, blk_q=None, blk_k=None):
     """``q3,k3: [BH, S_pad, D_pad]``; ``v3,do3: [BH, S_pad, Dv_pad]``;
     ``lse2, delta2: [BH, S_pad, BLOCK]`` f32, lane-replicated (same MIN_BLOCK_SIZE trick as
-    the forward's lse output — Mosaic wants (8k, 128k) tiles, the kernels
-    read lane 0).  Returns ``(dq, dk, dv)`` padded like the inputs."""
-    bh, s_pad, d = q3.shape
-    dv = v3.shape[-1]
-    blk_q = min(BWD_BLOCK_Q if blk_q is None else blk_q, s_pad)
-    blk_k = min(BWD_BLOCK_K if blk_k is None else blk_k, s_pad)
-    n_q, n_k = -(-s_pad // blk_q), -(-s_pad // blk_k)
-    # Same guard as _fwd_call: when s_pad is not a multiple of the clamped
-    # tile, edge blocks would read past the array (undefined bytes on real
-    # TPUs; 0 * non-finite garbage = NaN through the accumulators even
-    # though the position mask zeroes p).  Pad the q-aligned and k-aligned
-    # operands to their own tile multiples; outputs are sliced back below.
-    if n_q * blk_q != s_pad:
-        q3, do3 = _pad_to(q3, blk_q, 1), _pad_to(do3, blk_q, 1)
-        lse2, delta2 = _pad_to(lse2, blk_q, 1), _pad_to(delta2, blk_q, 1)
-    if n_k * blk_k != s_pad:
-        k3, v3 = _pad_to(k3, blk_k, 1), _pad_to(v3, blk_k, 1)
-    common = dict(scale=scale, causal=causal, seq_len=true_len,
-                  blk_q=blk_q, blk_k=blk_k)
-
-    dk3, dv3 = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, n_q=n_q, **common),
-        grid=(bh, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, j, i: (b, i, 0)),   # q
-            pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0)),   # k
-            pl.BlockSpec((1, blk_k, dv), lambda b, j, i: (b, j, 0)),  # v
-            pl.BlockSpec((1, blk_q, dv), lambda b, j, i: (b, i, 0)),  # dout
-            pl.BlockSpec((1, blk_q, BLOCK), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, BLOCK), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, n_k * blk_k, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, n_k * blk_k, dv), v3.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((blk_k, d), jnp.float32),
-            pltpu.VMEM((blk_k, dv), jnp.float32),
-        ],
-        interpret=interpret,
-        **_named("flash_bwd_dkdv"),
-    )(q3, k3, v3, do3, lse2, delta2)
-
-    dq3 = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, n_k=n_k, **common),
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),   # q
-            pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),   # k
-            pl.BlockSpec((1, blk_k, dv), lambda b, i, j: (b, j, 0)),  # v
-            pl.BlockSpec((1, blk_q, dv), lambda b, i, j: (b, i, 0)),  # dout
-            pl.BlockSpec((1, blk_q, BLOCK), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q, BLOCK), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, n_q * blk_q, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        interpret=interpret,
-        **_named("flash_bwd_dq"),
-    )(q3, k3, v3, do3, lse2, delta2)
-    return dq3[:, :s_pad], dk3[:, :s_pad], dv3[:, :s_pad]
+    the forward's lse output — Mosaic wants (8k, 128k) tiles).  Returns
+    ``(dq, dk, dv)`` padded like the inputs.  Tiles and sub-blocks are
+    `tile_plan`'s; ``blk_q`` / ``blk_k`` force the sub-block."""
+    plan = tile_plan(q3.shape[1], q3.shape[2], v3.shape[2], causal,
+                     blk_q=blk_q, blk_k=blk_k)
+    return _bwd_tiles(q3, k3, v3, do3, lse2, delta2,
+                      t_dkdv=plan.tiles["flash_bwd_dkdv"],
+                      t_dq=plan.tiles["flash_bwd_dq"], causal=causal,
+                      scale=scale, true_len=true_len, interpret=interpret)
 
 
 def _flash_bwd(causal, scale, interpret, res, dout):
     """Pallas blockwise backward from the saved logsumexp (FlashAttention-2
-    style: a dk/dv kernel sweeping q tiles, a dq kernel sweeping k tiles);
-    every live intermediate is one (blk_q, blk_k) tile in VMEM."""
+    style: a dk/dv kernel sweeping q per block of k, a dq kernel sweeping k
+    per block of q); every live intermediate is one sub-block in VMEM."""
     q, k, v, out, lse = res
     b, s, h, d = q.shape
     pad3 = lambda x: _pad_to(_pad_to(_to_bh(x), BLOCK, 1), BLOCK, 2)

@@ -29,6 +29,22 @@ def _qkv(seed, b=2, s=96, h=2, d=8):
     return mk(), mk(), mk()
 
 
+def _assert_out_and_grads_match(q, k, v, causal, **tol):
+    """Output and the three gradients of sum(sin(attention)) against
+    `dense_attention` on the same values in f32."""
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(
+        fn(q, k, v, causal=causal).astype(jnp.float32)))
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = [dense_attention(*f32, causal=causal),
+            *jax.grad(loss(dense_attention), argnums=(0, 1, 2))(*f32)]
+    got = [flash_attention(q, k, v, causal=causal),
+           *jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)]
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(g, dtype=np.float32),
+                                   np.asarray(w), err_msg=name, **tol)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("s", [64, 96, BLOCK, BLOCK + 40, 2 * BLOCK])
 def test_flash_matches_dense(causal, s):
@@ -68,14 +84,14 @@ def test_flash_gradients_match_dense(causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_gradients_multi_tile_grid(causal):
-    """s=640 pads past BWD_BLOCK (512) but is not a multiple of it: the
-    backward runs a 2x2 tile grid, exercising scratch accumulation across
-    grid steps, the init/finish gating, the causal tile skip, AND the
-    edge-tile re-pad guard (off-tile rows would otherwise read out of
+    """s=640 pads past the backward's 512-row tile but is not a multiple of
+    it: the backward runs a 2x2 tile grid, exercising scratch accumulation
+    across grid steps, the init/finish gating, the causal tile skip, AND
+    the edge-tile re-pad guard (off-tile rows would otherwise read out of
     bounds on hardware)."""
-    from pytorch_ps_mpi_tpu.ops.flash_attention import BWD_BLOCK_Q
-
-    s = BWD_BLOCK_Q + BLOCK          # 640
+    s = 640
+    tiles = _fa.tile_plan(s, BLOCK, BLOCK, causal).tiles
+    assert tiles["flash_bwd_dkdv"] == tiles["flash_bwd_dq"] == (512,) * 4
     q, k, v = _qkv(6, b=1, s=s, h=1, d=16)
 
     def loss(attn):
@@ -164,61 +180,206 @@ def test_flash_gradients_with_another_v_width(d, dv):
                                    atol=2e-5)
 
 
-def test_v_is_not_widened_to_the_q_width():
-    """At 192 / 128 the kernels see q and k at 256 lanes and v, the
-    accumulator and the output at 128: v is not padded to q's width."""
+def _pallas_calls(q, k, v):
+    """The keyword arguments of every `pallas_call` that a causal forward
+    and backward trace, in order."""
     seen = []
     real = _fa.pl.pallas_call
 
     def spy(kernel, **kw):
-        seen.append([s.shape[-1] for s in jax.tree.leaves(kw["out_shape"])])
+        seen.append(kw)
         return real(kernel, **kw)
 
-    q, k, v = _qkv_widths(2, s=64, d=192, dv=128)
     _fa.pl.pallas_call = spy
     try:
+        jax.clear_caches()   # the calls are jitted: make them trace here
         jax.grad(lambda q, k, v: jnp.sum(flash_attention(
             q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
     finally:
         _fa.pl.pallas_call = real
+    return seen
+
+
+def test_v_is_not_widened_to_the_q_width():
+    """At 192 / 128 the kernels see q and k at 256 lanes and v, the
+    accumulator and the output at 128: v is not padded to q's width."""
+    seen = [[s.shape[-1] for s in jax.tree.leaves(kw["out_shape"])]
+            for kw in _pallas_calls(*_qkv_widths(2, s=64, d=192, dv=128))]
     # forward: o 128 (+ the row statistics' lane tile); dk 256, dv 128; dq 256
     assert [128, BLOCK] in seen and [256, 128] in seen and [256] in seen
 
 
-# What the kernels gave at the parent commit (sha256 over out, dq, dk, dv as
-# f32 bytes; inputs as `_digest` makes them), and what plain `jax.numpy`
-# gives on the same inputs on the machine that recorded them: where the
-# latter differs, this is another CPU and the comparison says nothing.
-_BEFORE = {
-    (64, "bfloat16"):
-        "21c5bef24b194dd5d63f5e4c580047b091007bda4327536bb52c122388a0ef04",
-    (64, "float32"):
-        "5c1715fa1aeacb887ef5c036c94588dbbafbd6a743ba6b8b8e11b0cbaef77587",
-    (128, "bfloat16"):
-        "303c8dfb09df8b04d06fafc0beeb1033f10e044e78505594981d875dd98473a3",
-}
-_CANARY = "e5323ba271f34050ea54f017f5b1c104244597807658466bf2c7a563917a40b0"
-
-
-def _digest(arrays):
-    import hashlib
-    h = hashlib.sha256()
-    for a in arrays:
-        h.update(np.asarray(a.astype(jnp.float32)).tobytes())
-    return h.hexdigest()
-
-
-@pytest.mark.parametrize("d,dtype", sorted(_BEFORE))
-def test_bit_equal_to_before_at_equal_widths(d, dtype):
+# PR 28 pinned its refactor to the parent's bits with digests of these three
+# cases; the two-level tiles reorder f32 sums on purpose, so the same inputs
+# are now held to `dense_attention` in f32.
+@pytest.mark.parametrize("d,dtype", [(64, "bfloat16"), (64, "float32"),
+                                     (128, "bfloat16")])
+def test_matches_dense_at_equal_widths(d, dtype):
     rng = np.random.RandomState(7)
     q, k, v = (jnp.asarray(rng.randn(2, 200, 2, d), dtype) for _ in range(3))
-    canary = _digest([dense_attention(*(x.astype(jnp.float32)
-                                        for x in _qkv(7, s=200, d=64)),
-                                      causal=True)])
-    if canary != _CANARY:
-        pytest.skip("another CPU than the one the digests were taken on")
-    out = flash_attention(q, k, v, causal=True)
-    grads = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
-        q, k, v, causal=True).astype(jnp.float32))), argnums=(0, 1, 2))(
-            q, k, v)
-    assert _digest([out, *grads]) == _BEFORE[(d, dtype)]
+    tol = (dict(rtol=2e-4, atol=2e-5) if dtype == "float32"
+           else dict(rtol=2e-2, atol=2e-2))
+    _assert_out_and_grads_match(q, k, v, True, **tol)
+
+
+# -- two-level tiles: sub-blocks that follow the mask -------------------------
+
+
+@pytest.mark.parametrize("kernel", _fa.KERNELS)
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t,s_pad,true_len", [
+    (_fa.Tiles(512, 512, 128, 128), 1024, 1024),
+    (_fa.Tiles(512, 1024, 256, 128), 1024, 1000),
+    (_fa.Tiles(1024, 512, 128, 256), 1024, 700),
+    (_fa.Tiles(384, 384, 128, 384), 768, 768),
+    (_fa.Tiles(256, 256, 256, 256), 512, 300),
+])
+def test_the_bounds_enter_what_the_mask_keeps(kernel, causal, t, s_pad,
+                                              true_len):
+    """Brute force over positions: a sub-block is entered iff the mask
+    keeps an entry of it, runs the interior body iff it keeps all of them
+    (a padded q row counts only in the backward's row-major kernel, which
+    has to mask its `NEG_INF` logsumexp), and is entered once."""
+    seq_len = None if true_len == s_pad else true_len
+    entered = list(_fa._entered(kernel, t, s_pad, s_pad, causal, seq_len))
+    got = {(q0, k0): masked for q0, k0, masked in entered}
+    assert len(got) == len(entered)
+    pos = np.arange(s_pad)
+    keep = (pos[None, :] < true_len) & np.ones((s_pad, 1), bool)
+    if causal:
+        keep &= pos[:, None] >= pos[None, :]
+    rows_live = pos < true_len
+    for q0 in range(0, s_pad, t.sub_q):
+        for k0 in range(0, s_pad, t.sub_k):
+            block = keep[q0:q0 + t.sub_q, k0:k0 + t.sub_k]
+            live = rows_live[q0:q0 + t.sub_q]
+            if kernel != "flash_fwd":
+                block = block & live[:, None]
+            if not block.any():
+                assert (q0, k0) not in got, (q0, k0)
+            elif block.all():
+                assert got[(q0, k0)] is False, (q0, k0)
+            elif kernel == "flash_fwd" or live.all():
+                assert got[(q0, k0)] is True, (q0, k0)
+            else:   # some padded q rows: entered, and masked
+                assert got.get((q0, k0), True) is True, (q0, k0)
+
+
+def _forced(monkeypatch, blk_q, blk_k):
+    """Small sub-blocks for every call under the public entry point."""
+    if blk_q is not None:
+        monkeypatch.setattr(_fa, "tile_plan", functools.partial(
+            _fa.tile_plan, blk_q=blk_q, blk_k=blk_k))
+
+
+@pytest.mark.parametrize("d,dv", [(64, 64), (192, 128)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("s,blk_q,blk_k", [
+    (384, 128, 128),    # one tile of 3 x 3 sub-blocks: the unrolled loops
+    (600, 128, 128),    # several tiles, device loops, the padded tail
+    (600, 256, 128),    # sub-blocks that are not square
+    (520, 128, 256),
+    (1100, None, None),   # the shape's own plan: a head in one tile, unrolled
+])
+def test_sub_blocks_match_dense(monkeypatch, d, dv, causal, s, blk_q, blk_k):
+    """Outputs and the three gradients against `dense_attention` with every
+    branch of the two-level kernels run under the interpreter: a skipped
+    sub-block, an interior one, the diagonal one, the padded tail, a grid
+    tile of several sub-blocks and several grid tiles."""
+    _forced(monkeypatch, blk_q, blk_k)
+    plan = _fa.tile_plan(-(-s // BLOCK) * BLOCK, 256 if d > 128 else 128, 128,
+                         causal, true_len=s)
+    for kernel, c in plan.counts.items():
+        t = plan.tiles[kernel]
+        assert c.entered > c.masked > 0 or not causal, (kernel, c)
+        assert c.skipped > 0 or not causal, (kernel, c)
+        if blk_q is not None:
+            assert (t.sub_q, t.sub_k) == (blk_q, blk_k)
+        assert t.tile_q > t.sub_q or t.tile_k > t.sub_k or not causal
+    q, k, v = _qkv_widths(3, b=1, s=s, h=2, d=d, dv=dv)
+    _assert_out_and_grads_match(q, k, v, causal, rtol=2e-4, atol=2e-5)
+
+
+def test_the_plan_of_a_long_sequence_matches_dense():
+    """Past `_WHOLE_HEAD` the shape's own plan runs device loops over
+    512 x 512 sub-blocks with the other side whole in VMEM."""
+    s = 2100
+    plan = _fa.tile_plan(2176, 128, 128, True, true_len=s)
+    assert plan.tiles["flash_fwd"] == plan.tiles["flash_bwd_dq"] \
+        == (1024, 2560, 512, 512)
+    assert plan.tiles["flash_bwd_dkdv"] == (2560, 2048, 512, 512)
+    q, k, v = _qkv(9, b=1, s=s, h=1, d=64)
+    _assert_out_and_grads_match(q, k, v, True, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,tiles,counts", [
+    # gpt2m-*: [128, 1024, 128 / 128], a head in one tile, unrolled
+    ((1024, 128, 128), {"flash_fwd": (1024, 1024, 256, 256),
+                        "flash_bwd_dkdv": (1024, 1024, 128, 128),
+                        "flash_bwd_dq": (1024, 1024, 256, 256)},
+     {"flash_fwd": (10, 4, 6), "flash_bwd_dkdv": (36, 8, 28),
+      "flash_bwd_dq": (10, 4, 6)}),
+    # kimi-linear-sync-1chip: [64, 8192, 256 / 128], device loops
+    ((8192, 256, 128), {"flash_fwd": (1024, 8192, 512, 512),
+                        "flash_bwd_dkdv": (8192, 2048, 512, 512),
+                        "flash_bwd_dq": (1024, 8192, 512, 512)},
+     {k: (136, 16, 120) for k in _fa.KERNELS}),
+    # a short sequence: one tile, one sub-block, as before the sweep
+    ((512, 128, 128), {k: (512, 512, 512, 512) for k in _fa.KERNELS},
+     {k: (1, 1, 0) for k in _fa.KERNELS}),
+])
+def test_tile_plan_counts(shape, tiles, counts):
+    """Entered / masked / skipped sub-blocks a head, for the two shapes the
+    benchmark's cells run and for the fallback (PERF.md quotes these).
+    Before the two-level tiles GPT-2's shape read forward 2 / 2 / 0 and
+    each backward kernel 3 / 3 / 1 (every tile that ran paid the mask); of
+    the score elements of the square a head now computes 0.625 (forward,
+    dq) and 0.5625 (dkdv) where the mask keeps 0.50."""
+    plan = _fa.tile_plan(*shape, True)
+    assert plan.tiles == tiles and plan.counts == counts
+    for kernel, (entered, masked, skipped) in counts.items():
+        t = plan.tiles[kernel]
+        assert entered + skipped == (shape[0] // t.sub_q) * (shape[0] // t.sub_k)
+    # not causal, nothing padded: every sub-block interior
+    free = _fa.tile_plan(*shape, False)
+    assert all(c.masked == c.skipped == 0 for c in free.counts.values())
+    # the same length reached by padding: in the forward the padded columns
+    # lie in sub-blocks the diagonal masks already; the backward also masks
+    # the padded q rows' whole band
+    tail = _fa.tile_plan(*shape, True, true_len=shape[0] - 5)
+    assert tail.counts["flash_fwd"] == plan.counts["flash_fwd"]
+    if shape[0] > 512:
+        assert all(tail.counts[k].masked > plan.counts[k].masked
+                   and tail.counts[k].entered == plan.counts[k].entered
+                   for k in ("flash_bwd_dkdv", "flash_bwd_dq"))
+
+
+def test_the_names_the_benchmark_and_the_smoke_look_for():
+    """`mla_flash_ms_step` matches the three calls by name and the smoke's
+    `lm_flash` phase asks the compiled step for them: both must be the names
+    the module gives its calls (the smoke drifted unseen from PR 28 to 30)."""
+    import ast
+    import os
+
+    from perfbench.models import kimi_linear
+
+    seen = [(kw["name"], kw["metadata"])
+            for kw in _pallas_calls(*_qkv(8, b=1, s=40, h=1, d=8))]
+    assert [n for n, _ in seen] == list(_fa.KERNELS)
+    assert all(m == {"kernel": n} for n, m in seen)
+    assert tuple(kimi_linear.FLASH_KERNELS) == _fa.KERNELS
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chip_smoke.py")) as f:
+        tree = ast.parse(f.read())
+    phase = next(n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)
+                 and n.name == "phase_lm_flash")
+    imported = {a.name for n in ast.walk(phase)
+                if isinstance(n, ast.ImportFrom)
+                and n.module == "pytorch_ps_mpi_tpu.ops.flash_attention"
+                for a in n.names}
+    literals = {n.value for n in ast.walk(phase)
+                if isinstance(n, ast.Constant) and isinstance(n.value, str)}
+    assert "KERNELS" in imported
+    assert not literals & {"_fwd_kernel", "_bwd_dkdv_kernel",
+                           "_bwd_dq_kernel", *_fa.KERNELS}

@@ -5,7 +5,9 @@ TPU gets the Mosaic kernels though this process's backend is the CPU, every
 kernel call of the forward, the rematerialised forward and the backward
 stays under the program's ``kda`` scope and carries its kernel's name, and
 no loop of the plain code is left under that scope.  And the kernels compile
-at the cell's shape.
+at the cell's shape — the flash kernels' two-level tiles too, at the shapes
+of the two cells that run them (PR 31): they sit here because this is the
+one file that may describe a topology.
 
 This is the one test file that describes a TPU topology (the
 `on-chip-measurement` guide, section 2): only inside a fixture, never while
@@ -114,3 +116,24 @@ def test_the_kernels_compile_at_the_cells_shape(one_chip, what):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert {kernel for _, kernel in _CALL.findall(text)} <= KERNELS
     assert ("kda_bwd" if what == "backward" else "kda_fwd") in text
+
+
+@pytest.mark.parametrize("b,s,h,d,dv", [
+    (8, 1024, 16, 64, 64),      # gpt2m-*: a head in one tile, unrolled loops
+    (2, 8192, 32, 192, 128),    # kimi-linear-sync-1chip's MLA: device loops
+])
+def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
+                                                       dv):
+    """The two-level flash kernels as `tile_plan` sizes them for the two
+    shapes the benchmark runs, forward and both backward calls, through the
+    chip's compiler: slices, loop bounds and VMEM are its to refuse; and
+    the three calls reach the compiled program under their names."""
+    from pytorch_ps_mpi_tpu.ops import flash_attention as fa
+
+    qk = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16, sharding=one_chip)
+    grad = jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(qk, qk, v).compile().as_text()
+    assert sorted(kernel for _, kernel in _CALL.findall(text)) == sorted(
+        fa.KERNELS)
