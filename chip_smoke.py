@@ -27,6 +27,10 @@ for all steps, and prints compile seconds, seconds per step and peak HBM):
   lm_flash              d1024 x L12 x 16 heads, seq 1024, 16/chip, bf16, flash
                         attention, through SGD(...).compile_step().step();
                         then train.main --model transformer --attn flash
+  glm_flash             GLM-4.7-Flash at published widths, 1 dense + 1 expert
+                        layer + the MTP module, 1 x 8192 tokens a chip, bf16,
+                        through Adam(...).compile_step(has_aux).step(): the
+                        flash kernels at a 256-wide q / k and a 256-wide v
   kernel_parity         every Pallas kernel against its jnp reference
   kda_kernels           the KDA recurrence's kernels against kda_chunked at
                         [1, 8192, 32, 128]: o and the five gradients
@@ -63,12 +67,12 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-PHASES = ("sync_resnet18", "sync_resnet18_blockq", "lm_flash",
+PHASES = ("sync_resnet18", "sync_resnet18_blockq", "lm_flash", "glm_flash",
           "kernel_parity", "kda_kernels", "async_inprocess", "tcp_pair",
           "multichip", "cache_reuse")
 # Seconds a phase may take before its process group is killed.  The sum
 # stays under the 1200 s the whole script is allowed.
-PHASE_TIMEOUT_S = {"lm_flash": 420, "kernel_parity": 300,
+PHASE_TIMEOUT_S = {"lm_flash": 420, "glm_flash": 300, "kernel_parity": 300,
                    "multichip": 420}
 DEFAULT_TIMEOUT_S = 300
 
@@ -95,6 +99,12 @@ FULL = dict(
     lm=dict(vocab_size=32768, d_model=1024, n_heads=16, n_layers=12,
             d_ff=4096), lm_seq=1024, lm_batch=16, lm_steps=4,
     cli_lm_seq=1024, cli_lm_batch=8,
+    glm=dict(vocab_size=19360, d_model=2048, n_layers=2, first_k_dense=1,
+             d_ff=10240, d_expert=1536, n_experts=64,
+             experts_held=tuple(range(8)), top_k=4, n_shared=1,
+             routed_scale=1.8, n_heads=20, q_lora_rank=768, kv_lora_rank=512,
+             qk_nope_dim=192, qk_rope_dim=64, v_dim=256, rope_theta=1e6),
+    glm_seq=8192, glm_steps=4,
     async_lm_seq=256, async_lm_batch=8, async_updates=20,
     async_resnet_batch=512, async_resnet_updates=24,
     flash_shapes=((2, 1024, 16, 64), (2, 777, 16, 64)),
@@ -105,6 +115,12 @@ TINY = dict(
     lm=dict(vocab_size=128, d_model=64, n_heads=4, n_layers=2, d_ff=128),
     lm_seq=128, lm_batch=2, lm_steps=4,
     cli_lm_seq=128, cli_lm_batch=2,
+    glm=dict(vocab_size=128, d_model=64, n_layers=2, first_k_dense=1,
+             d_ff=96, d_expert=32, n_experts=16, experts_held=(0, 1, 2, 3),
+             top_k=4, n_shared=1, routed_scale=1.8, n_heads=2, q_lora_rank=24,
+             kv_lora_rank=32, qk_nope_dim=24, qk_rope_dim=8, v_dim=32,
+             rope_theta=1e6),
+    glm_seq=128, glm_steps=4,
     async_lm_seq=128, async_lm_batch=2, async_updates=20,
     async_resnet_batch=8, async_resnet_updates=24,
     flash_shapes=((1, 256, 2, 64), (1, 200, 2, 64)),
@@ -401,6 +417,46 @@ def phase_lm_flash(run: Run) -> None:
         run, opt, lm_batch(synthetic_lm(cb, seq_len=sz["cli_lm_seq"])),
         flash)
     fields["cli"] = cli
+    run.done(**fields)
+
+
+def phase_glm_flash(run: Run) -> None:
+    """The GLM-MoE LM (rotary latent attention, expert layer, MTP module
+    through the shared head) at its published widths and a small depth: the
+    compiled step holds the three flash kernels, here at 256 / 256."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_ps_mpi_tpu import Adam
+    from pytorch_ps_mpi_tpu.data.datasets import synthetic_lm
+    from pytorch_ps_mpi_tpu.models.glm_moe import (GlmMoeConfig, GlmMoeLM,
+                                                   glm_aux, make_glm_loss)
+    from pytorch_ps_mpi_tpu.models.transformer import lm_batch
+    from pytorch_ps_mpi_tpu.ops.flash_attention import (KERNELS,
+                                                        flash_attention)
+    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
+    from pytorch_ps_mpi_tpu.utils.flatten import named_params
+
+    sz = run.sizes
+    seq, cfg = sz["glm_seq"], GlmMoeConfig(**sz["glm"], dtype=jnp.bfloat16)
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    model = GlmMoeLM(cfg, attn=functools.partial(
+        flash_attention, causal=True, scale=scale, impl=run.impl))
+    ids = jnp.zeros((1, 8), jnp.int32)
+    params = jax.jit(lambda key: named_params(GlmMoeLM(
+        GlmMoeConfig(**sz["glm"])).init(key, ids, ids, ids)["params"]))(
+            jax.random.PRNGKey(0))
+    opt = Adam(list(params.items()), lr=1e-4, mesh=make_ps_mesh())
+    del params
+    opt.compile_step(make_glm_loss(model), has_aux=True, aux=glm_aux(model))
+    b = lm_batch(synthetic_lm(run.n, seq_len=seq, vocab=cfg.vocab_size,
+                              seed=0))
+    losses = [opt.step(b)[0] for _ in range(sz["glm_steps"])]
+    fields = sync_opt_fields(run, opt, losses, "glm-moe d%d x L%d + mtp" % (
+        cfg.d_model, cfg.n_layers))
+    fields["mosaic_kernels"] = mosaic_kernels(run, opt, b, set(KERNELS))
+    fields["flash_widths"] = [cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_dim]
+    check_batch_on_all_devices(opt, b)
     run.done(**fields)
 
 
@@ -752,6 +808,7 @@ CHILD_PHASES = {
     "sync_resnet18": phase_sync_resnet18,
     "sync_resnet18_blockq": phase_sync_resnet18_blockq,
     "lm_flash": phase_lm_flash,
+    "glm_flash": phase_glm_flash,
     "kernel_parity": phase_kernel_parity,
     "kda_kernels": phase_kda_kernels,
     "async_inprocess": phase_async_inprocess,

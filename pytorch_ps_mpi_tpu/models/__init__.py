@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.flatten import named_params, unflatten_params
+from .glm_moe import GlmMoeConfig, GlmMoeLM, glm_aux, make_glm_loss
 from .lenet import LeNet5
 from .mlp import init_mlp, mlp_apply, mlp_loss_fn
 from .resnet import ResNet, resnet18, resnet34, resnet50
@@ -23,6 +24,7 @@ __all__ = [
     "LeNet5", "ResNet", "resnet18", "resnet34", "resnet50",
     "TransformerLM", "build_lm", "lm_batch", "make_lm_loss",
     "make_pipelined_lm_loss",
+    "GlmMoeConfig", "GlmMoeLM", "glm_aux", "make_glm_loss",
     "init_mlp", "mlp_apply", "mlp_loss_fn",
     "build_model", "make_classifier_loss", "eval_accuracy",
 ]

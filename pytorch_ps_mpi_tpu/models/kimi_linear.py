@@ -19,9 +19,11 @@ the model takes the `lm_batch` contract.  The head is untied.
   W_g1 x)]``.
 * **MLA** (`LatentAttention`): q of ``nope + rope`` columns a head; keys and
   values from one 512-wide latent (RMSNorm'd) plus ``rope`` columns shared by
-  all heads, to which **no rotation is applied**; causal softmax attention
-  with a q / k width (192) other than the v width (128), through the
-  ``attn`` callable (`ops.flash_attention.flash_attention` on the chip).
+  all heads, to which **no rotation is applied** here (``rope_theta=None``;
+  `models.glm_moe` sets it, and a low-rank q, on the same class); causal
+  softmax attention with a q / k width (192) other than the v width (128),
+  through the ``attn`` callable (`ops.flash_attention.flash_attention` on
+  the chip).
 
 Each half of each block is rematerialised on its own (`nn.remat`).  The device trace can
 split a step by layer kind: `jax.named_scope` goes round ``kda`` (the
@@ -124,7 +126,29 @@ class KDAttention(nn.Module):
         return _dense(self.d_model, self.dtype, "o_proj")(o)
 
 
+def rotate(x, positions, theta: float):
+    """Rotary position embedding over all of the last axis, pairing column
+    ``i`` with column ``i + d/2`` (split halves): the pair turns by the angle
+    ``pos * theta^(-2i/d)``.  ``x: [B, S, d]`` or ``[B, S, H, d]``,
+    ``positions: [B, S]``; angles, sines and the products in f32."""
+    half = x.shape[-1] // 2
+    inv_freq = jnp.asarray(
+        theta ** (-np.arange(half, dtype=np.float64) / half), jnp.float32)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (half,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = (y.astype(jnp.float32) for y in jnp.split(x, 2, axis=-1))
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 class LatentAttention(nn.Module):
+    """Multi-head latent attention.  ``q_lora_rank`` None: one full-rank
+    ``q_proj``; a rank: ``q_b_proj(RMSNorm(q_a_proj x))``.  ``rope_theta``
+    None: no position enters (NoPE, `positions` unread); a base: the
+    ``rope`` columns of q (a head) and the one shared rope key are rotated
+    by `positions` (`rotate`), the key before it is broadcast to the heads."""
+
     d_model: int
     n_heads: int
     kv_lora_rank: int
@@ -134,22 +158,37 @@ class LatentAttention(nn.Module):
     eps: float
     dtype: jnp.dtype
     attn: Callable
+    q_lora_rank: "int | None" = None
+    rope_theta: "float | None" = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         b, s, _ = x.shape
         h, nope, rope, dv = (self.n_heads, self.qk_nope_dim,
                              self.qk_rope_dim, self.v_dim)
-        q = _dense(h * (nope + rope), self.dtype, "q_proj")(x)
+        norm = lambda name: nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                                       param_dtype=jnp.float32, name=name)
+        if self.q_lora_rank is None:
+            q = _dense(h * (nope + rope), self.dtype, "q_proj")(x)
+        else:
+            q = _dense(h * (nope + rope), self.dtype, "q_b_proj")(
+                norm("q_a_norm")(
+                    _dense(self.q_lora_rank, self.dtype, "q_a_proj")(x)))
         q = q.reshape(b, s, h, nope + rope)
         kv_a = _dense(self.kv_lora_rank + rope, self.dtype, "kv_a_proj")(x)
         latent, k_pe = kv_a[..., :self.kv_lora_rank], \
             kv_a[..., self.kv_lora_rank:]
-        latent = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
-                            param_dtype=jnp.float32, name="kv_a_norm")(latent)
+        latent = norm("kv_a_norm")(latent)
         kv = _dense(h * (nope + dv), self.dtype, "kv_b_proj")(latent)
         kv = kv.reshape(b, s, h, nope + dv)
-        # NoPE: the "rope" columns are shared by the heads and not rotated.
+        if self.rope_theta is not None:
+            with jax.named_scope("rope"):
+                q = jnp.concatenate(
+                    [q[..., :nope],
+                     rotate(q[..., nope:], positions, self.rope_theta)],
+                    axis=-1)
+                k_pe = rotate(k_pe, positions, self.rope_theta)
+        # The "rope" columns of k are one key shared by the heads.
         k = jnp.concatenate(
             [kv[..., :nope],
              jnp.broadcast_to(k_pe[:, :, None, :], (b, s, h, rope))], axis=-1)
@@ -186,17 +225,20 @@ class KimiLinearConfig:
     gate_rank: int = 128
     eps: float = 1e-5
     dtype: Any = jnp.float32
+    q_lora_rank: "int | None" = None    # the MLA layers' q is full-rank
+    rope_theta: "float | None" = None   # ... and they see no position
 
     @property
     def n_moe_layers(self) -> int:
         return self.n_layers - self.first_k_dense
 
 
-def _attn_part(block: "KimiBlock", x):
-    return x + block.attn(block.attn_norm(x))
+def _attn_part(block: "DecoderBlock", x, positions):
+    y = block.attn_norm(x)
+    return x + (block.attn(y) if block.linear else block.attn(y, positions))
 
 
-def _mlp_part(block: "KimiBlock", x):
+def _mlp_part(block: "DecoderBlock", x):
     y = block.mlp_norm(x)
     if block.dense:
         return x + block.mlp(y), None
@@ -205,12 +247,15 @@ def _mlp_part(block: "KimiBlock", x):
     return x + y, load
 
 
-class KimiBlock(nn.Module):
+class DecoderBlock(nn.Module):
     """``x += Attn(RMSNorm(x)); x += MLP(RMSNorm(x))``, each half
     rematerialised on its own: the backward pass holds the activations of
-    one half of one block at a time, and the block's input."""
+    one half of one block at a time, and the block's input.  The one block
+    of `KimiLinearLM` and of `models.glm_moe.GlmMoeLM`: ``cfg`` is either
+    model's configuration, read for the sizes of the kinds of layer this
+    block is (the KDA sizes only where ``linear``)."""
 
-    cfg: KimiLinearConfig
+    cfg: Any
     attn_fn: Callable
     linear: bool     # KDA, else MLA
     dense: bool      # a dense SwiGLU, else the expert layer
@@ -228,7 +273,8 @@ class KimiBlock(nn.Module):
         else:
             self.attn = LatentAttention(
                 c.d_model, c.n_heads, c.kv_lora_rank, c.qk_nope_dim,
-                c.qk_rope_dim, c.v_dim, c.eps, c.dtype, self.attn_fn)
+                c.qk_rope_dim, c.v_dim, c.eps, c.dtype, self.attn_fn,
+                c.q_lora_rank, c.rope_theta)
         if self.dense:
             self.mlp = SwiGLU(c.d_ff, c.dtype)
         else:
@@ -236,8 +282,8 @@ class KimiBlock(nn.Module):
                 c.d_model, c.d_expert, c.n_experts, tuple(c.experts_held),
                 c.top_k, c.routed_scale, c.d_expert * c.n_shared, c.dtype)
 
-    def __call__(self, x):
-        x = nn.remat(_attn_part)(self, x)
+    def __call__(self, x, positions=None):
+        x = nn.remat(_attn_part)(self, x, positions)
         return nn.remat(_mlp_part)(self, x)
 
 
@@ -263,9 +309,9 @@ class KimiLinearLM(nn.Module):
                      name="tok_embed")(tokens)
         loads = []
         for i in range(c.n_layers):
-            x, load = KimiBlock(c, attn, linear=(i + 1) in c.kda_layers,
-                                dense=i < c.first_k_dense, kda_fn=self.kda,
-                                name=f"block_{i}")(x)
+            x, load = DecoderBlock(c, attn, linear=(i + 1) in c.kda_layers,
+                                   dense=i < c.first_k_dense, kda_fn=self.kda,
+                                   name=f"block_{i}")(x)
             if load is not None:
                 loads.append(load)
         with jax.named_scope("head_loss"):

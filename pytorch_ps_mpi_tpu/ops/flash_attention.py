@@ -131,6 +131,15 @@ class Plan(NamedTuple):
 #     (1024, 2048 |  256,  512)              14.26   21.01   24.38
 #     (1024, 1024 |  256,  256)              22.85   24.48   29.88
 #     (1024, 1024 |  128,  128)              55.99   58.72   77.62
+#   [20, 8192, 256 / 256]  (PR 33: no cell ran a 256-wide v before)
+#     (1024, 8192 |  512,  512)               4.77    6.34    8.60
+#     (2048, 8192 |  512,  512)               4.77    6.30    8.28
+#     ( 512, 8192 |  512,  512)               4.78    6.33     -
+#     (4096, 8192 |  512,  512)                -       -      8.63
+#     (1024, 4096 |  512,  512)               4.92    6.80     -
+#     (2048, 4096 |  512,  512)                -       -      8.44
+#     (2048, 2048 |  512,  512)               5.19    7.24    8.69
+#     (1024, 2048 |  512,  512)               5.46    7.47     -
 #
 # What it taught.  (1) A device loop (traced bounds) costs far more than
 # the work it skips unless its sub-blocks are large: 512 x 512 there; two
@@ -142,7 +151,11 @@ class Plan(NamedTuple):
 # statistics for dkdv) beats any split of it up to S_pad 8192.  (4) The
 # interior body alone, at the old tiles, is worth 13 % (dkdv 0.990 -> 0.861).
 # (5) dkdv's 256 x 256 is worse than both its neighbours in every unrolled
-# shape (its two transposed products; not looked into).
+# shape (its two transposed products; not looked into).  (6) With v as wide
+# as q / k (256 / 256) the other side whole is twice the bytes of v and dO
+# in VMEM (`_vmem` asks for its 96 MiB cap in dkdv) and still beats every
+# split of it; own-side tiles of 512 to 2048 rows are within 1 % of each
+# other, so `_LOOP_TILE` stands at this shape too.
 _WHOLE_HEAD = 2048   # S_pad up to which a head is one tile (swept to here)
 _WHOLE_SIDE = 8192   # ... and the other operand is, beyond it (swept to here)
 _STATIC_SUB = {"flash_fwd": (256, 256), "flash_bwd_dq": (256, 256),

@@ -324,12 +324,18 @@ def test_the_plan_of_a_long_sequence_matches_dense():
                         "flash_bwd_dkdv": (8192, 2048, 512, 512),
                         "flash_bwd_dq": (1024, 8192, 512, 512)},
      {k: (136, 16, 120) for k in _fa.KERNELS}),
+    # glm47-flash-sync-1chip: [20, 8192, 256 / 256], the same plan: the
+    # tiles follow the length and the mask, v's width only sizes the VMEM
+    ((8192, 256, 256), {"flash_fwd": (1024, 8192, 512, 512),
+                        "flash_bwd_dkdv": (8192, 2048, 512, 512),
+                        "flash_bwd_dq": (1024, 8192, 512, 512)},
+     {k: (136, 16, 120) for k in _fa.KERNELS}),
     # a short sequence: one tile, one sub-block, as before the sweep
     ((512, 128, 128), {k: (512, 512, 512, 512) for k in _fa.KERNELS},
      {k: (1, 1, 0) for k in _fa.KERNELS}),
 ])
 def test_tile_plan_counts(shape, tiles, counts):
-    """Entered / masked / skipped sub-blocks a head, for the two shapes the
+    """Entered / masked / skipped sub-blocks a head, for the three shapes the
     benchmark's cells run and for the fallback (PERF.md quotes these).
     Before the two-level tiles GPT-2's shape read forward 2 / 2 / 0 and
     each backward kernel 3 / 3 / 1 (every tile that ran paid the mask); of
@@ -361,25 +367,28 @@ def test_the_names_the_benchmark_and_the_smoke_look_for():
     import ast
     import os
 
-    from perfbench.models import kimi_linear
+    from perfbench.models import glm_moe, kimi_linear
 
     seen = [(kw["name"], kw["metadata"])
             for kw in _pallas_calls(*_qkv(8, b=1, s=40, h=1, d=8))]
     assert [n for n, _ in seen] == list(_fa.KERNELS)
     assert all(m == {"kernel": n} for n, m in seen)
     assert tuple(kimi_linear.FLASH_KERNELS) == _fa.KERNELS
+    assert glm_moe.FLASH_KERNELS is kimi_linear.FLASH_KERNELS
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "chip_smoke.py")) as f:
         tree = ast.parse(f.read())
-    phase = next(n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)
-                 and n.name == "phase_lm_flash")
-    imported = {a.name for n in ast.walk(phase)
-                if isinstance(n, ast.ImportFrom)
-                and n.module == "pytorch_ps_mpi_tpu.ops.flash_attention"
-                for a in n.names}
-    literals = {n.value for n in ast.walk(phase)
-                if isinstance(n, ast.Constant) and isinstance(n.value, str)}
-    assert "KERNELS" in imported
-    assert not literals & {"_fwd_kernel", "_bwd_dkdv_kernel",
-                           "_bwd_dq_kernel", *_fa.KERNELS}
+    for name in ("phase_lm_flash", "phase_glm_flash"):
+        phase = next(n for n in ast.walk(tree)
+                     if isinstance(n, ast.FunctionDef) and n.name == name)
+        imported = {a.name for n in ast.walk(phase)
+                    if isinstance(n, ast.ImportFrom)
+                    and n.module == "pytorch_ps_mpi_tpu.ops.flash_attention"
+                    for a in n.names}
+        literals = {n.value for n in ast.walk(phase)
+                    if isinstance(n, ast.Constant)
+                    and isinstance(n.value, str)}
+        assert "KERNELS" in imported, name
+        assert not literals & {"_fwd_kernel", "_bwd_dkdv_kernel",
+                               "_bwd_dq_kernel", *_fa.KERNELS}, name

@@ -6,7 +6,7 @@ kernel call of the forward, the rematerialised forward and the backward
 stays under the program's ``kda`` scope and carries its kernel's name, and
 no loop of the plain code is left under that scope.  And the kernels compile
 at the cell's shape — the flash kernels' two-level tiles too, at the shapes
-of the two cells that run them (PR 31): they sit here because this is the
+of the three cells that run them (PRs 31, 33): they sit here because this is the
 one file that may describe a topology.
 
 This is the one test file that describes a TPU topology (the
@@ -121,10 +121,11 @@ def test_the_kernels_compile_at_the_cells_shape(one_chip, what):
 @pytest.mark.parametrize("b,s,h,d,dv", [
     (8, 1024, 16, 64, 64),      # gpt2m-*: a head in one tile, unrolled loops
     (2, 8192, 32, 192, 128),    # kimi-linear-sync-1chip's MLA: device loops
+    (1, 8192, 20, 256, 256),    # glm47-flash-sync-1chip: v as wide as q / k
 ])
 def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
                                                        dv):
-    """The two-level flash kernels as `tile_plan` sizes them for the two
+    """The two-level flash kernels as `tile_plan` sizes them for the three
     shapes the benchmark runs, forward and both backward calls, through the
     chip's compiler: slices, loop bounds and VMEM are its to refuse; and
     the three calls reach the compiled program under their names."""
