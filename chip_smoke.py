@@ -313,6 +313,16 @@ def mosaic_kernels(run: Run, opt, batch, need: set) -> list:
     return sorted(names)
 
 
+def flash_calls(seq: int, d: int, dv: int) -> set:
+    """The flash calls `tile_plan` names for a causal head of ``seq`` rows
+    at the widths ``d`` / ``dv``: one backward call where the q side is
+    whole in VMEM."""
+    from pytorch_ps_mpi_tpu.ops.flash_attention import BLOCK, tile_plan
+
+    pad = lambda n: -(-n // BLOCK) * BLOCK
+    return set(tile_plan(pad(seq), pad(d), pad(dv), True).tiles)
+
+
 def check_batch_on_all_devices(opt, batch) -> None:
     import jax
 
@@ -381,8 +391,7 @@ def phase_lm_flash(run: Run) -> None:
     from pytorch_ps_mpi_tpu.models.transformer import (TransformerLM,
                                                        build_lm, lm_batch,
                                                        make_lm_loss)
-    from pytorch_ps_mpi_tpu.ops.flash_attention import (KERNELS,
-                                                        flash_attention)
+    from pytorch_ps_mpi_tpu.ops.flash_attention import flash_attention
     from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
 
     # The LM the r05 record measured, through the optimizer API (the CLI's
@@ -399,10 +408,11 @@ def phase_lm_flash(run: Run) -> None:
     b = lm_batch(synthetic_lm(batch, seq_len=seq,
                               vocab=sz["lm"]["vocab_size"], seed=0))
     losses = [opt.step(b)[0] for _ in range(sz["lm_steps"])]
-    flash = set(KERNELS)   # the names the module gives its three calls
+    head = sz["lm"]["d_model"] // sz["lm"]["n_heads"]
     fields = sync_opt_fields(run, opt, losses, "lm d%d x L%d" % (
         sz["lm"]["d_model"], sz["lm"]["n_layers"]))
-    fields["mosaic_kernels"] = mosaic_kernels(run, opt, b, flash)
+    fields["mosaic_kernels"] = mosaic_kernels(
+        run, opt, b, flash_calls(seq, head, head))
     check_batch_on_all_devices(opt, b)
     del opt, params
 
@@ -415,7 +425,7 @@ def phase_lm_flash(run: Run) -> None:
     cli = sync_opt_fields(run, opt, logged_losses(log), "cli transformer")
     cli["mosaic_kernels"] = mosaic_kernels(
         run, opt, lm_batch(synthetic_lm(cb, seq_len=sz["cli_lm_seq"])),
-        flash)
+        flash_calls(sz["cli_lm_seq"], 32, 32))   # the CLI's d256 / 8 heads
     fields["cli"] = cli
     run.done(**fields)
 
@@ -423,7 +433,8 @@ def phase_lm_flash(run: Run) -> None:
 def phase_glm_flash(run: Run) -> None:
     """The GLM-MoE LM (rotary latent attention, expert layer, MTP module
     through the shared head) at its published widths and a small depth: the
-    compiled step holds the three flash kernels, here at 256 / 256."""
+    compiled step holds the flash kernels `tile_plan` names, here at
+    256 / 256."""
     import jax
     import jax.numpy as jnp
 
@@ -432,8 +443,7 @@ def phase_glm_flash(run: Run) -> None:
     from pytorch_ps_mpi_tpu.models.glm_moe import (GlmMoeConfig, GlmMoeLM,
                                                    glm_aux, make_glm_loss)
     from pytorch_ps_mpi_tpu.models.transformer import lm_batch
-    from pytorch_ps_mpi_tpu.ops.flash_attention import (KERNELS,
-                                                        flash_attention)
+    from pytorch_ps_mpi_tpu.ops.flash_attention import flash_attention
     from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
     from pytorch_ps_mpi_tpu.utils.flatten import named_params
 
@@ -454,8 +464,10 @@ def phase_glm_flash(run: Run) -> None:
     losses = [opt.step(b)[0] for _ in range(sz["glm_steps"])]
     fields = sync_opt_fields(run, opt, losses, "glm-moe d%d x L%d + mtp" % (
         cfg.d_model, cfg.n_layers))
-    fields["mosaic_kernels"] = mosaic_kernels(run, opt, b, set(KERNELS))
-    fields["flash_widths"] = [cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_dim]
+    widths = [cfg.qk_nope_dim + cfg.qk_rope_dim, cfg.v_dim]
+    fields["mosaic_kernels"] = mosaic_kernels(run, opt, b,
+                                              flash_calls(seq, *widths))
+    fields["flash_widths"] = widths
     check_batch_on_all_devices(opt, b)
     run.done(**fields)
 

@@ -2,8 +2,8 @@
 
 Dense softmax attention materializes the ``[S, S]`` score matrix in HBM;
 at long context that matrix IS the memory bill.  This module computes
-exact attention with O(S · tile) live memory, in three Mosaic calls whose
-names the device trace and the benchmark's readers know (`KERNELS`):
+exact attention with O(S · tile) live memory, in two or three Mosaic calls
+whose names the device trace and the benchmark's readers know (`KERNELS`):
 
 * **Forward** (`flash_fwd`, `_fwd_kernel`): grid ``(B·H, q_tiles,
   k_tiles)`` with the k sweep minor.  For each q tile the kernel holds a
@@ -13,12 +13,18 @@ names the device trace and the benchmark's readers know (`KERNELS`):
   softmax as `parallel.ring_attention`, here on one chip.  Operands go into
   the MXU as they come (bf16), products accumulate in f32, the softmax
   statistics are f32.
-* **Backward**: two kernels (FlashAttention-2 decomposition) under
-  ``jax.custom_vjp`` — `flash_bwd_dkdv` sweeps q per block of k (grid
-  ``(B·H, k_tiles, q_tiles)``), `flash_bwd_dq` sweeps k per block of q —
-  each recomputing ``P`` from the saved per-row logsumexp (``exp(s - lse)``,
-  no second softmax) and accumulating in VMEM scratch, so the backward never
-  materializes ``[S, S]`` either.
+* **Backward**, under ``jax.custom_vjp``: `flash_bwd_dkdv` sweeps q per
+  block of k (grid ``(B·H, k_tiles, q_tiles)``), recomputing ``P`` from the
+  saved per-row logsumexp (``exp(s - lse)``, no second softmax) and
+  accumulating in VMEM scratch, so the backward never materializes
+  ``[S, S]`` either.  Where a head's whole q side is its one q tile (every
+  length up to `_WHOLE_SIDE`, so every shape a cell runs) that call is the
+  whole backward: it has ``dS`` and k in hand for every sub-block it enters
+  and dq's rows are all in VMEM, so it adds ``dS k`` into a third
+  accumulator across the k tiles and writes dq too — five matrix products a
+  sub-block, each of s, ``exp``, dP and dS computed once.  Only past that
+  length, with the q side in tiles, is `flash_bwd_dq` (k swept per block of
+  q, the FlashAttention-2 decomposition: seven products) called for dq.
 
 **Two levels of tiling, the inner one following the mask.**  The grid tile
 (what a `BlockSpec` copies into VMEM) is large — a head's whole sequence
@@ -100,6 +106,8 @@ class Plan(NamedTuple):
 # is (own, other | own, other): the kernel's own side is q for `flash_fwd`
 # and `flash_bwd_dq`, k for `flash_bwd_dkdv`; tile first, sub-block after the
 # bar.  * = a head is one tile, so every loop bound is static and unrolls.
+# The two backward columns are the two-call backward, which since PR 34
+# runs only past `_WHOLE_SIDE`; the one call's rows follow the table.
 #
 #   [BH, S_pad, D_pad / Dv_pad]            flash_fwd  _bwd_dq  _bwd_dkdv
 #   [128, 1024, 128 / 128]  before          0.734   0.897   0.990
@@ -156,10 +164,48 @@ class Plan(NamedTuple):
 # in VMEM (`_vmem` asks for its 96 MiB cap in dkdv) and still beats every
 # split of it; own-side tiles of 512 to 2048 rows are within 1 % of each
 # other, so `_LOOP_TILE` stands at this shape too.
+#
+# PR 34: the backward as one call, `flash_bwd_dkdv` writing dq too (q side
+# whole, so tile_q = S_pad; `tile_k | sub_q, sub_k`), against the two calls
+# above it at their tiles, "two" (same chip, same clock; ms a backward):
+#
+#   [BH, S_pad, D_pad / Dv_pad]      two     one call
+#   [128, 1024, 128 / 128]          1.001   *(1024 |  128,  128)  0.997
+#                                           *(1024 |  256,  256)  0.812
+#                                           *(1024 |  512,  512)  0.732
+#                                           *(1024 |  512,  256)  0.747
+#                                           *(1024 |  256,  512)  0.743
+#                                           *(1024 | 1024, 1024)  0.951
+#   [64, 1536, 128 / 128]           0.950   *(1536 |  128,  128)  1.093
+#                                           *(1536 |  256,  256)  0.865
+#                                           *(1536 |  512,  512)  0.764
+#   [64, 2048, 128 / 128]           1.531   *(2048 |  128,  128)  1.815
+#                                           *(2048 |  256,  256)  1.416
+#                                           *(2048 |  512,  512)  1.212
+#                                           *(2048 | 1024,  512)  1.432
+#   [32, 4096, 128 / 128]           3.416    (1024 |  512,  512)  2.588
+#                                            (2048 |  512,  512)  2.373
+#                                            (4096 |  512,  512)  2.432
+#   [64, 8192, 256 / 128]           36.55    (1024 |  512,  512)  27.28
+#                                            (2048 |  512,  512)  25.94
+#                                            (4096 |  512,  512)  26.98
+#                                            (2048 |  256,  512)  29.28
+#                                            (2048 | 1024,  512)  26.87
+#   [20, 8192, 256 / 256]           14.39    (1024 |  512,  512)  10.57
+#                                            (2048 |  512,  512)  10.12
+#                                            (4096 |  512,  512)  10.49
+#                                            (2048 |  512,  256)  10.45
+#                                            (2048 | 1024,  512)  11.08
+#
+# (7) One call is 0.70–0.80 of two at every shape, at 0.89–0.92 of the
+# MXU's peak for its five products a sub-block.  (8) With the third
+# accumulator the unrolled kernel wants 512 x 512 sub-blocks, not dkdv's
+# old 128 x 128 (1.00–1.19 of two calls there: no gain at all), though it
+# then enters 0.75 of GPT-2's square for the 0.5625 of before; the looped
+# tiles stand.  dq's rows are read, added to and written back a sub-block.
 _WHOLE_HEAD = 2048   # S_pad up to which a head is one tile (swept to here)
 _WHOLE_SIDE = 8192   # ... and the other operand is, beyond it (swept to here)
-_STATIC_SUB = {"flash_fwd": (256, 256), "flash_bwd_dq": (256, 256),
-               "flash_bwd_dkdv": (128, 128)}
+_STATIC_SUB = {"flash_fwd": (256, 256), "flash_bwd_dkdv": (512, 512)}
 _LOOP_SUB = (512, 512)
 _LOOP_TILE = {"flash_fwd": 1024, "flash_bwd_dq": 1024, "flash_bwd_dkdv": 2048}
 # One level (sub-block = grid tile), the sizes from before the sweep: what
@@ -170,16 +216,20 @@ _ONE_LEVEL = {"flash_fwd": (512, 1024), "flash_bwd_dq": (512, 512),
 
 def tile_plan(s_pad, d_pad, dv_pad, causal, *, true_len=None,
               blk_q=None, blk_k=None) -> Plan:
-    """Grid tiles and sub-blocks of the three kernels, from what the code
-    can see: the padded length, the two padded widths and the mask — and,
-    counted with the kernels' own bounds, how many sub-blocks a head enters,
-    masks and skips (``true_len`` under ``s_pad`` adds the padded tail).
+    """Grid tiles and sub-blocks of the calls a shape makes (the keys, in
+    call order: no `flash_bwd_dq` where `flash_bwd_dkdv` holds the whole q
+    side and writes dq itself), from what the code can see: the padded
+    length, the two padded widths and the mask — and, counted with the
+    kernels' own bounds, how many sub-blocks a head enters, masks and skips
+    (``true_len`` under ``s_pad`` adds the padded tail).
     ``blk_q`` / ``blk_k`` force the sub-block (the tests' way to small
     ones); the grid tile stays the shape's, in whole sub-blocks."""
     true_len = s_pad if true_len is None else true_len
     swept = causal and s_pad >= 1024 and max(d_pad, dv_pad) <= 256
     tiles, counts = {}, {}
     for kernel in KERNELS:
+        if kernel == "flash_bwd_dq" and tiles["flash_bwd_dkdv"].tile_q >= s_pad:
+            break   # its rows are whole in dkdv's tile, which writes dq
         if not swept:
             tq, tk = sq, sk = _ONE_LEVEL[kernel]
         elif s_pad <= _WHOLE_HEAD:
@@ -574,15 +624,28 @@ def _bwd_probs(q, k, v, do, lse, delta, keep, scale):
     return p, ds
 
 
-def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref, dk_acc, dv_acc,
-                     *, scale, causal, seq_len, t, n_qt, n_kt):
+def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                     scale, causal, seq_len, t, n_qt, n_kt):
+    """dk and dv of a k tile — and, where the head's whole q side is the one
+    q tile (``n_qt == 1``), dq too: its rows are all in VMEM while the k
+    tiles go by, so ``ds @ k`` adds into a third accumulator that is zeroed
+    at the head's first k tile and written at its last, and `flash_bwd_dq`
+    is not called (``refs``: the outputs, then their accumulators)."""
     iq, ik = _tile_ids(n_qt, n_kt, 2, 1)   # k tile major, q sweep minor
+    whole = n_qt == 1
+    if whole:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
 
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
     _when(iq == 0, _init)
+
+    def _init_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+    _when(whole and ik == 0, _init_dq)
 
     keep = _masker(t, causal, seq_len, q_tail=True)
     for c in range(t.tile_k // t.sub_k):
@@ -599,9 +662,12 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_acc[cols, :] += lax.dot_general(
                 p.astype(do.dtype), do, _TN,
                 preferred_element_type=jnp.float32)
+            ds = ds.astype(q.dtype)
             dk_acc[cols, :] += lax.dot_general(
-                ds.astype(q.dtype), q, _TN,
-                preferred_element_type=jnp.float32)
+                ds, q, _TN, preferred_element_type=jnp.float32)
+            if whole:   # as `_bwd_dq_kernel`: k sub-blocks in ascending order
+                dq_acc[rows, :] += jnp.dot(
+                    ds, k, preferred_element_type=jnp.float32)
 
         for start, stop, masked in _q_segments(
                 k0, q0, t.tile_q // t.sub_q, t, causal, seq_len):
@@ -611,6 +677,10 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
     _when(iq == n_qt - 1, _finish)
+
+    def _finish_dq():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+    _when(whole and ik == n_kt - 1, _finish_dq)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -651,8 +721,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     "t_dkdv", "t_dq", "causal", "scale", "true_len", "interpret"))
 def _bwd_tiles(q3, k3, v3, do3, lse2, delta2, *, t_dkdv, t_dq, causal, scale,
                true_len, interpret):
-    """The two backward calls at their tiles, under `jax.jit` like
-    `_fwd_tiles`.  Each pads its q-aligned and its k-aligned operands to
+    """The backward at its tiles, under `jax.jit` like `_fwd_tiles`: one
+    call where `tile_plan` names no `flash_bwd_dq` (``t_dq`` None: the q side
+    is whole in `flash_bwd_dkdv`'s tile, which then writes dq too), else
+    two.  Each pads its q-aligned and its k-aligned operands to
     whole tiles of its own: a block past the array would read undefined
     bytes on the chip (0 * non-finite garbage = NaN through the
     accumulators even though the mask zeroes p); outputs are sliced back."""
@@ -701,9 +773,11 @@ def _bwd_tiles(q3, k3, v3, do3, lse2, delta2, *, t_dkdv, t_dq, causal, scale,
             **_named(name),
         )(q, k, v, do, lse, delta)
 
-    dk3, dv3 = call(_bwd_dkdv_kernel, "flash_bwd_dkdv", t_dkdv, False,
-                    [("k", d), ("k", dv)])
-    dq3, = call(_bwd_dq_kernel, "flash_bwd_dq", t_dq, True, [("q", d)])
+    whole = t_dq is None
+    dk3, dv3, *rest = call(_bwd_dkdv_kernel, "flash_bwd_dkdv", t_dkdv, False,
+                           [("k", d), ("k", dv)] + [("q", d)] * whole)
+    dq3, = rest if whole else call(
+        _bwd_dq_kernel, "flash_bwd_dq", t_dq, True, [("q", d)])
     return dq3[:, :s_pad], dk3[:, :s_pad], dv3[:, :s_pad]
 
 
@@ -718,14 +792,15 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
                      blk_q=blk_q, blk_k=blk_k)
     return _bwd_tiles(q3, k3, v3, do3, lse2, delta2,
                       t_dkdv=plan.tiles["flash_bwd_dkdv"],
-                      t_dq=plan.tiles["flash_bwd_dq"], causal=causal,
+                      t_dq=plan.tiles.get("flash_bwd_dq"), causal=causal,
                       scale=scale, true_len=true_len, interpret=interpret)
 
 
 def _flash_bwd(causal, scale, interpret, res, dout):
-    """Pallas blockwise backward from the saved logsumexp (FlashAttention-2
-    style: a dk/dv kernel sweeping q per block of k, a dq kernel sweeping k
-    per block of q); every live intermediate is one sub-block in VMEM."""
+    """Pallas blockwise backward from the saved logsumexp: the dk / dv kernel
+    sweeping q per block of k, which writes dq too where the q side is
+    whole, else a dq kernel sweeping k per block of q (FlashAttention-2
+    style); every live intermediate is one sub-block in VMEM."""
     q, k, v, out, lse = res
     b, s, h, d = q.shape
     pad3 = lambda x: _pad_to(_pad_to(_to_bh(x), BLOCK, 1), BLOCK, 2)

@@ -205,8 +205,9 @@ def test_v_is_not_widened_to_the_q_width():
     accumulator and the output at 128: v is not padded to q's width."""
     seen = [[s.shape[-1] for s in jax.tree.leaves(kw["out_shape"])]
             for kw in _pallas_calls(*_qkv_widths(2, s=64, d=192, dv=128))]
-    # forward: o 128 (+ the row statistics' lane tile); dk 256, dv 128; dq 256
-    assert [128, BLOCK] in seen and [256, 128] in seen and [256] in seen
+    # forward: o 128 (+ the row statistics' lane tile); the one backward call
+    # (the q side is whole at 64 rows): dk 256, dv 128, dq 256
+    assert seen == [[128, BLOCK], [256, 128, 256]]
 
 
 # PR 28 pinned its refactor to the parent's bits with digests of these three
@@ -300,49 +301,104 @@ def test_sub_blocks_match_dense(monkeypatch, d, dv, causal, s, blk_q, blk_k):
     _assert_out_and_grads_match(q, k, v, causal, rtol=2e-4, atol=2e-5)
 
 
+def _one_backward_call_at(monkeypatch, t):
+    """The backward as the one call at the tiles ``t`` whatever the shape;
+    the forward keeps the shape's own."""
+    real = _fa.tile_plan
+
+    def plan(*a, **kw):
+        shape = real(*a, **kw)
+        return _fa.Plan({"flash_fwd": shape.tiles["flash_fwd"],
+                         "flash_bwd_dkdv": t}, shape.counts)
+    monkeypatch.setattr(_fa, "tile_plan", plan)
+
+
+@pytest.mark.parametrize("d,dv", [(192, 128), (256, 256)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("s,t", [
+    (384, _fa.Tiles(384, 384, 128, 128)),   # a head in one tile: unrolled
+    (600, _fa.Tiles(640, 128, 128, 128)),   # 5 k tiles, a padded tail
+    (600, _fa.Tiles(768, 256, 256, 128)),   # both sides padded to the tile
+])
+def test_one_backward_call_matches_dense(monkeypatch, d, dv, causal, s, t):
+    """`flash_bwd_dkdv` with the whole q side in its tile writes dq too: dq
+    adds up in VMEM over the k tiles of a head (a mask, a padded tail, a
+    second head that must not see the first one's sum) and no
+    `flash_bwd_dq` is traced."""
+    _one_backward_call_at(monkeypatch, t)
+    q, k, v = _qkv_widths(4, b=1, s=s, h=2, d=d, dv=dv)
+    _assert_out_and_grads_match(q, k, v, causal, rtol=2e-4, atol=2e-5)
+    assert [kw["name"] for kw in _pallas_calls(q, k, v)] == [
+        "flash_fwd", "flash_bwd_dkdv"]
+
+
+def test_one_backward_call_gives_the_dq_of_two(monkeypatch):
+    """Past `_WHOLE_SIDE` (patched down to the length's half) the q side
+    comes in tiles and `flash_bwd_dq` is called as before; the one call's
+    dq on the same inputs is that dq to f32 rounding, dk and dv too."""
+    s = 1200
+    q, k, v = _qkv(10, b=1, s=s, h=2, d=64)
+    grads = lambda: jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+        q, k, v, causal=True))), argnums=(0, 1, 2))(q, k, v)
+    assert "flash_bwd_dq" not in _fa.tile_plan(1280, 128, 128, True).tiles
+    one = grads()
+    monkeypatch.setattr(_fa, "_WHOLE_HEAD", 512)
+    monkeypatch.setattr(_fa, "_WHOLE_SIDE", 512)
+    assert _fa.tile_plan(1280, 128, 128, True).tiles["flash_bwd_dq"] \
+        == (1024, 512, 512, 512)
+    for a, b, name in zip(one, grads(), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6, err_msg=name)
+
+
 def test_the_plan_of_a_long_sequence_matches_dense():
     """Past `_WHOLE_HEAD` the shape's own plan runs device loops over
-    512 x 512 sub-blocks with the other side whole in VMEM."""
+    512 x 512 sub-blocks with the other side whole in VMEM, and the backward
+    is one call over two k tiles."""
     s = 2100
     plan = _fa.tile_plan(2176, 128, 128, True, true_len=s)
-    assert plan.tiles["flash_fwd"] == plan.tiles["flash_bwd_dq"] \
-        == (1024, 2560, 512, 512)
-    assert plan.tiles["flash_bwd_dkdv"] == (2560, 2048, 512, 512)
+    assert plan.tiles == {"flash_fwd": (1024, 2560, 512, 512),
+                          "flash_bwd_dkdv": (2560, 2048, 512, 512)}
     q, k, v = _qkv(9, b=1, s=s, h=1, d=64)
     _assert_out_and_grads_match(q, k, v, True, rtol=2e-4, atol=2e-5)
+
+
+_LOOPED = {"flash_fwd": (1024, 8192, 512, 512),
+           "flash_bwd_dkdv": (8192, 2048, 512, 512)}
 
 
 @pytest.mark.parametrize("shape,tiles,counts", [
     # gpt2m-*: [128, 1024, 128 / 128], a head in one tile, unrolled
     ((1024, 128, 128), {"flash_fwd": (1024, 1024, 256, 256),
-                        "flash_bwd_dkdv": (1024, 1024, 128, 128),
-                        "flash_bwd_dq": (1024, 1024, 256, 256)},
-     {"flash_fwd": (10, 4, 6), "flash_bwd_dkdv": (36, 8, 28),
-      "flash_bwd_dq": (10, 4, 6)}),
+                        "flash_bwd_dkdv": (1024, 1024, 512, 512)},
+     {"flash_fwd": (10, 4, 6), "flash_bwd_dkdv": (3, 2, 1)}),
     # kimi-linear-sync-1chip: [64, 8192, 256 / 128], device loops
-    ((8192, 256, 128), {"flash_fwd": (1024, 8192, 512, 512),
-                        "flash_bwd_dkdv": (8192, 2048, 512, 512),
-                        "flash_bwd_dq": (1024, 8192, 512, 512)},
-     {k: (136, 16, 120) for k in _fa.KERNELS}),
+    ((8192, 256, 128), _LOOPED, {k: (136, 16, 120) for k in _LOOPED}),
     # glm47-flash-sync-1chip: [20, 8192, 256 / 256], the same plan: the
     # tiles follow the length and the mask, v's width only sizes the VMEM
-    ((8192, 256, 256), {"flash_fwd": (1024, 8192, 512, 512),
-                        "flash_bwd_dkdv": (8192, 2048, 512, 512),
-                        "flash_bwd_dq": (1024, 8192, 512, 512)},
-     {k: (136, 16, 120) for k in _fa.KERNELS}),
+    ((8192, 256, 256), _LOOPED, {k: (136, 16, 120) for k in _LOOPED}),
     # a short sequence: one tile, one sub-block, as before the sweep
-    ((512, 128, 128), {k: (512, 512, 512, 512) for k in _fa.KERNELS},
-     {k: (1, 1, 0) for k in _fa.KERNELS}),
+    ((512, 128, 128), {k: (512, 512, 512, 512) for k in _LOOPED},
+     {k: (1, 1, 0) for k in _LOOPED}),
+    # past `_WHOLE_SIDE` the q side comes in tiles: dq's rows are not all in
+    # VMEM while the k tiles go by, and `flash_bwd_dq` is called for them
+    ((16384, 128, 128), {**_LOOPED, "flash_bwd_dq": (1024, 8192, 512, 512)},
+     {k: (528, 32, 496) for k in _fa.KERNELS}),
 ])
 def test_tile_plan_counts(shape, tiles, counts):
-    """Entered / masked / skipped sub-blocks a head, for the three shapes the
-    benchmark's cells run and for the fallback (PERF.md quotes these).
+    """The calls a shape makes (the plan's keys: one backward call where the
+    q side of a head is one tile) and the entered / masked / skipped
+    sub-blocks a head, for the three shapes the benchmark's cells run, for
+    the fallback and for a length past `_WHOLE_SIDE` (PERF.md quotes these).
     Before the two-level tiles GPT-2's shape read forward 2 / 2 / 0 and
     each backward kernel 3 / 3 / 1 (every tile that ran paid the mask); of
-    the score elements of the square a head now computes 0.625 (forward,
-    dq) and 0.5625 (dkdv) where the mask keeps 0.50."""
+    the score elements of the square a head now computes 0.625 (forward)
+    and 0.75 (the one backward call, whose sweep chose 512 x 512 sub-blocks
+    over the 0.5625 of 128 x 128) where the mask keeps 0.50."""
     plan = _fa.tile_plan(*shape, True)
     assert plan.tiles == tiles and plan.counts == counts
+    assert list(plan.tiles) == list(plan.counts) == list(
+        _fa.KERNELS[:len(tiles)])
     for kernel, (entered, masked, skipped) in counts.items():
         t = plan.tiles[kernel]
         assert entered + skipped == (shape[0] // t.sub_q) * (shape[0] // t.sub_k)
@@ -357,38 +413,48 @@ def test_tile_plan_counts(shape, tiles, counts):
     if shape[0] > 512:
         assert all(tail.counts[k].masked > plan.counts[k].masked
                    and tail.counts[k].entered == plan.counts[k].entered
-                   for k in ("flash_bwd_dkdv", "flash_bwd_dq"))
+                   for k in list(tiles)[1:])
 
 
 def test_the_names_the_benchmark_and_the_smoke_look_for():
-    """`mla_flash_ms_step` matches the three calls by name and the smoke's
-    `lm_flash` phase asks the compiled step for them: both must be the names
-    the module gives its calls (the smoke drifted unseen from PR 28 to 30)."""
+    """`mla_flash_ms_step` matches the calls by name and the smoke's flash
+    phases ask the compiled step for those `tile_plan` names at their shape:
+    both must be the names the module gives its calls (the smoke drifted
+    unseen from PR 28 to 30), whichever of them a shape makes."""
     import ast
     import os
 
     from perfbench.models import glm_moe, kimi_linear
 
-    seen = [(kw["name"], kw["metadata"])
-            for kw in _pallas_calls(*_qkv(8, b=1, s=40, h=1, d=8))]
-    assert [n for n, _ in seen] == list(_fa.KERNELS)
-    assert all(m == {"kernel": n} for n, m in seen)
+    for s, calls in ((40, _fa.KERNELS[:2]), (640, _fa.KERNELS)):
+        seen = [(kw["name"], kw["metadata"])
+                for kw in _pallas_calls(*_qkv(8, b=1, s=s, h=1, d=8))]
+        assert tuple(n for n, _ in seen) == calls == tuple(_fa.tile_plan(
+            -(-s // BLOCK) * BLOCK, BLOCK, BLOCK, True).tiles)
+        assert all(m == {"kernel": n} for n, m in seen)
     assert tuple(kimi_linear.FLASH_KERNELS) == _fa.KERNELS
     assert glm_moe.FLASH_KERNELS is kimi_linear.FLASH_KERNELS
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "chip_smoke.py")) as f:
         tree = ast.parse(f.read())
+    def fn(name):
+        return next(n for n in ast.walk(tree)
+                    if isinstance(n, ast.FunctionDef) and n.name == name)
+
+    # the phases ask `flash_calls`, which asks `tile_plan`: no list of names
+    # of the smoke's own, and not all of `KERNELS` (a shape may not call one)
+    assert {a.name for n in ast.walk(fn("flash_calls"))
+            if isinstance(n, ast.ImportFrom)
+            and n.module == "pytorch_ps_mpi_tpu.ops.flash_attention"
+            for a in n.names} == {"BLOCK", "tile_plan"}
     for name in ("phase_lm_flash", "phase_glm_flash"):
-        phase = next(n for n in ast.walk(tree)
-                     if isinstance(n, ast.FunctionDef) and n.name == name)
-        imported = {a.name for n in ast.walk(phase)
-                    if isinstance(n, ast.ImportFrom)
-                    and n.module == "pytorch_ps_mpi_tpu.ops.flash_attention"
-                    for a in n.names}
-        literals = {n.value for n in ast.walk(phase)
+        called = {n.func.id for n in ast.walk(fn(name))
+                  if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)}
+        names = {n.id for n in ast.walk(fn(name)) if isinstance(n, ast.Name)}
+        literals = {n.value for n in ast.walk(fn(name))
                     if isinstance(n, ast.Constant)
                     and isinstance(n.value, str)}
-        assert "KERNELS" in imported, name
+        assert "flash_calls" in called and "KERNELS" not in names, name
         assert not literals & {"_fwd_kernel", "_bwd_dkdv_kernel",
                                "_bwd_dq_kernel", *_fa.KERNELS}, name
