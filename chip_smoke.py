@@ -31,6 +31,12 @@ for all steps, and prints compile seconds, seconds per step and peak HBM):
                         layer + the MTP module, 1 x 8192 tokens a chip, bf16,
                         through Adam(...).compile_step(has_aux).step(): the
                         flash kernels at a 256-wide q / k and a 256-wide v
+  phi_flash             Phi-4-mini-flash-reasoning at published widths, one
+                        Mamba, one window and one full differential-attention
+                        layer, 1 x 8192 tokens a chip, bf16, through
+                        Adam(...).compile_step(has_aux).step(): the scan at
+                        [1, 8192, 5120, 16] and the flash kernels at a 64-wide
+                        q / k and a 128-wide v, with and without window=512
   kernel_parity         every Pallas kernel against its jnp reference
   kda_kernels           the KDA recurrence's kernels against kda_chunked at
                         [1, 8192, 32, 128]: o and the five gradients
@@ -68,8 +74,8 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 PHASES = ("sync_resnet18", "sync_resnet18_blockq", "lm_flash", "glm_flash",
-          "kernel_parity", "kda_kernels", "async_inprocess", "tcp_pair",
-          "multichip", "cache_reuse")
+          "phi_flash", "kernel_parity", "kda_kernels", "async_inprocess",
+          "tcp_pair", "multichip", "cache_reuse")
 # Seconds a phase may take before its process group is killed.  The sum
 # stays under the 1200 s the whole script is allowed.
 PHASE_TIMEOUT_S = {"lm_flash": 420, "glm_flash": 300, "kernel_parity": 300,
@@ -105,6 +111,11 @@ FULL = dict(
              routed_scale=1.8, n_heads=20, q_lora_rank=768, kv_lora_rank=512,
              qk_nope_dim=192, qk_rope_dim=64, v_dim=256, rope_theta=1e6),
     glm_seq=8192, glm_steps=4,
+    phi=dict(vocab_size=25008, d_model=2560, d_ff=10240, n_heads=40,
+             n_kv_heads=20, window=512, d_inner=5120, d_state=16, d_conv=4,
+             dt_rank=160,
+             layers=(("mamba", 0), ("swa", 1), ("full_kv", 17))),
+    phi_seq=8192, phi_steps=4,
     async_lm_seq=256, async_lm_batch=8, async_updates=20,
     async_resnet_batch=512, async_resnet_updates=24,
     flash_shapes=((2, 1024, 16, 64), (2, 777, 16, 64)),
@@ -121,6 +132,10 @@ TINY = dict(
              kv_lora_rank=32, qk_nope_dim=24, qk_rope_dim=8, v_dim=32,
              rope_theta=1e6),
     glm_seq=128, glm_steps=4,
+    phi=dict(vocab_size=128, d_model=64, d_ff=96, n_heads=4, n_kv_heads=2,
+             window=48, d_inner=128, d_state=4, d_conv=4, dt_rank=4,
+             layers=(("mamba", 0), ("swa", 1), ("full_kv", 17))),
+    phi_seq=160, phi_steps=4,
     async_lm_seq=128, async_lm_batch=2, async_updates=20,
     async_resnet_batch=8, async_resnet_updates=24,
     flash_shapes=((1, 256, 2, 64), (1, 200, 2, 64)),
@@ -468,6 +483,51 @@ def phase_glm_flash(run: Run) -> None:
     fields["mosaic_kernels"] = mosaic_kernels(run, opt, b,
                                               flash_calls(seq, *widths))
     fields["flash_widths"] = widths
+    check_batch_on_all_devices(opt, b)
+    run.done(**fields)
+
+
+def phase_phi_flash(run: Run) -> None:
+    """SambaY with differential attention at Phi-4-mini-flash-reasoning's
+    published widths and a small depth: a Mamba layer (the blocked scan at
+    `[1, 8192, 5120, 16]`), a window layer and a full layer, whose flash
+    calls at 64 / 128 the compiled step has to hold."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_ps_mpi_tpu import Adam
+    from pytorch_ps_mpi_tpu.data.datasets import synthetic_lm
+    from pytorch_ps_mpi_tpu.models.sambay import (SambaYConfig, SambaYLM,
+                                                  make_sambay_loss,
+                                                  sambay_aux)
+    from pytorch_ps_mpi_tpu.models.transformer import lm_batch
+    from pytorch_ps_mpi_tpu.ops.flash_attention import flash_attention
+    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
+    from pytorch_ps_mpi_tpu.utils.flatten import named_params
+
+    sz = run.sizes
+    seq, cfg = sz["phi_seq"], SambaYConfig(**sz["phi"], dtype=jnp.bfloat16)
+    model = SambaYLM(cfg, attn=functools.partial(
+        flash_attention, causal=True, scale=cfg.head_dim ** -0.5,
+        impl=run.impl))
+    params = jax.jit(lambda key: named_params(SambaYLM(
+        SambaYConfig(**sz["phi"])).init(
+            key, jnp.zeros((1, 8), jnp.int32))["params"]))(
+                jax.random.PRNGKey(0))
+    opt = Adam(list(params.items()), lr=1e-4, mesh=make_ps_mesh())
+    del params
+    opt.compile_step(make_sambay_loss(model), has_aux=True,
+                     aux=sambay_aux(model))
+    b = lm_batch(synthetic_lm(run.n, seq_len=seq, vocab=cfg.vocab_size,
+                              seed=0))
+    losses = [opt.step(b)[0] for _ in range(sz["phi_steps"])]
+    fields = sync_opt_fields(run, opt, losses, "sambay d%d x L%d" % (
+        cfg.d_model, len(cfg.layers)))
+    widths = [cfg.head_dim, 2 * cfg.head_dim]
+    fields["mosaic_kernels"] = mosaic_kernels(run, opt, b,
+                                              flash_calls(seq, *widths))
+    fields["flash_widths"], fields["window"] = widths, cfg.window
+    fields["scan"] = [run.n, seq, cfg.d_inner, cfg.d_state]
     check_batch_on_all_devices(opt, b)
     run.done(**fields)
 
@@ -821,6 +881,7 @@ CHILD_PHASES = {
     "sync_resnet18_blockq": phase_sync_resnet18_blockq,
     "lm_flash": phase_lm_flash,
     "glm_flash": phase_glm_flash,
+    "phi_flash": phase_phi_flash,
     "kernel_parity": phase_kernel_parity,
     "kda_kernels": phase_kda_kernels,
     "async_inprocess": phase_async_inprocess,

@@ -18,6 +18,7 @@ from .lenet import LeNet5
 from .mlp import init_mlp, mlp_apply, mlp_loss_fn
 from .resnet import ResNet, resnet18, resnet34, resnet50
 from .pipelined import make_pipelined_lm_loss
+from .sambay import SambaYConfig, SambaYLM, make_sambay_loss, sambay_aux
 from .transformer import TransformerLM, build_lm, lm_batch, make_lm_loss
 
 __all__ = [
@@ -25,6 +26,7 @@ __all__ = [
     "TransformerLM", "build_lm", "lm_batch", "make_lm_loss",
     "make_pipelined_lm_loss",
     "GlmMoeConfig", "GlmMoeLM", "glm_aux", "make_glm_loss",
+    "SambaYConfig", "SambaYLM", "sambay_aux", "make_sambay_loss",
     "init_mlp", "mlp_apply", "mlp_loss_fn",
     "build_model", "make_classifier_loss", "eval_accuracy",
 ]

@@ -63,15 +63,17 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def causal_conv_silu(x, kernel):
+def causal_conv_silu(x, kernel, bias=None):
     """Depthwise causal convolution over the sequence, then SiLU:
-    ``y_t = sum_i kernel[i] * x_{t - (K - 1) + i}``.  ``x: [B, S, C]``,
-    ``kernel: [K, C]``."""
+    ``y_t = sum_i kernel[i] * x_{t - (K - 1) + i}`` (``+ bias``, a channel,
+    where the layer has one).  ``x: [B, S, C]``, ``kernel: [K, C]``."""
     taps = kernel.shape[0]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     s = x.shape[1]
     y = sum(padded[:, i:i + s] * kernel[i].astype(x.dtype)
             for i in range(taps))
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     return nn.silu(y)
 
 

@@ -1,0 +1,16 @@
+"""The operations the models and the step are built from, a module each:
+
+* `codecs`, `pallas_kernels`, `robust`: gradient codecs, their Pallas
+  kernels, robust aggregation.
+* `flash_attention`: `flash_attention(q, k, v, causal=, scale=, window=)`
+  and `tile_plan`, the Mosaic attention kernels.
+* `kda`, `kda_pallas`: `kda_attention`, the KDA recurrence (chunked plain
+  form and Pallas kernels).
+* `selective_scan`: `selective_scan(x, dt, A, B, C, D)`, the Mamba-1
+  state-space scan (blocked plain form, hand-written backward).
+
+Nothing is imported here: a program that never attends does not load Pallas.
+"""
+
+__all__ = ["codecs", "flash_attention", "kda", "kda_pallas", "pallas_kernels",
+           "robust", "selective_scan"]

@@ -41,6 +41,17 @@ loops unroll; otherwise they are device loops.  `tile_plan` is the one
 source of both levels' sizes, from the shape, and counts with the kernels'
 own bounds how many sub-blocks a head enters, masks and skips.
 
+**A sliding window** (``window=W`` with ``causal``: row ``i`` sees the ``W``
+keys ``j`` with ``0 <= i - j < W``, its own among them) gives the band a
+lower edge, and the same bounds follow it: a sub-block wholly under the band
+(``q0 - (k0 + sub_k - 1) >= W``) is never entered, one the lower edge
+crosses runs the masked body with one more compare on the same iota
+difference (``diff < k0 - q0 + W``), what lies between the two edges stays
+interior.  `tile_plan` counts with the same bounds, and `flash_bwd_dkdv`
+stays the whole backward.
+``window=None`` is the program this module traced before it knew windows:
+the same jaxpr, the same plan, the same counts.
+
 Composition: `flash_attention` is a drop-in for
 `parallel.ring_attention.dense_attention` (``[B, S, H, D]`` in/out,
 ``causal=``/``scale=``), so it plugs into `models.transformer.TransformerLM`
@@ -203,6 +214,28 @@ class Plan(NamedTuple):
 # old 128 x 128 (1.00–1.19 of two calls there: no gain at all), though it
 # then enters 0.75 of GPT-2's square for the 0.5625 of before; the looped
 # tiles stand.  dq's rows are read, added to and written back a sub-block.
+#
+# PR 35: under ``window=512`` at `[40, 8192, 128 / 128]` (the window layer of
+# `phi4flash-sync-1chip`: q / k 64 wide padded to 128; same chip, same clock,
+# best of five; the looped tiles `(1024, 8192 | ..)` and `(8192, 2048 | ..)`,
+# sub-blocks varied; entered / masked of a head's sub-blocks beside them):
+#
+#   sub-blocks       flash_fwd   the backward   entered / masked
+#   no window          6.29        11.55         136 /  16 of  256
+#    512 x  512        2.85         4.34          31 /  31 of  256
+#    256 x  256        3.63         4.69          93 /  62 of 1024
+#    256 x  512        3.07         4.73          62 /  62 of  512
+#    512 x  256        3.42         4.72          62 /  62 of  512
+#    128 x  128        7.11         8.67         310 / 124 of 4096
+#   1024 x  512        3.34         4.93          23 /  23 of  128
+#
+# (9) Under a window the looped sub-blocks stay 512 x 512: smaller ones enter
+# fewer columns a row (768 for 1,024 at 256 x 256, a third of them mask-free)
+# and still lose to their loops, as in (1).  The band's 31 sub-blocks are all
+# masked and take 0.45 (forward) and 0.38 (backward) of the causal 136's time
+# for 0.23 of their number.  A band walked in a fixed number of guarded trips
+# instead of device loops was tried and bought 3-4 % of the two kernels
+# (2.73 / 4.21), 0.05 ms of the cell's 8.1 ms window layer: not kept.
 _WHOLE_HEAD = 2048   # S_pad up to which a head is one tile (swept to here)
 _WHOLE_SIDE = 8192   # ... and the other operand is, beyond it (swept to here)
 _STATIC_SUB = {"flash_fwd": (256, 256), "flash_bwd_dkdv": (512, 512)}
@@ -214,16 +247,17 @@ _ONE_LEVEL = {"flash_fwd": (512, 1024), "flash_bwd_dq": (512, 512),
               "flash_bwd_dkdv": (512, 512)}
 
 
-def tile_plan(s_pad, d_pad, dv_pad, causal, *, true_len=None,
+def tile_plan(s_pad, d_pad, dv_pad, causal, *, window=None, true_len=None,
               blk_q=None, blk_k=None) -> Plan:
     """Grid tiles and sub-blocks of the calls a shape makes (the keys, in
     call order: no `flash_bwd_dq` where `flash_bwd_dkdv` holds the whole q
     side and writes dq itself), from what the code can see: the padded
     length, the two padded widths and the mask — and, counted with the
     kernels' own bounds, how many sub-blocks a head enters, masks and skips
-    (``true_len`` under ``s_pad`` adds the padded tail).
-    ``blk_q`` / ``blk_k`` force the sub-block (the tests' way to small
-    ones); the grid tile stays the shape's, in whole sub-blocks."""
+    (``true_len`` under ``s_pad`` adds the padded tail, ``window`` the
+    band's lower edge; the tiles are the same with and without one: see the
+    sweep above).  ``blk_q`` / ``blk_k`` force the sub-block (the tests' way
+    to small ones); the grid tile stays the shape's, in whole sub-blocks."""
     true_len = s_pad if true_len is None else true_len
     swept = causal and s_pad >= 1024 and max(d_pad, dv_pad) <= 256
     tiles, counts = {}, {}
@@ -246,7 +280,7 @@ def tile_plan(s_pad, d_pad, dv_pad, causal, *, true_len=None,
         t = Tiles(-(-min(tq, s_pad) // sq) * sq, -(-min(tk, s_pad) // sk) * sk,
                   sq, sk)
         tiles[kernel] = t
-        counts[kernel] = _count(kernel, t, s_pad, causal, true_len)
+        counts[kernel] = _count(kernel, t, s_pad, causal, true_len, window)
     return Plan(tiles, counts)
 
 
@@ -300,14 +334,17 @@ def _unless(cond, x, other):
     return jnp.where(cond, other, x)
 
 
-def _k_segments(q0, k0, n, t, causal, seq_len, q_tail):
+def _k_segments(q0, k0, n, t, causal, seq_len, q_tail, window=None):
     """For the q rows ``[q0, q0 + sub_q)`` and the ``n`` k sub-blocks of the
     tile that starts at column ``k0``: ``(start, stop, masked)`` runs of
     sub-blocks to enter, in order — the interior ones (wholly under the
     diagonal, no padded column), then the masked ones; what follows lies
     above the diagonal or in the padding and is never entered.  ``seq_len``
     is None when nothing is padded; ``q_tail`` (the backward) also masks
-    padded q rows, whose logsumexp is ``NEG_INF``."""
+    padded q rows, whose logsumexp is ``NEG_INF``.  Under a ``window``
+    (key ``j`` is seen from row ``i`` while ``i - j < window``) the run
+    starts at the first sub-block that reaches into the band, and those the
+    band's lower edge crosses come first, masked."""
     mid = hi = n
     if causal:
         mid = _span(q0 + 1 - k0, t.sub_k, n)
@@ -319,40 +356,59 @@ def _k_segments(q0, k0, n, t, causal, seq_len, q_tail):
             mid = _unless(q0 + t.sub_q > seq_len, mid, 0)
             hi = _unless(q0 >= seq_len, hi, 0)
     mid = _least(mid, hi)
-    return [(0, mid, False), (mid, hi, True)]
+    if window is None:
+        return [(0, mid, False), (mid, hi, True)]
+    # the newest key of a sub-block under the band: q0 - (k + sub_k - 1) >=
+    # window; its oldest key inside for the last row: q0 + sub_q - 1 - k <
+    # window
+    lo = _least(_span(q0 + 1 - window - k0, t.sub_k, n), hi)
+    mid = _most(mid, lo)
+    full = _least(_most(_span(q0 + t.sub_q - window - k0, t.sub_k, n, up=True),
+                        lo), mid)
+    return [(lo, full, True), (full, mid, False), (mid, hi, True)]
 
 
-def _q_segments(k0, q0, n, t, causal, seq_len):
+def _q_segments(k0, q0, n, t, causal, seq_len, window=None):
     """The same for `flash_bwd_dkdv`, which holds the k rows ``[k0, k0 +
     sub_k)`` and walks the ``n`` q sub-blocks of the tile that starts at row
     ``q0``: above the diagonal nothing, on it masked, under it interior,
-    then (only where something is padded) the masked tail."""
+    then (only where something is padded, or under a ``window``, whose
+    lower edge the last rows cross) the masked tail; past the band
+    nothing."""
     lo, mid, full, hi = 0, 0, n, n
     if causal:
         lo = _span(k0 - q0, t.sub_q, n)
         mid = _span(k0 + t.sub_k - 1 - q0, t.sub_q, n, up=True)
+    if window is not None:
+        full = _span(window + k0 - q0, t.sub_q, n)
+        hi = _span(window + k0 + t.sub_k - 1 - q0, t.sub_q, n, up=True)
     if seq_len is not None:
-        full = _span(seq_len - q0, t.sub_q, n)
-        hi = _span(seq_len - q0, t.sub_q, n, up=True)
+        tail_full = _span(seq_len - q0, t.sub_q, n)
+        tail_hi = _span(seq_len - q0, t.sub_q, n, up=True)
+        if window is None:
+            full, hi = tail_full, tail_hi
+        else:
+            full, hi = _least(full, tail_full), _least(hi, tail_hi)
         mid = _unless(k0 + t.sub_k > seq_len, mid, hi)   # padded columns
         hi = _unless(k0 >= seq_len, hi, 0)               # nothing but
         full = _least(full, hi)
     mid = _least(mid, hi)
     lo, full = _least(lo, mid), _most(mid, full)
     segments = [(lo, mid, True), (mid, full, False)]
-    if seq_len is not None:
+    if seq_len is not None or window is not None:
         segments.append((full, hi, True))
     return segments
 
 
-def _entered(kernel, t, s_q, s_k, causal, seq_len):
+def _entered(kernel, t, s_q, s_k, causal, seq_len, window=None):
     """``(q0, k0, masked)`` of every sub-block of a head that ``kernel``
     enters, by the kernel's own bounds on Python ints."""
     if kernel == "flash_bwd_dkdv":
         for k0 in range(0, s_k, t.sub_k):
             for q0 in range(0, s_q, t.tile_q):
                 for start, stop, masked in _q_segments(
-                        k0, q0, t.tile_q // t.sub_q, t, causal, seq_len):
+                        k0, q0, t.tile_q // t.sub_q, t, causal, seq_len,
+                        window):
                     for a in range(start, stop):
                         yield q0 + a * t.sub_q, k0, masked
     else:
@@ -360,16 +416,16 @@ def _entered(kernel, t, s_q, s_k, causal, seq_len):
             for k0 in range(0, s_k, t.tile_k):
                 for start, stop, masked in _k_segments(
                         q0, k0, t.tile_k // t.sub_k, t, causal, seq_len,
-                        q_tail=kernel == "flash_bwd_dq"):
+                        q_tail=kernel == "flash_bwd_dq", window=window):
                     for c in range(start, stop):
                         yield q0, k0 + c * t.sub_k, masked
 
 
-def _count(kernel, t, s_pad, causal, true_len) -> Counts:
+def _count(kernel, t, s_pad, causal, true_len, window=None) -> Counts:
     s_q, s_k = (-(-s_pad // t.tile_q) * t.tile_q,
                 -(-s_pad // t.tile_k) * t.tile_k)
     masks = [masked for _, _, masked in _entered(
-        kernel, t, s_q, s_k, causal, _seq_len(true_len, s_q, s_k))]
+        kernel, t, s_q, s_k, causal, _seq_len(true_len, s_q, s_k), window)]
     return Counts(len(masks), sum(masks),
                   (s_q // t.sub_q) * (s_k // t.sub_k) - len(masks))
 
@@ -404,16 +460,19 @@ def _lanes(x, n):
     return x if n == BLOCK else jnp.tile(x, (1, n // BLOCK))
 
 
-def _masker(t, causal, seq_len, q_tail):
+def _masker(t, causal, seq_len, q_tail, window=None):
     """``keep(q0, k0)`` for the masked sub-block whose corner is row ``q0``,
-    column ``k0``: one compare on an iota difference built once a grid step,
-    and the ``< seq_len`` tests only where something is padded."""
+    column ``k0``: one compare on an iota difference built once a grid step
+    (one more under a ``window``), and the ``< seq_len`` tests only where
+    something is padded."""
     row = lax.broadcasted_iota(jnp.int32, (t.sub_q, t.sub_k), 0)
     col = lax.broadcasted_iota(jnp.int32, (t.sub_q, t.sub_k), 1)
     diff = row - col
 
     def keep(q0, k0):
         mask = diff >= k0 - q0 if causal else None
+        if window is not None:
+            mask &= diff < k0 - q0 + window
         if seq_len is not None:
             tail = col < seq_len - k0             # padded K tail: no mass
             if q_tail:
@@ -431,7 +490,7 @@ def _tile_ids(n_qt, n_kt, q_axis, k_axis):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, seq_len, t, n_qt, n_kt):
+                *, scale, causal, seq_len, t, n_qt, n_kt, window=None):
     iq, ik = _tile_ids(n_qt, n_kt, 1, 2)
     dv = v_ref.shape[-1]
 
@@ -441,7 +500,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
     _when(ik == 0, _init)
 
-    keep = _masker(t, causal, seq_len, q_tail=False)
+    keep = _masker(t, causal, seq_len, q_tail=False, window=window)
     for a in range(t.tile_q // t.sub_q):
         rows = _rows(a, t.sub_q)
         q0, k0 = iq * t.tile_q + a * t.sub_q, ik * t.tile_k
@@ -470,7 +529,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                           preferred_element_type=jnp.float32))
 
         for start, stop, masked in _k_segments(
-                q0, k0, t.tile_k // t.sub_k, t, causal, seq_len, q_tail=False):
+                q0, k0, t.tile_k // t.sub_k, t, causal, seq_len,
+                q_tail=False, window=window):
             _loop(start, stop, functools.partial(sub_block, masked=masked))
 
     def _finish():
@@ -513,8 +573,9 @@ def _kv_index(t, causal, n_kt):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "t", "causal", "scale", "true_len", "interpret"))
-def _fwd_tiles(q3, k3, v3, *, t, causal, scale, true_len, interpret):
+    "t", "causal", "scale", "true_len", "interpret", "window"))
+def _fwd_tiles(q3, k3, v3, *, t, causal, scale, true_len, interpret,
+               window=None):
     """The forward call at the tiles ``t``, under `jax.jit` so that the
     layers of a model share one trace of the kernel."""
     bh, s_pad, d = q3.shape
@@ -525,7 +586,8 @@ def _fwd_tiles(q3, k3, v3, *, t, causal, scale, true_len, interpret):
     n_qt, n_kt = s_q // t.tile_q, s_k // t.tile_k
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        seq_len=_seq_len(true_len, s_q, s_k), t=t, n_qt=n_qt, n_kt=n_kt)
+        seq_len=_seq_len(true_len, s_q, s_k), t=t, n_qt=n_qt, n_kt=n_kt,
+        window=window)
     kv = _kv_index(t, causal, n_kt)
     size = q3.dtype.itemsize
     out, lse = pl.pallas_call(
@@ -559,7 +621,7 @@ def _fwd_tiles(q3, k3, v3, *, t, causal, scale, true_len, interpret):
 
 
 def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
-              blk_q=None, blk_k=None):
+              window=None, blk_q=None, blk_k=None):
     """``q3,k3: [BH, S_pad, D_pad]``, ``v3: [BH, S_pad, Dv_pad]`` already
     padded to BLOCK/lane tiles (``Dv_pad`` may differ from ``D_pad``: the
     accumulator and the output take v's width); returns ``(out [BH, S_pad,
@@ -567,9 +629,10 @@ def _fwd_call(q3, k3, v3, *, causal, scale, true_len, interpret,
     tail so it carries no softmax mass.  Tiles and sub-blocks are
     `tile_plan`'s; ``blk_q`` / ``blk_k`` force the sub-block."""
     plan = tile_plan(q3.shape[1], q3.shape[2], v3.shape[2], causal,
-                     blk_q=blk_q, blk_k=blk_k)
+                     blk_q=blk_q, blk_k=blk_k, window=window)
     return _fwd_tiles(q3, k3, v3, t=plan.tiles["flash_fwd"], causal=causal,
-                      scale=scale, true_len=true_len, interpret=interpret)
+                      scale=scale, true_len=true_len, interpret=interpret,
+                      window=window)
 
 
 def _to_bh(x):
@@ -583,26 +646,26 @@ def _from_bh(x3, b, h):
     return x3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, scale, interpret):
-    out, _ = _flash_fwd_res(q, k, v, causal, scale, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, interpret, window=None):
+    out, _ = _flash_fwd_res(q, k, v, causal, scale, interpret, window)
     return out
 
 
-def _flash_fwd_res(q, k, v, causal, scale, interpret):
+def _flash_fwd_res(q, k, v, causal, scale, interpret, window=None):
     b, s, h, d = q.shape
     q3 = _pad_to(_pad_to(_to_bh(q), BLOCK, 1), BLOCK, 2)
     k3 = _pad_to(_pad_to(_to_bh(k), BLOCK, 1), BLOCK, 2)
     v3 = _pad_to(_pad_to(_to_bh(v), BLOCK, 1), BLOCK, 2)
     out3, lse3 = _fwd_call(q3, k3, v3, causal=causal, scale=scale,
-                           true_len=s, interpret=interpret)
+                           true_len=s, interpret=interpret, window=window)
     out = _from_bh(out3[:, :s, :v.shape[-1]], b, h)
     lse = lse3[:, :s, 0].reshape(b, h, s)
     return out, (q, k, v, out, lse)
 
 
-def _flash_fwd_vjp(q, k, v, causal, scale, interpret):
-    return _flash_fwd_res(q, k, v, causal, scale, interpret)
+def _flash_fwd_vjp(q, k, v, causal, scale, interpret, window):
+    return _flash_fwd_res(q, k, v, causal, scale, interpret, window)
 
 
 def _bwd_probs(q, k, v, do, lse, delta, keep, scale):
@@ -625,7 +688,7 @@ def _bwd_probs(q, k, v, do, lse, delta, keep, scale):
 
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                     scale, causal, seq_len, t, n_qt, n_kt):
+                     scale, causal, seq_len, t, n_qt, n_kt, window=None):
     """dk and dv of a k tile — and, where the head's whole q side is the one
     q tile (``n_qt == 1``), dq too: its rows are all in VMEM while the k
     tiles go by, so ``ds @ k`` adds into a third accumulator that is zeroed
@@ -647,7 +710,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dq_acc[...] = jnp.zeros_like(dq_acc)
     _when(whole and ik == 0, _init_dq)
 
-    keep = _masker(t, causal, seq_len, q_tail=True)
+    keep = _masker(t, causal, seq_len, q_tail=True, window=window)
     for c in range(t.tile_k // t.sub_k):
         cols = _rows(c, t.sub_k)
         q0, k0 = iq * t.tile_q, ik * t.tile_k + c * t.sub_k
@@ -670,7 +733,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                     ds, k, preferred_element_type=jnp.float32)
 
         for start, stop, masked in _q_segments(
-                k0, q0, t.tile_q // t.sub_q, t, causal, seq_len):
+                k0, q0, t.tile_q // t.sub_q, t, causal, seq_len, window):
             _loop(start, stop, functools.partial(sub_block, masked=masked))
 
     def _finish():
@@ -685,14 +748,14 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc,
-                   *, scale, causal, seq_len, t, n_qt, n_kt):
+                   *, scale, causal, seq_len, t, n_qt, n_kt, window=None):
     iq, ik = _tile_ids(n_qt, n_kt, 1, 2)   # q tile major, k sweep minor
 
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
     _when(ik == 0, _init)
 
-    keep = _masker(t, causal, seq_len, q_tail=True)
+    keep = _masker(t, causal, seq_len, q_tail=True, window=window)
     for a in range(t.tile_q // t.sub_q):
         rows = _rows(a, t.sub_q)
         q0, k0 = iq * t.tile_q + a * t.sub_q, ik * t.tile_k
@@ -709,7 +772,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                        preferred_element_type=jnp.float32)
 
         for start, stop, masked in _k_segments(
-                q0, k0, t.tile_k // t.sub_k, t, causal, seq_len, q_tail=True):
+                q0, k0, t.tile_k // t.sub_k, t, causal, seq_len,
+                q_tail=True, window=window):
             _loop(start, stop, functools.partial(sub_block, masked=masked))
 
     def _finish():
@@ -718,9 +782,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "t_dkdv", "t_dq", "causal", "scale", "true_len", "interpret"))
+    "t_dkdv", "t_dq", "causal", "scale", "true_len", "interpret", "window"))
 def _bwd_tiles(q3, k3, v3, do3, lse2, delta2, *, t_dkdv, t_dq, causal, scale,
-               true_len, interpret):
+               true_len, interpret, window=None):
     """The backward at its tiles, under `jax.jit` like `_fwd_tiles`: one
     call where `tile_plan` names no `flash_bwd_dq` (``t_dq`` None: the q side
     is whole in `flash_bwd_dkdv`'s tile, which then writes dq too), else
@@ -759,7 +823,8 @@ def _bwd_tiles(q3, k3, v3, do3, lse2, delta2, *, t_dkdv, t_dq, causal, scale,
         return pl.pallas_call(
             functools.partial(
                 kernel, scale=scale, causal=causal, t=t, n_qt=n_qt,
-                n_kt=n_kt, seq_len=_seq_len(true_len, s_q, s_k)),
+                n_kt=n_kt, seq_len=_seq_len(true_len, s_q, s_k),
+                window=window),
             grid=grid,
             in_specs=[spec("q", d), spec("k", d), spec("k", dv),
                       spec("q", dv), spec("q", BLOCK), spec("q", BLOCK)],
@@ -782,21 +847,22 @@ def _bwd_tiles(q3, k3, v3, do3, lse2, delta2, *, t_dkdv, t_dq, causal, scale,
 
 
 def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
-              interpret, blk_q=None, blk_k=None):
+              interpret, window=None, blk_q=None, blk_k=None):
     """``q3,k3: [BH, S_pad, D_pad]``; ``v3,do3: [BH, S_pad, Dv_pad]``;
     ``lse2, delta2: [BH, S_pad, BLOCK]`` f32, lane-replicated (same MIN_BLOCK_SIZE trick as
     the forward's lse output — Mosaic wants (8k, 128k) tiles).  Returns
     ``(dq, dk, dv)`` padded like the inputs.  Tiles and sub-blocks are
     `tile_plan`'s; ``blk_q`` / ``blk_k`` force the sub-block."""
     plan = tile_plan(q3.shape[1], q3.shape[2], v3.shape[2], causal,
-                     blk_q=blk_q, blk_k=blk_k)
+                     blk_q=blk_q, blk_k=blk_k, window=window)
     return _bwd_tiles(q3, k3, v3, do3, lse2, delta2,
                       t_dkdv=plan.tiles["flash_bwd_dkdv"],
                       t_dq=plan.tiles.get("flash_bwd_dq"), causal=causal,
-                      scale=scale, true_len=true_len, interpret=interpret)
+                      scale=scale, true_len=true_len, interpret=interpret,
+                      window=window)
 
 
-def _flash_bwd(causal, scale, interpret, res, dout):
+def _flash_bwd(causal, scale, interpret, window, res, dout):
     """Pallas blockwise backward from the saved logsumexp: the dk / dv kernel
     sweeping q per block of k, which writes dq too where the q side is
     whole, else a dq kernel sweeping k per block of q (FlashAttention-2
@@ -815,7 +881,7 @@ def _flash_bwd(causal, scale, interpret, res, dout):
     rep = lambda x2: jnp.broadcast_to(x2[..., None], x2.shape + (BLOCK,))
     dq3, dk3, dv3 = _bwd_call(q3, k3, v3, do3, rep(lse2), rep(delta2),
                               causal=causal, scale=scale, true_len=s,
-                              interpret=interpret)
+                              interpret=interpret, window=window)
     back = lambda x3, w: _from_bh(x3[:, :s, :w], b, h).astype(q.dtype)
     return back(dq3, d), back(dk3, d), back(dv3, v.shape[-1])
 
@@ -824,7 +890,8 @@ _flash.defvjp(_flash_fwd_vjp, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
-                    scale: float | None = None, impl: str = "mosaic"):
+                    scale: float | None = None, window: int | None = None,
+                    impl: str = "mosaic"):
     """Exact attention, O(S·BLOCK) memory.  ``q,k: [B, S, H, D]``,
     ``v: [B, S, H, Dv]`` → ``[B, S, H, Dv]``; ``Dv`` may differ from ``D``
     (latent attention trains with a 192-wide q / k and a 128-wide v), each
@@ -834,7 +901,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     hot-op layer of the TPU framework).  ``impl="interpret"`` runs the
     kernels under the Pallas interpreter (the CPU mesh, by name); the
     default lowers through Mosaic and fails to compile anywhere but on a
-    TPU."""
+    TPU.  ``window=W`` (with ``causal``) keeps, for row ``i``, the ``W``
+    keys ``j`` with ``0 <= i - j < W``, the row's own among them: the
+    kernels then enter only the sub-blocks the band touches and mask the
+    ones its two edges cross.  ``window=None`` is the program it was before
+    there were windows, traced and compiled the same."""
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
-    return _flash(q, k, v, causal, scale, use_interpreter(impl))
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window of at least one key, under a causal mask")
+    return _flash(q, k, v, causal, scale, use_interpreter(impl), window)

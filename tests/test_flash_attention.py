@@ -448,7 +448,7 @@ def test_the_names_the_benchmark_and_the_smoke_look_for():
             if isinstance(n, ast.ImportFrom)
             and n.module == "pytorch_ps_mpi_tpu.ops.flash_attention"
             for a in n.names} == {"BLOCK", "tile_plan"}
-    for name in ("phase_lm_flash", "phase_glm_flash"):
+    for name in ("phase_lm_flash", "phase_glm_flash", "phase_phi_flash"):
         called = {n.func.id for n in ast.walk(fn(name))
                   if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)}
         names = {n.id for n in ast.walk(fn(name)) if isinstance(n, ast.Name)}
@@ -458,3 +458,135 @@ def test_the_names_the_benchmark_and_the_smoke_look_for():
         assert "flash_calls" in called and "KERNELS" not in names, name
         assert not literals & {"_fwd_kernel", "_bwd_dkdv_kernel",
                                "_bwd_dq_kernel", *_fa.KERNELS}, name
+
+
+# -- a sliding window: the band's lower edge ----------------------------------
+
+
+def _dense_band(q, k, v, window, causal=True):
+    """Dense softmax attention over the keys ``0 <= i - j < window``."""
+    del causal
+    s = q.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    age = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    scores = jnp.where((age >= 0) & (age < window), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), v)
+
+
+@pytest.mark.parametrize("kernel", _fa.KERNELS)
+@pytest.mark.parametrize("window", [1, 50, 128, 129, 300, 2000])
+@pytest.mark.parametrize("t,s_pad,true_len", [
+    (_fa.Tiles(512, 512, 128, 128), 1024, 1024),
+    (_fa.Tiles(512, 1024, 256, 128), 1024, 1000),
+    (_fa.Tiles(1024, 512, 128, 256), 1024, 700),
+    (_fa.Tiles(384, 384, 128, 384), 768, 768),
+    (_fa.Tiles(256, 256, 256, 256), 512, 300),
+])
+def test_the_bounds_enter_what_the_band_keeps(kernel, window, t, s_pad,
+                                              true_len):
+    """Brute force over positions, as `test_the_bounds_enter_what_the_mask_
+    keeps`, with the band ``0 <= i - j < window`` in the mask: a sub-block
+    wholly under the band is never entered, one the band's lower edge
+    crosses is masked, one between the edges is interior, each entered
+    once.  (The forward may enter,
+    masked, a sub-block in which only padded q rows would have had keys:
+    their output is cut off.)"""
+    seq_len = None if true_len == s_pad else true_len
+    entered = list(_fa._entered(kernel, t, s_pad, s_pad, True, seq_len,
+                                window))
+    got = {(q0, k0): masked for q0, k0, masked in entered}
+    assert len(got) == len(entered)
+    pos = np.arange(s_pad)
+    age = pos[:, None] - pos[None, :]
+    keep = (pos[None, :] < true_len) & (age >= 0) & (age < window)
+    rows_live = pos < true_len
+    for q0 in range(0, s_pad, t.sub_q):
+        for k0 in range(0, s_pad, t.sub_k):
+            block = keep[q0:q0 + t.sub_q, k0:k0 + t.sub_k]
+            live = rows_live[q0:q0 + t.sub_q]
+            if kernel != "flash_fwd":
+                block = block & live[:, None]
+            if not block.any():
+                assert (q0, k0) not in got or (
+                    kernel == "flash_fwd" and not live.all()
+                    and got[(q0, k0)]), (q0, k0)
+            elif block.all():
+                assert got[(q0, k0)] is False, (q0, k0)
+            elif kernel == "flash_fwd" or live.all():
+                assert got[(q0, k0)] is True, (q0, k0)
+            else:   # some padded q rows: entered, and masked
+                assert got.get((q0, k0), True) is True, (q0, k0)
+
+
+@pytest.mark.parametrize("s,window,blk_q,blk_k", [
+    (384, 100, 128, 128),   # a window smaller than a sub-block, unrolled
+    (384, 128, 128, 128),   # ... equal to one
+    (600, 200, 128, 128),   # ... larger; device loops, S no multiple of 128
+    (600, 50, 256, 128),    # sub-blocks that are not square
+    (520, 300, 128, 256),
+    (300, 1000, 128, 128),  # a window longer than the sequence: causal
+    (1100, 256, None, None),    # the shape's own plan, unrolled
+])
+def test_window_matches_a_dense_band_at_64_and_128(monkeypatch, s, window,
+                                                   blk_q, blk_k):
+    """Output and the three gradients under ``window=`` against a dense
+    softmax over the band, at the widths the differential layers run (q / k
+    64, v 128), in f32 under the interpreter: a sub-block under the band
+    skipped, one on its lower edge, an interior one, the diagonal, the
+    padded tail."""
+    _forced(monkeypatch, blk_q, blk_k)
+    counts = _fa.tile_plan(-(-s // BLOCK) * BLOCK, 128, 128, True,
+                           window=window, true_len=s).counts
+    causal = _fa.tile_plan(-(-s // BLOCK) * BLOCK, 128, 128, True,
+                           true_len=s).counts
+    for kernel, c in counts.items():
+        assert c.entered + c.skipped == sum(causal[kernel][::2])
+        assert c.entered <= causal[kernel].entered
+        if window + 2 * 256 <= s:   # some sub-block lies under the band
+            assert c.entered < causal[kernel].entered
+    q, k, v = _qkv_widths(11, b=1, s=s, h=2, d=64, dv=128)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    flash = functools.partial(flash_attention, causal=True, window=window)
+    dense = functools.partial(_dense_band, window=window)
+    want = [dense(q, k, v), *jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)]
+    got = [flash(q, k, v), *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)]
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5, err_msg=name)
+
+
+def test_the_plan_under_the_cells_window():
+    """`[40, 8192, 128 / 128]` under ``window=512`` (the window layer of
+    `phi4flash-sync-1chip`): the looped tiles of the causal plan, and of a
+    head's 256 sub-blocks of 512 x 512 the band touches 31 (two a row of
+    sub-blocks, both crossed by an edge) where the causal mask alone leaves
+    136; 256 x 256 sub-blocks would enter 93 of 1,024, a third of them
+    interior; a window of 4,096 enters 108, 84 of them interior."""
+    plan = _fa.tile_plan(8192, 128, 128, True, window=512)
+    assert plan.tiles == _LOOPED == _fa.tile_plan(8192, 128, 128, True).tiles
+    assert plan.counts == {k: (31, 31, 225) for k in _LOOPED}
+    small = _fa.tile_plan(8192, 128, 128, True, window=512, blk_q=256,
+                          blk_k=256)
+    assert small.counts == {k: (93, 62, 931) for k in _LOOPED}
+    assert _fa.tile_plan(8192, 128, 128, True, window=4096).counts \
+        == {k: (108, 24, 148) for k in _LOOPED}
+    # a window as long as the sequence is the causal mask, counted the same
+    assert _fa.tile_plan(8192, 128, 128, True, window=8192).counts \
+        == {k: (136, 16, 120) for k in _LOOPED}
+
+
+def test_without_a_window_the_program_is_the_one_it_was():
+    """``window=None`` (the default) traces what a call without the
+    argument traces, to the letter of the jaxpr, and plans what it planned;
+    a window needs the causal mask."""
+    q, k, v = _qkv(12, b=1, s=300, h=2, d=64)
+    text = lambda **kw: str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True, **kw)),
+        argnums=(0, 1, 2)))(q, k, v))
+    assert text() == text(window=None)
+    assert text() != text(window=100)
+    for shape in ((1024, 128, 128), (8192, 256, 128), (8192, 256, 256)):
+        assert _fa.tile_plan(*shape, True, window=None) \
+            == _fa.tile_plan(*shape, True)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=100)

@@ -6,8 +6,9 @@ kernel call of the forward, the rematerialised forward and the backward
 stays under the program's ``kda`` scope and carries its kernel's name, and
 no loop of the plain code is left under that scope.  And the kernels compile
 at the cell's shape — the flash kernels' two-level tiles too, at the shapes
-of the three cells that run them (PRs 31, 33): they sit here because this is the
-one file that may describe a topology.
+of the cells that run them (PRs 31, 33, 35; the last under a window too), and
+the selective scan's kernels: they sit here because this is
+the one file that may describe a topology.
 
 This is the one test file that describes a TPU topology (the
 `on-chip-measurement` guide, section 2): only inside a fixture, never while
@@ -118,14 +119,16 @@ def test_the_kernels_compile_at_the_cells_shape(one_chip, what):
     assert ("kda_bwd" if what == "backward" else "kda_fwd") in text
 
 
-@pytest.mark.parametrize("b,s,h,d,dv", [
-    (8, 1024, 16, 64, 64),      # gpt2m-*: a head in one tile, unrolled loops
-    (2, 8192, 32, 192, 128),    # kimi-linear-sync-1chip's MLA: device loops
-    (1, 8192, 20, 256, 256),    # glm47-flash-sync-1chip: v as wide as q / k
+@pytest.mark.parametrize("b,s,h,d,dv,window", [
+    (8, 1024, 16, 64, 64, None),    # gpt2m-*: a head in one tile, unrolled
+    (2, 8192, 32, 192, 128, None),  # kimi-linear-sync-1chip's MLA: loops
+    (1, 8192, 20, 256, 256, None),  # glm47-flash-sync-1chip: v as wide as q
+    (1, 8192, 40, 64, 128, None),   # phi4flash-sync-1chip: full and cross
+    (1, 8192, 40, 64, 128, 512),    # ... and its window layer (PR 35)
 ])
 def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
-                                                       dv):
-    """The two-level flash kernels as `tile_plan` sizes them for the three
+                                                       dv, window):
+    """The two-level flash kernels as `tile_plan` sizes them for the
     shapes the benchmark runs, forward and backward, through the chip's
     compiler: slices, loop bounds and VMEM are its to refuse; and the
     compiled program holds exactly the calls `tile_plan` names: at these
@@ -137,9 +140,37 @@ def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
     qk = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16, sharding=one_chip)
     grad = jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
-        q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
+        q, k, v, causal=True, window=window).astype(jnp.float32)),
+        argnums=(0, 1, 2))
     text = jax.jit(grad).lower(qk, qk, v).compile().as_text()
     pad = lambda n: -(-n // fa.BLOCK) * fa.BLOCK
-    calls = list(fa.tile_plan(s, pad(d), pad(dv), True).tiles)
+    calls = list(fa.tile_plan(s, pad(d), pad(dv), True, window=window).tiles)
     assert calls == ["flash_fwd", "flash_bwd_dkdv"]
     assert sorted(kernel for _, kernel in _CALL.findall(text)) == sorted(calls)
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_the_selective_scan_compiles_at_the_cells_shape(one_chip, what):
+    """`ops.selective_scan` at ``[1, 8192, 5120, 16]`` lowered for the TPU:
+    the sizes are whole tiles, so the program holds the `ssm_fwd` /
+    `ssm_bwd` kernels (tiling, the dynamic first index of the spread ``B``
+    and ``C`` blocks and of the block's states, and VMEM are the chip's
+    compiler's to refuse) and nothing near the 2.7 GB that the states of a
+    whole row would be."""
+    from pytorch_ps_mpi_tpu.ops.selective_scan import selective_scan
+
+    rows, s, d, n = 1, 8192, 5120, 16
+    shape = lambda dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    args = (shape((rows, s, d), jnp.bfloat16), shape((rows, s, d)),
+            shape((d, n)), shape((rows, s, n)), shape((rows, s, n)),
+            shape((d,)))
+    fn = selective_scan
+    if what == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(selective_scan(*a).astype(
+            jnp.float32)), argnums=range(6))
+    compiled = jax.jit(fn).lower(*args).compile()
+    kernels = sorted(kernel for _, kernel in _CALL.findall(compiled.as_text()))
+    assert kernels == (["ssm_bwd", "ssm_fwd"] if what == "backward"
+                       else ["ssm_fwd"])
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
