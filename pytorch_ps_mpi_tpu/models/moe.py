@@ -10,7 +10,10 @@
   renormalised and scaled, SwiGLU experts, a shared expert).  It is told
   which experts it holds, routes over all of them, computes its own
   experts' part of the result for the tokens routed to them and drops
-  none, whatever the imbalance.  `models.kimi_linear` runs on it.
+  none, whatever the imbalance.  `models.kimi_linear` and `models.glm_moe`
+  run on it.  Its plan over the ``T * k`` assignments (the count an
+  expert, the weights in sorted order) compares, sums and sorts: XLA's TPU
+  gather and scatter take scalars one after another.
 
 `MoEMLP`, in detail.  The reference explores ``Ialltoallv`` as a transport primitive
 (`/root/reference/test_mpi.py:11-25`) but never builds on it; this layer is
@@ -158,6 +161,26 @@ BLOCK_ROWS = 512    # assignments a step of the grouped sweep, all of one expert
 # here (the seeded routing, ~480 an expert): 128 25.9, 256 24.2, **512
 # 24.3**, 1024 25.3.  With 10.8 % here (where the cell's routing drifts
 # to, ~1,760 an expert): 128 44.3, 256 37.6, **512 34.7**, 1024 33.0.
+# Again in PR 36 (my chip runs; the sweep alone, `routed_here` forward and
+# backward, ms at the two cells' shapes and at the shares they reach).
+# Kimi's, 7 % / 13 % here (9,127 / 17,006): 256 21.1 / 31.6, 384 20.5 / 28.7,
+# **512 20.1 / 28.4**, 768 19.4 / 25.2, 1024 22.7 / 30.2.  GLM's (8,192
+# tokens, d 2048, experts of 1536, 8 of 64, top-4), 15 % / 25 % (4,939 /
+# 8,177): 256 12.8 / 16.7, 384 9.3 / 12.2, **512 10.0 / 11.3**, 768 8.0 /
+# 12.7, 1024 9.6 / 11.9.  What wins follows how each expert's count falls
+# into its blocks, which drifts inside a run: 512 stays.
+# The same sweep as grouped Pallas kernels over windows of W consecutive
+# sorted rows, whichever experts they belong to (ISSUE 36: megablox's `gmm`,
+# then a `gmm` of this repo's with one product a tile, and a `tgmm` that
+# kept an expert's f32 gradient tile in VMEM over its rows), best tiles of a
+# sweep over (tm, tk, tn): Kimi's W 512 19.6 / 28.7, 1024 19.3 / 28.0, 2048
+# 19.5 / 28.0, 4096 21.1 / 29.6, 8192 23.5 / 31.9; GLM's 512 11.2 / 14.7,
+# 1024 11.5 / 14.7, 2048 11.5 / 13.9, 4096 11.7 / 12.9, 8192 12.1 / 13.3: no
+# better than the block loop alone, and worse inside the cells (GLM's step
+# 366.8 -> 375.3 ms), so it is not here.  Why: PERF.md section 6, PR 36 (XLA's
+# products on a 512-row block run at 175 TFLOP/s, the kernels at 80-138; a
+# block's intermediates stay on the chip, a window's go through HBM; the
+# scatter-add costs twice as much a row at 4,096 rows as at 512).
 
 
 def route_top_k(x, router, select_bias, *, top_k: int, scale: float):
@@ -200,18 +223,34 @@ def _swiglu_block(xs, w_in, w_out, expert):
 
 
 @jax.custom_vjp
-def _grouped_swiglu(x, w_in, w_out, weight, plan):
+def _grouped_swiglu(x, w_gate, w_up, w_down, weight, plan):
     """``sum over the assignments routed here of weight * SwiGLU_e(x_token)``
     as ``[T, d]`` in f32.  The assignments come sorted by held expert
     (``plan``); the sweep takes one block of `BLOCK_ROWS` of them at a
     time, all of one expert, under a loop whose trip count is the number of
     blocks the routing actually filled — so the matrix products follow the
     tokens that came here, not the worst case, and nothing has a capacity
-    to overflow."""
-    return _grouped_fwd(x, w_in, w_out, weight, plan)[0]
+    to overflow.
+
+    The weights are read in ``x``'s type, ``[gate | up]`` side by side, and
+    the reading is made in here, from the parameters themselves: the
+    backward makes its own and hands each parameter's gradient back in one
+    pass over its f32 accumulator, rounded to ``x``'s type as the transpose
+    of that reading rounds it.  (Made outside, the concatenation and the
+    cast were transposed by JAX in two more passes over ``[held, d, 2 f]``
+    in f32, and their results kept for the backward: 0.6 ms a layer and
+    228 MB of `glm47-flash-sync-1chip`'s peak; my chip runs, PR 36.)"""
+    return _grouped_fwd(x, w_gate, w_up, w_down, weight, plan)[0]
 
 
-def _grouped_fwd(x, w_in, w_out, weight, plan):
+def _read_weights(x, w_gate, w_up, w_down):
+    return (jnp.concatenate([w_gate, w_up], axis=-1).astype(x.dtype),
+            w_down.astype(x.dtype))
+
+
+def _grouped_fwd(x, w_gate, w_up, w_down, weight, plan):
+    w_in, w_out = _read_weights(x, w_gate, w_up, w_down)
+
     def body(i, y):
         expert, _, _, tokens, w = _block_of(i, plan, weight)
         out = _swiglu_block(x[tokens], w_in, w_out, expert)[3]
@@ -219,11 +258,12 @@ def _grouped_fwd(x, w_in, w_out, weight, plan):
 
     y = lax.fori_loop(0, plan["n_blocks"], body,
                       jnp.zeros(x.shape, jnp.float32))
-    return y, (x, w_in, w_out, weight, plan)
+    return y, (x, w_gate, w_up, w_down, weight, plan)
 
 
 def _grouped_bwd(res, dy):
-    x, w_in, w_out, weight, plan = res
+    x, w_gate, w_up, w_down, weight, plan = res
+    w_in, w_out = _read_weights(x, w_gate, w_up, w_down)
     dy = dy.astype(jnp.float32)
 
     def body(i, acc):
@@ -257,13 +297,41 @@ def _grouped_bwd(res, dy):
          jnp.zeros(w_in.shape, jnp.float32),
          jnp.zeros(w_out.shape, jnp.float32),
          jnp.zeros(weight.shape, jnp.float32)))
+    f = w_gate.shape[-1]
+    as_read = lambda g, w: g.astype(x.dtype).astype(w.dtype)
     no_grad = jax.tree.map(
         lambda a: np.zeros(a.shape, jax.dtypes.float0), plan)
-    return (dx.astype(x.dtype), dw_in.astype(w_in.dtype),
-            dw_out.astype(w_out.dtype), dweight, no_grad)
+    return (dx.astype(x.dtype), as_read(dw_in[..., :f], w_gate),
+            as_read(dw_in[..., f:], w_up), as_read(dw_out, w_down), dweight,
+            no_grad)
 
 
 _grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@jax.custom_vjp
+def _sorted_by(where, weight):
+    """``(order, weight[order])`` for ``order = argsort(where, stable)``, as
+    one sort that carries both along, and its transpose as the sort that
+    undoes it: no gather of ``T * k`` scalars and no scatter-add behind it
+    (0.23 and 0.29 ms at 32,768 on the v5e, where a sort takes 0.02; my
+    chip runs, PR 36)."""
+    return _sorted_by_fwd(where, weight)[0]
+
+
+def _sorted_by_fwd(where, weight):
+    index = lax.iota(jnp.int32, where.shape[0])
+    _, order, sorted_weight = lax.sort((where, index, weight), num_keys=1,
+                                       is_stable=True)
+    return (order, sorted_weight), order
+
+
+def _sorted_by_bwd(order, grads):
+    _, back = lax.sort((order, grads[1]), num_keys=1)
+    return np.zeros(order.shape, jax.dtypes.float0), back
+
+
+_sorted_by.defvjp(_sorted_by_fwd, _sorted_by_bwd)
 
 
 def routed_here(x, chosen, weight, w_gate, w_up, w_down, *, n_experts: int,
@@ -277,8 +345,9 @@ def routed_here(x, chosen, weight, w_gate, w_up, w_down, *, n_experts: int,
     local = np.full((n_experts,), n_held, np.int32)
     local[list(held)] = np.arange(n_held, dtype=np.int32)
     where = jnp.asarray(local)[chosen.reshape(-1)]          # [T * k]
-    order = jnp.argsort(where, stable=True)
-    count = jnp.zeros((n_held + 1,), jnp.int32).at[where].add(1)[:n_held]
+    order, sorted_weight = _sorted_by(where, weight.reshape(-1))
+    count = jnp.sum(where[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                    dtype=jnp.int32)      # a scatter-add of ones is serial
     blocks = -(-count // BLOCK_ROWS)
     pad = lambda a: jnp.pad(a, (0, BLOCK_ROWS))   # a slice may run past
     plan = {
@@ -287,9 +356,7 @@ def routed_here(x, chosen, weight, w_gate, w_up, w_down, *, n_experts: int,
         "blocks": blocks, "block_end": jnp.cumsum(blocks),
         "n_blocks": jnp.sum(blocks),
     }
-    y = _grouped_swiglu(
-        x, jnp.concatenate([w_gate, w_up], axis=-1).astype(x.dtype),
-        w_down.astype(x.dtype), pad(weight.reshape(-1)[order]), plan)
+    y = _grouped_swiglu(x, w_gate, w_up, w_down, pad(sorted_weight), plan)
     load = jnp.concatenate([count, jnp.sum(count, keepdims=True)])
     return y, load.astype(jnp.float32)
 

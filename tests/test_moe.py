@@ -380,3 +380,69 @@ def test_the_shares_add_up_to_the_uncut_layer(whole_layer):
     np.testing.assert_allclose(np.asarray(sum(routed) + shared),
                                np.asarray(whole), rtol=1e-5, atol=1e-5)
     assert sum(loads) == 2 * 37 * TOP_K       # every assignment, once
+
+
+# -- the plan without scalar gathers and scatters (PR 36) ---------------------
+# XLA's TPU gather and scatter take the `T * k` scalars one after another;
+# `routed_here` compares, sums and sorts instead, to the same numbers.
+
+@pytest.mark.parametrize("n,groups", [(296, 5), (64, 1), (1000, 9)])
+def test_sorted_by_is_the_stable_argsort_its_gather_and_its_scatter(n,
+                                                                    groups):
+    rng = np.random.RandomState(n)
+    where = jnp.asarray(rng.randint(0, groups, n), jnp.int32)   # many ties
+    weight = jnp.asarray(rng.rand(n), jnp.float32)
+    order, in_order = moe._sorted_by(where, weight)
+    want = jnp.argsort(where, stable=True)
+    np.testing.assert_array_equal(order, want)
+    np.testing.assert_array_equal(in_order, weight[want])
+    pull = jnp.asarray(rng.randn(n), jnp.float32)
+    got = jax.grad(lambda w: jnp.sum(moe._sorted_by(where, w)[1] * pull))(
+        weight)
+    np.testing.assert_array_equal(
+        got, jax.grad(lambda w: jnp.sum(w[want] * pull))(weight))
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_layer_gathers_and_scatters_rows_and_nothing_smaller(whole_layer):
+    """Forward and backward of the layer: every gather and scatter-add left
+    moves rows of ``d`` numbers or an expert's slice of a weight gradient
+    (the sweep's), is `route_top_k`'s ``[T, k]`` reading of the chosen
+    scores, or reads the 16-entry table of held experts; the plan walks no
+    list of ``T * k`` scalars (the count an expert was a scatter-add of
+    ones, the sorted weights a gather with a scatter-add behind it)."""
+    params, x = whole_layer
+    held = (4, 5, 6, 7)
+    f = lambda p, x: jnp.sum(_share(held).apply({"params": p}, x)[0])
+    jaxpr = jax.make_jaxpr(jax.grad(f, (0, 1)))(_cut(params, held), x)
+    moved = [(e.primitive.name, e.outvars[0].aval.shape)
+             for e in _equations(jaxpr.jaxpr)
+             if e.primitive.name in ("gather", "scatter-add", "scatter")]
+    assert {name for name, _ in moved} >= {"gather", "scatter-add"}
+    scalars = [(name, shape) for name, shape in moved if len(shape) < 2]
+    assert scalars == [("gather", (2 * 37 * TOP_K,))], scalars  # local[chosen]
+
+
+def test_the_experts_gradients_are_rounded_as_their_reading_is(whole_layer):
+    """In bf16 the experts' weights are read in bf16 inside the sweep; their
+    gradients come back f32, rounded to bf16 as the transpose of that
+    reading rounds them, and near the f32 layer's."""
+    params, x = whole_layer
+    held = (4, 5, 6, 7)
+    mine = _cut(params, held)
+    f = lambda dtype: lambda p: jnp.sum(jnp.sin(
+        _share(held).clone(dtype=dtype).apply({"params": p}, x)[0]
+        .astype(jnp.float32)))
+    got, want = jax.grad(f(jnp.bfloat16))(mine), jax.grad(f(jnp.float32))(mine)
+    for name in ("w_gate", "w_up", "w_down"):
+        g = got[name]
+        assert g.dtype == jnp.float32 and np.asarray(g).any()
+        np.testing.assert_array_equal(
+            g, g.astype(jnp.bfloat16).astype(jnp.float32))
+        np.testing.assert_allclose(g, want[name], rtol=0.1, atol=0.05)
