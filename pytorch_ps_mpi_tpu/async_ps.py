@@ -56,7 +56,7 @@ from .ops.codecs import Codec, IdentityCodec, get_codec
 from .parallel.mesh import default_devices
 from .ps import init_ps_core
 from .utils.bytes import bytes_of
-from .utils.timing import span
+from .utils.timing import BoundedList, span
 
 Params = "OrderedDict[str, jax.Array]"
 
@@ -479,7 +479,7 @@ class AsyncPS:
         self._apply_robust_fn = None
         self._norm_fn = None
         self._itemwise = False
-        self.timings: list[dict[str, float]] = []
+        self.timings: list[dict[str, float]] = BoundedList()
         # Test/diagnostic knob: workers wait for their own gradient to be
         # consumed before pulling again, making 1-worker runs deterministic
         # (sequential SGD).  Never the default — it is a barrier.

@@ -188,8 +188,12 @@ class TransformerLM(nn.Module):
                       attn, self.tp_axis, self.moe_experts,
                       self.moe_capacity, self.ep_axis,
                       name=f"block_{i}")(x)
-        x = nn.LayerNorm(dtype=jnp.float32)(x)
-        return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
+        # The scope the other language models give their final norm, head
+        # and cross-entropy (`make_lm_loss` has the other half).
+        with jax.named_scope("head_loss"):
+            x = nn.LayerNorm(dtype=jnp.float32)(x)
+            return nn.Dense(self.vocab_size, dtype=jnp.float32,
+                            name="lm_head")(x)
 
 
 def build_lm(model: TransformerLM, seq_len: int, seed: int = 0):
@@ -219,10 +223,11 @@ def make_lm_loss(model: TransformerLM, *, aux_weight: float = 0.01):
         else:
             logits = model.apply(variables, batch["tokens"],
                                  batch["positions"])
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, batch["targets"][..., None],
-                                 axis=-1)[..., 0]
-        loss = -jnp.mean(ll)
+        with jax.named_scope("head_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ll = jnp.take_along_axis(logp, batch["targets"][..., None],
+                                     axis=-1)[..., 0]
+            loss = -jnp.mean(ll)
         if moe:
             aux = sum(jax.tree.leaves(extras["losses"]))
             loss = loss + aux_weight * aux

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.timing import record_overlap_schedule
+from ..utils.timing import record_overlap_schedule, step_scope
 from . import collectives
 from .collectives import _allreduce_rs_ag, _plan_buckets
 
@@ -199,18 +199,21 @@ def _sync_identity(cot: "OrderedDict", axis, world: int, reducer: str):
     combiner's reach (see module docstring); ``psum`` is one fused
     all-reduce."""
     names = list(cot)
-    flat = (jnp.concatenate([cot[n].reshape(-1) for n in names])
-            if len(names) > 1 else cot[names[0]].reshape(-1))
-    if reducer == "psum":
-        summed = lax.psum(flat, axis)
-    else:
-        summed = _allreduce_rs_ag(flat, axis, world)
     out = OrderedDict()
-    off = 0
-    for n in names:
-        sz = cot[n].size
-        out[n] = summed[off:off + sz].reshape(cot[n].shape)
-        off += sz
+    # Inside the backward, so the operations' `op_name` also carries
+    # `transpose(`: `utils.timing.step_phase` lets the step's own scope win.
+    with step_scope("exchange"):
+        flat = (jnp.concatenate([cot[n].reshape(-1) for n in names])
+                if len(names) > 1 else cot[names[0]].reshape(-1))
+        if reducer == "psum":
+            summed = lax.psum(flat, axis)
+        else:
+            summed = _allreduce_rs_ag(flat, axis, world)
+        off = 0
+        for n in names:
+            sz = cot[n].size
+            out[n] = summed[off:off + sz].reshape(cot[n].shape)
+            off += sz
     return out
 
 
@@ -224,8 +227,9 @@ def _sync_codec(cot: "OrderedDict", axis, codec):
     codes = OrderedDict((n, codec.encode(g)) for n, g in cot.items())
     # A bucket is already size-targeted; gather its codes in one flat
     # transfer per dtype (1 << 62 disables the inner re-bucketing).
-    gathered = collectives.allgather_tree_bucketed(
-        codes, axis, bucket_bytes=1 << 62)
+    with step_scope("exchange"):
+        gathered = collectives.allgather_tree_bucketed(
+            codes, axis, bucket_bytes=1 << 62)
     return OrderedDict(
         (n, codec.decode_sum(gathered[n], shape=meta[n][0],
                              dtype=meta[n][1]))
@@ -265,8 +269,9 @@ def _sync_blockq_fused(cot: "OrderedDict", axis, codec):
     from ..ops import pallas_kernels as pk
 
     q, scales, rows = _blockq_bucket_encode(cot, codec)
-    gathered = collectives.allgather_tree_bucketed(
-        {"q": q, "scales": scales}, axis, bucket_bytes=1 << 62)
+    with step_scope("exchange"):
+        gathered = collectives.allgather_tree_bucketed(
+            {"q": q, "scales": scales}, axis, bucket_bytes=1 << 62)
     out2d = pk.block_dequant_sum(gathered["q"], gathered["scales"],
                                  block_rows=rows, impl=codec.impl)
     summed = out2d.reshape(-1)

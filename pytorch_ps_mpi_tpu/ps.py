@@ -39,7 +39,6 @@ user-supplied ``loss_fn(params, batch)`` — the JAX analogue of
 from __future__ import annotations
 
 import sys
-import time
 from collections import OrderedDict
 from functools import partial
 from typing import Any, Callable
@@ -55,8 +54,8 @@ from .optim.rules import RULES
 from .parallel.mesh import PS_AXIS, make_ps_mesh, replicated
 from .parallel import collectives
 from .utils.bytes import bytes_of
-from .utils.timing import (STEP_METRIC_KEYS, counter_log,
-                           register_program)
+from .utils.timing import (STEP_METRIC_KEYS, BoundedList, counter_log,
+                           register_program, span, step_scope)
 
 Params = "OrderedDict[str, jax.Array]"
 
@@ -417,7 +416,9 @@ class MPI_PS:
             # gradient sync ran through the fused per-bucket encode twin
             # (one quantize sweep per bucket) instead of per-leaf encodes.
             "fused_sync_encodes": 0, "rollbacks": []}
-        self.timings: list[dict[str, float]] = []  # `ps.py:80` accumulator
+        # `ps.py:80` accumulator: one dict a step, the newest 65,536.
+        self.timings: list[dict[str, float]] = BoundedList()
+        self._byte_metrics: "dict[str, float] | None" = None
         # Incremented the moment a step's NEW params become visible on self
         # (i.e. with the post-dispatch reassignment, before the blocking
         # wait).  An interrupt-triggered checkpoint must record the step
@@ -434,7 +435,6 @@ class MPI_PS:
         self._remat = False
         self._step_fn = None
         self._loss_fn = None
-        self._warm = False
 
     # -- ZeRO state layout ----------------------------------------------------
 
@@ -496,8 +496,9 @@ class MPI_PS:
         """all_gather the code leaves across the PS axis (bucketed when
         ``bucket_mb`` is set — one flat transfer per ~bucket_mb of same-dtype
         payload across ALL parameters), then decode-sum per parameter."""
-        gathered = collectives.allgather_tree_bucketed(
-            codes, self.axis, bucket_bytes=self.bucket_bytes)
+        with step_scope("exchange"):
+            gathered = collectives.allgather_tree_bucketed(
+                codes, self.axis, bucket_bytes=self.bucket_bytes)
         d_ps = OrderedDict()
         for n, code in gathered.items():
             shape, dtype = grads_meta[n]
@@ -514,12 +515,13 @@ class MPI_PS:
 
     def _apply_updates(self, params, state, d_ps):
         new_params, new_state = OrderedDict(), OrderedDict()
-        for n, p in params.items():
-            if n not in d_ps:  # grad-is-None skip (`ps.py:178-179` parity)
-                new_params[n], new_state[n] = p, state[n]
-                continue
-            new_params[n], new_state[n] = self._update_fn(
-                p, d_ps[n], state[n], **self._resolved_hyper(state[n]))
+        with step_scope("update"):
+            for n, p in params.items():
+                if n not in d_ps:  # grad-is-None skip (`ps.py:178-179` parity)
+                    new_params[n], new_state[n] = p, state[n]
+                    continue
+                new_params[n], new_state[n] = self._update_fn(
+                    p, d_ps[n], state[n], **self._resolved_hyper(state[n]))
         return new_params, new_state
 
     def _grads_and_aux(self, loss_fn, has_aux: bool, params, aux, batch):
@@ -553,11 +555,12 @@ class MPI_PS:
 
             def body(carry, mb):
                 aux_c, acc = carry
-                if has_aux:
-                    (loss, aux_c), g = jax.value_and_grad(
-                        loss_fn, has_aux=True)(params, aux_c, mb)
-                else:
-                    loss, g = jax.value_and_grad(loss_fn)(params, mb)
+                with step_scope("grad"):
+                    if has_aux:
+                        (loss, aux_c), g = jax.value_and_grad(
+                            loss_fn, has_aux=True)(params, aux_c, mb)
+                    else:
+                        loss, g = jax.value_and_grad(loss_fn)(params, mb)
                 acc = jax.tree.map(jnp.add, acc, g)
                 return (aux_c, acc), loss
 
@@ -565,29 +568,33 @@ class MPI_PS:
             grads = jax.tree.map(lambda a: a / accum, acc)
             loss = jnp.mean(losses)
         elif has_aux:
-            (loss, new_aux), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, aux, batch)
+            with step_scope("grad"):
+                (loss, new_aux), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, aux, batch)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            with step_scope("grad"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             new_aux = aux
-        if has_aux:
-            # Batch stats are per-rank; average them so aux stays
-            # replicated (the standard cross-replica BN-stats sync).
-            new_aux = collectives.pmean_tree(new_aux, self.reduce_axes)
-        if self.extra_axes:
-            # Collapse the intra-rank axes first: after this, every sp
-            # shard holds its rank's full gradient, replicated.
-            grads = collectives.pmean_tree(grads, self.extra_axes)
-            loss = lax.pmean(loss, self.extra_axes)
+        with step_scope("exchange"):
+            if has_aux:
+                # Batch stats are per-rank; average them so aux stays
+                # replicated (the standard cross-replica BN-stats sync).
+                new_aux = collectives.pmean_tree(new_aux, self.reduce_axes)
+            if self.extra_axes:
+                # Collapse the intra-rank axes first: after this, every sp
+                # shard holds its rank's full gradient, replicated.
+                grads = collectives.pmean_tree(grads, self.extra_axes)
+                loss = lax.pmean(loss, self.extra_axes)
         return loss, grads, new_aux
 
     def _summed_grads(self, grads):
         """Cross-rank gradient sum, full tensors: the identity codec fuses
         to bucketed all-reduces; codecs ride all_gather + fused decode-sum."""
         if isinstance(self.code, IdentityCodec):
-            return collectives.psum_tree_bucketed(
-                grads, self.axis, bucket_bytes=self.bucket_bytes,
-                decompose=self.decompose_allreduce)
+            with step_scope("exchange"):
+                return collectives.psum_tree_bucketed(
+                    grads, self.axis, bucket_bytes=self.bucket_bytes,
+                    decompose=self.decompose_allreduce)
         meta = {n: (g.shape, g.dtype) for n, g in grads.items()}
         codes = self._encode_all(grads)
         return self._sync_codes(codes, meta)
@@ -614,12 +621,14 @@ class MPI_PS:
         leaves are disjoint per-rank chunks (the ZeRO layout, pads zero)
         and the global sq-norm assembles via one scalar psum; without it
         the leaves are the full replicated tensors."""
-        sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                 for g in jax.tree.leaves(d_ps))
-        if psum_axis is not None:
-            sq = lax.psum(sq, psum_axis)
-        scale = jnp.minimum(1.0, self.clip_norm / (jnp.sqrt(sq) + 1e-6))
-        return jax.tree.map(lambda g: (g * scale).astype(g.dtype), d_ps)
+        with step_scope("update"):
+            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                     for g in jax.tree.leaves(d_ps))
+            if psum_axis is not None:
+                with step_scope("exchange"):
+                    sq = lax.psum(sq, psum_axis)
+            scale = jnp.minimum(1.0, self.clip_norm / (jnp.sqrt(sq) + 1e-6))
+            return jax.tree.map(lambda g: (g * scale).astype(g.dtype), d_ps)
 
     def _extras_specs(self):
         """Per-key PartitionSpecs for the extras tree: the EF residual is
@@ -657,6 +666,10 @@ class MPI_PS:
 
         counters = self._has_counters
 
+        # The phases below and in the helpers they call run under
+        # `step_scope(...)` (`ps.grad`, `ps.exchange`, `ps.update`): names
+        # on the operations, which `utils.timing.step_phase` reads back out
+        # of the compiled text to split a device trace by phase (PERF.md §3).
         def core(params, state, aux, batch, extras):
             # With overlap, `grads` leave the backward ALREADY cross-rank
             # summed (the bucket hooks ran the exchange in-flight).
@@ -670,7 +683,8 @@ class MPI_PS:
                 # rank's NaN/inf propagates through the sum.)
                 bad = sum(jnp.sum(~jnp.isfinite(g)).astype(jnp.float32)
                           for g in jax.tree.leaves(grads))
-                ok = lax.psum(bad, self.reduce_axes) == 0
+                with step_scope("exchange"):
+                    ok = lax.psum(bad, self.reduce_axes) == 0
             new_extras = OrderedDict(extras)
             if use_ef:
                 d_sum, new_extras["ef"] = self._summed_grads_ef(
@@ -700,10 +714,11 @@ class MPI_PS:
                 new_params, new_state = self._apply_updates(
                     params, state, d_ps)
             if ema_decay is not None:
-                new_extras["ema"] = jax.tree.map(
-                    lambda e, p: (ema_decay * e
-                                  + (1.0 - ema_decay) * p.astype(e.dtype)),
-                    extras["ema"], new_params)
+                with step_scope("update"):
+                    new_extras["ema"] = jax.tree.map(
+                        lambda e, p: (ema_decay * e
+                                      + (1.0 - ema_decay) * p.astype(e.dtype)),
+                        extras["ema"], new_params)
             if self.skip_nonfinite:
                 keep = lambda new, old: jax.tree.map(
                     lambda a, b: jnp.where(ok, a, b), new, old)
@@ -714,8 +729,11 @@ class MPI_PS:
                 skipped = 1.0 - ok.astype(jnp.float32)
             else:
                 skipped = jnp.float32(0.0)
-            out = (new_params, new_state, new_aux,
-                   lax.pmean(loss, self.reduce_axes), skipped, new_extras)
+            # Among the exchange: the compiler may combine this all-reduce
+            # with the gradients'.
+            with step_scope("exchange"):
+                loss = lax.pmean(loss, self.reduce_axes)
+            out = (new_params, new_state, new_aux, loss, skipped, new_extras)
             # The counters once more, as an output nothing donates: the
             # copy in `new_aux` is handed to the next step and dies there.
             return out + ((new_aux["counters"],) if counters else ())
@@ -758,21 +776,22 @@ class MPI_PS:
         every other exchange; slice the already-decoded sum otherwise.
         Clip (if configured) applies here — the chunks jointly are the
         summed gradient the update consumes."""
-        if d_full is None:
-            flats = OrderedDict(
-                (n, self._zero_pad_flat(grads[n], *self._zero_meta[n]))
-                for n in grads)
-            d_chunks = collectives.reduce_scatter_flats_bucketed(
-                flats, self.axis, world=self.world_size,
-                bucket_bytes=self.bucket_bytes)
-        else:
-            my = lax.axis_index(self.axis)
-            d_chunks = OrderedDict()
-            for n in d_full:
-                sz, chunk = self._zero_meta[n]
-                d_chunks[n] = lax.dynamic_slice(
-                    self._zero_pad_flat(d_full[n], sz, chunk),
-                    (my * chunk,), (chunk,))
+        with step_scope("exchange"):
+            if d_full is None:
+                flats = OrderedDict(
+                    (n, self._zero_pad_flat(grads[n], *self._zero_meta[n]))
+                    for n in grads)
+                d_chunks = collectives.reduce_scatter_flats_bucketed(
+                    flats, self.axis, world=self.world_size,
+                    bucket_bytes=self.bucket_bytes)
+            else:
+                my = lax.axis_index(self.axis)
+                d_chunks = OrderedDict()
+                for n in d_full:
+                    sz, chunk = self._zero_meta[n]
+                    d_chunks[n] = lax.dynamic_slice(
+                        self._zero_pad_flat(d_full[n], sz, chunk),
+                        (my * chunk,), (chunk,))
         if self.clip_norm is not None:
             d_chunks = self._clip_tree(d_chunks, psum_axis=self.axis)
         return d_chunks
@@ -785,27 +804,30 @@ class MPI_PS:
         math is bitwise the replicated rule applied elementwise."""
         my = lax.axis_index(self.axis)
         new_chunks, new_state = OrderedDict(), OrderedDict()
-        for n, p in params.items():
-            sz, chunk = self._zero_meta[n]
-            p_chunk = lax.dynamic_slice(
-                self._zero_pad_flat(p, sz, chunk), (my * chunk,), (chunk,))
-            # Per-shard chunked state rows arrive as (1, chunk); scalars
-            # (step counters) replicated as-is.
-            st = {k: (v[0] if v.ndim > 0 else v)
-                  for k, v in state[n].items()}
-            new_chunks[n], new_st = self._update_fn(
-                p_chunk, d_chunks[n].astype(p.dtype), st,
-                **self._resolved_hyper(st))
-            new_state[n] = {k: (v[None] if v.ndim > 0 else v)
-                            for k, v in new_st.items()}
+        with step_scope("update"):
+            for n, p in params.items():
+                sz, chunk = self._zero_meta[n]
+                p_chunk = lax.dynamic_slice(
+                    self._zero_pad_flat(p, sz, chunk), (my * chunk,),
+                    (chunk,))
+                # Per-shard chunked state rows arrive as (1, chunk); scalars
+                # (step counters) replicated as-is.
+                st = {k: (v[0] if v.ndim > 0 else v)
+                      for k, v in state[n].items()}
+                new_chunks[n], new_st = self._update_fn(
+                    p_chunk, d_chunks[n].astype(p.dtype), st,
+                    **self._resolved_hyper(st))
+                new_state[n] = {k: (v[None] if v.ndim > 0 else v)
+                                for k, v in new_st.items()}
         # Untiled gather -> (world, chunk) leaves; the flatten restores the
         # tiled (world*chunk,) layout the de-pad slice expects.
-        gathered = collectives.allgather_tree_bucketed(
-            new_chunks, self.axis, bucket_bytes=self.bucket_bytes)
-        new_params = OrderedDict(
-            (n, gathered[n].reshape(-1)[:self._zero_meta[n][0]]
-             .reshape(p.shape))
-            for n, p in params.items())
+        with step_scope("exchange"):
+            gathered = collectives.allgather_tree_bucketed(
+                new_chunks, self.axis, bucket_bytes=self.bucket_bytes)
+            new_params = OrderedDict(
+                (n, gathered[n].reshape(-1)[:self._zero_meta[n][0]]
+                 .reshape(p.shape))
+                for n, p in params.items())
         return new_params, new_state
 
     def compile_step(self, loss_fn: Callable, *, has_aux: bool = False,
@@ -857,8 +879,7 @@ class MPI_PS:
         self._loss_fn = loss_fn  # raw: wrapping happens at build time only
         self._remat = remat
         self._has_aux = has_aux
-        self._warm = False  # next step's dispatch time is trace+compile
-        self._step_programs = {}
+        self._step_programs = {}    # the next step's dispatch compiles
         if aux is not None:
             rep = replicated(self.mesh)
             # copy=True for the same donation-aliasing reason as params.
@@ -904,59 +925,68 @@ class MPI_PS:
         if batch is None:
             raise ValueError("step() needs a batch")
 
+        if self._byte_metrics is None:   # fixed by the shapes and the codec
+            self._byte_metrics = self._static_byte_metrics()
         data: dict[str, float] = {k: 0.0 for k in STEP_METRIC_KEYS}
-        data.update(self._static_byte_metrics())
-        batch = self._shard_batch(batch)
+        data.update(self._byte_metrics)
+        # The host path in spans (`utils.timing.span`; PERF.md has the
+        # table): the dict's times are the spans' durations, a second view
+        # of the same clock reads.
+        with span("sync.step", step=self.steps_completed, block=block):
+            with span("sync.shard_batch"):
+                batch = self._shard_batch(batch)
 
-        if closure is not None:  # API parity with `ps.py:110-112`
-            closure()
+            if closure is not None:  # API parity with `ps.py:110-112`
+                closure()
 
-        args = (self.params, self.state, self.aux, batch) + (
-            (self.extras,) if self.extras else ())
-        start = time.perf_counter()
-        out = self._step_program(args, batch)(*args)
-        dispatch = time.perf_counter() - start
-        del args    # donated: the new values are in `out`
-        if self._has_counters:
-            *out, counters = out
-            counter_log().append("MPI_PS.step", self.steps_completed,
-                                 counters)
-        if not self._warm:
-            # First call traces+compiles the SPMD program; that one-time
-            # cost is the TPU analogue of the reference's collective
-            # "prepare" (`ps.py:140`) — keep it out of isend_time so the
-            # per-step dispatch metric stays meaningful.
-            data["iallgather_prepare_time"] = dispatch
-            self._warm = True
-        else:
-            data["isend_time"] = dispatch
-        # Reassign BEFORE blocking: the dispatch donated the old
-        # params/state buffers, so between dispatch and reassignment
-        # `self.params` points at deleted arrays — and block_until_ready
-        # is where nearly all step wall-time is spent.  Holding the NEW
-        # futures during the wait means an interrupt-triggered
-        # state_dict() (Ctrl-C checkpointing) always sees live buffers.
-        if self.extras:
-            (self.params, self.state, self.aux, loss, skipped,
-             self.extras) = out
-        else:
-            self.params, self.state, self.aux, loss, skipped = out
-        self.steps_completed += 1
-        if self._count_fused_sync:
-            self.fault_stats["fused_sync_encodes"] += 1
-        if block:
-            start = time.perf_counter()
-            jax.block_until_ready(out)
-            data["comm_wait"] = time.perf_counter() - start
-            # Only when synced: with block=False the flag is still a
-            # device future, and storing a live array would break the
-            # dict[str, float] timings contract (and pin the buffer).
-            data["nonfinite_skip"] = float(skipped)
-            loss = float(loss)
-        # Consensus cadence AFTER the step's reassignments: the fingerprint
-        # program reads (does not donate) the new params, so it composes
-        # with async dispatch — though a firing check does synchronize.
-        self._maybe_check_consensus(data)
+            args = (self.params, self.state, self.aux, batch) + (
+                (self.extras,) if self.extras else ())
+            with span("sync.dispatch") as dispatch:
+                program, compiled = self._step_program(args, batch)
+                out = program(*args)
+            del args    # donated: the new values are in `out`
+            if self._has_counters:
+                *out, counters = out
+                counter_log().append("MPI_PS.step", self.steps_completed,
+                                     counters)
+            if compiled:
+                # This call lowered and compiled the SPMD program; that
+                # one-time cost is the TPU analogue of the reference's
+                # collective "prepare" (`ps.py:140`) — keep it out of
+                # isend_time so the per-step dispatch metric stays
+                # meaningful.
+                dispatch.set(compiled=True)
+                data["iallgather_prepare_time"] = dispatch.duration
+            else:
+                data["isend_time"] = dispatch.duration
+            # Reassign BEFORE blocking: the dispatch donated the old
+            # params/state buffers, so between dispatch and reassignment
+            # `self.params` points at deleted arrays — and block_until_ready
+            # is where nearly all step wall-time is spent.  Holding the NEW
+            # futures during the wait means an interrupt-triggered
+            # state_dict() (Ctrl-C checkpointing) always sees live buffers.
+            if self.extras:
+                (self.params, self.state, self.aux, loss, skipped,
+                 self.extras) = out
+            else:
+                self.params, self.state, self.aux, loss, skipped = out
+            self.steps_completed += 1
+            if self._count_fused_sync:
+                self.fault_stats["fused_sync_encodes"] += 1
+            if block:
+                with span("sync.block") as wait:
+                    jax.block_until_ready(out)
+                data["comm_wait"] = wait.duration
+                # Only when synced: with block=False the flag is still a
+                # device future, and storing a live array would break the
+                # dict[str, float] timings contract (and pin the buffer).
+                data["nonfinite_skip"] = float(skipped)
+                loss = float(loss)
+            # Consensus cadence AFTER the step's reassignments: the
+            # fingerprint program reads (does not donate) the new params, so
+            # it composes with async dispatch — though a firing check does
+            # synchronize.
+            self._maybe_check_consensus(data)
         self.timings.append(data)
         return loss, data
 
@@ -969,15 +999,17 @@ class MPI_PS:
         `jax.named_scope` each HLO instruction was traced, and a device
         trace names an operation by its instruction only (see
         `utils.timing.program_scopes`).  The registry is given the
-        program's `as_text`, not this object: it holds no parameters."""
+        program's `as_text`, not this object: it holds no parameters.
+        Returns the program and whether this call compiled it."""
         leaves, tree = jax.tree.flatten(batch)
         key = (tree, tuple((x.shape, x.dtype) for x in leaves))
         program = self._step_programs.get(key)
-        if program is None:
+        compiled = program is None
+        if compiled:
             program = self._step_fn.lower(*args).compile()
             self._step_programs[key] = program
             register_program("MPI_PS.step", program.as_text)
-        return program
+        return program, compiled
 
     # -- replica-consensus SDC guard -----------------------------------------
 

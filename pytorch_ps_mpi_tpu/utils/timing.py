@@ -23,6 +23,7 @@ import time
 from collections import deque
 from typing import Any
 
+import jax
 from jax.profiler import TraceAnnotation
 
 # Canonical metric keys, matching the reference step() dict (`ps.py:193`).
@@ -622,6 +623,13 @@ _OP_NAME = re.compile(
 # `metadata=`: its ``kernel_metadata={`` is printed over three lines, the
 # ``op_name`` on the last.  Such continuation lines start with ``"`` or ``}``.
 _CONTINUATION = re.compile(r'\n(?=["}])')
+# A computation's header, at the left margin (instructions are indented), and
+# a fusion instruction with the computation it calls.
+_COMPUTATION = re.compile(r'^(?:ENTRY\s+)?%?([\w.\-]+) \([^\n]*\{$',
+                          re.MULTILINE)
+_FUSION = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*\bfusion\([^\n]*'
+    r'\bcalls=%?([\w.\-]+)', re.MULTILINE)
 
 
 def register_program(name: str, text_fn) -> None:
@@ -630,7 +638,28 @@ def register_program(name: str, text_fn) -> None:
     newest registration under a name wins; it is kept, and with it the
     compiled program (not its arguments), until the next one."""
     with _PROGRAMS_LOCK:
-        _PROGRAMS[name] = {"text_fn": text_fn, "scopes": None}
+        _PROGRAMS[name] = {"text_fn": text_fn, "parsed": None}
+
+
+def _parsed(name: str) -> "dict | None":
+    """The registered program's text, read once: ``scopes`` for
+    `program_scopes`, ``fusions`` for `program_fusions`."""
+    with _PROGRAMS_LOCK:
+        entry = _PROGRAMS.get(name)
+    if entry is None:
+        return None
+    if entry["parsed"] is None:
+        text = _CONTINUATION.sub(" ", entry["text_fn"]())
+        # name, body, name, body, ...: a computation's instructions follow
+        # its header up to the next header
+        pieces = _COMPUTATION.split(text)[1:]
+        bodies = {comp: tuple(n for n, _ in _OP_NAME.findall(body))
+                  for comp, body in zip(pieces[::2], pieces[1::2])}
+        entry["parsed"] = {
+            "scopes": dict(_OP_NAME.findall(text)),
+            "fusions": {n: bodies.get(comp, ())
+                        for n, comp in _FUSION.findall(text)}}
+    return entry["parsed"]
 
 
 def program_scopes(name: str) -> "dict[str, str] | None":
@@ -639,14 +668,20 @@ def program_scopes(name: str) -> "dict[str, str] | None":
     under, as in ``jit(step)/.../kda/while/body/dot_general`` — or None
     where no such program is registered.  The first call fetches the text
     and parses it; the result is kept."""
-    with _PROGRAMS_LOCK:
-        entry = _PROGRAMS.get(name)
-    if entry is None:
-        return None
-    if entry["scopes"] is None:
-        text = _CONTINUATION.sub(" ", entry["text_fn"]())
-        entry["scopes"] = dict(_OP_NAME.findall(text))
-    return entry["scopes"]
+    parsed = _parsed(name)
+    return None if parsed is None else parsed["scopes"]
+
+
+def program_fusions(name: str) -> "dict[str, tuple] | None":
+    """``{fusion instruction: the instructions XLA fused into it}`` of the
+    program registered under ``name`` (those with an ``op_name``: look each
+    up in `program_scopes`), or None where no such program is registered.  A
+    device trace shows a fusion as ONE operation under its root's
+    ``op_name``, whatever else the compiler put inside: an optimizer's rule
+    fused into the matrix product that makes its gradient runs under the
+    product's name.  This is how a reader tells."""
+    parsed = _parsed(name)
+    return None if parsed is None else parsed["fusions"]
 
 
 def in_scope(op_name: str, scope: str) -> bool:
@@ -654,6 +689,59 @@ def in_scope(op_name: str, scope: str) -> bool:
     bare or wrapped by a transformation (``transpose(jvp(kda))``)."""
     return re.search(rf"(?:^|[/(]){re.escape(scope)}(?:[/)]|$)",
                      op_name) is not None
+
+
+# The scopes `MPI_PS.step` puts round the phases of its own program
+# (`ps.py:_make_spmd_step`, `parallel/overlap.py`), in the order `step_phase`
+# asks for them: a scope of the step's own beats the transformation wrappers
+# round it (the bucket hooks of ``sync_mode="overlap"`` run their sums inside
+# the backward), and ``ps.grad`` comes last.  The names hold a dot so that no
+# model scope or flax module is called the same.  (A codec's encode and
+# decode and the non-finite guard have no scope: no cell runs them, so no
+# metric would read one.)
+STEP_SCOPES = {"exchange": "ps.exchange", "update": "ps.update",
+               "grad": "ps.grad"}
+# JAX's own words in an ``op_name``: what `jax.checkpoint` calls the forward
+# it runs again inside the backward, and how a transposed (backward)
+# computation is wrapped.  Pinned in tier-1 against a compiled program.
+_REMAT_MARK = "rematted_computation"
+_TRANSPOSE_MARK = "transpose("
+
+
+def step_scope(phase: str):
+    """The `jax.named_scope` of one phase of the fused step (a key of
+    `STEP_SCOPES`): metadata on the operations traced inside it, nothing
+    else."""
+    return jax.named_scope(STEP_SCOPES[phase])
+
+
+def step_phase(op_name: str) -> "str | None":
+    """The phase of `MPI_PS.step`'s program that an instruction with this
+    ``op_name`` belongs to: ``"exchange"`` or ``"update"`` under the step's
+    scope of that name; else, under ``ps.grad``, ``"remat"`` (forward work
+    done a second time inside the backward), ``"backward"`` or
+    ``"forward"``; None under none of them."""
+    for phase, scope in STEP_SCOPES.items():
+        if not in_scope(op_name, scope):
+            continue
+        if phase != "grad":
+            return phase
+        if _REMAT_MARK in op_name:
+            return "remat"
+        return "backward" if _TRANSPOSE_MARK in op_name else "forward"
+    return None
+
+
+class BoundedList(list):
+    """The per-step dicts of a long run (`MPI_PS.timings`): a list in every
+    way (``len``, slices, iteration) that holds at most `SPAN_LOG_CAPACITY`
+    records.  An append to a full list first drops the oldest sixteenth, so
+    the cost of dropping is spread over thousands of steps."""
+
+    def append(self, record) -> None:
+        if len(self) >= SPAN_LOG_CAPACITY:
+            del self[:max(1, SPAN_LOG_CAPACITY // 16)]
+        super().append(record)
 
 
 def print_summary(timings: list[dict[str, Any]], keys=None) -> None:

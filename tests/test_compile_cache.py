@@ -1,7 +1,7 @@
 """The one compile-cache helper every entry point uses
 (`utils.compile_cache.configure_compile_cache`): placed from outside when
-``JAX_COMPILATION_CACHE_DIR`` is set — nothing is set in code — and at one
-fixed, git-ignored directory inside the checkout otherwise."""
+``JAX_COMPILATION_CACHE_DIR`` is set — no directory is set in code — and at
+one fixed, git-ignored directory inside the checkout otherwise."""
 
 import os
 import subprocess
@@ -17,6 +17,12 @@ _PROBE = (
     "jax.config.update=lambda k,v: (calls.append(k), orig(k,v))[1];"
     "d=cc.configure_compile_cache();"
     "print(d); print(jax.config.jax_compilation_cache_dir); print(calls)")
+
+
+# What a program is keyed by is set wherever the cache lies: the scope names
+# are in the key, the Python frames are not (`KEYED_WITH_SCOPES`).
+KEY_POLICY = ("['jax_compilation_cache_include_metadata_in_key', "
+              "'jax_traceback_in_locations_limit']")
 
 
 def _run(env_dir):
@@ -35,14 +41,14 @@ def test_env_set_means_nothing_is_set_in_code(tmp_path):
     want = str(tmp_path / "outside")
     returned, in_effect, calls = _run(want)
     assert returned == want and in_effect == want
-    assert calls == "[]"        # jax read the env itself
+    assert calls == KEY_POLICY          # jax read the env's directory itself
 
 
 def test_env_unset_means_the_fixed_in_checkout_directory():
     returned, in_effect, calls = _run(None)
     fixed = os.path.join(REPO, ".jax_cache")
     assert returned == fixed and in_effect == fixed
-    assert calls == "['jax_compilation_cache_dir']"
+    assert calls == KEY_POLICY[:-1] + ", 'jax_compilation_cache_dir']"
     # git would not commit it.
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
