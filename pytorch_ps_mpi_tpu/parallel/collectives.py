@@ -225,14 +225,20 @@ def _bucketed_leafwise(tree: Tree, collective, bucket_bytes: int,
             out[i] = res.reshape(res.shape[:-1] + shape)
             continue
         flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
-        res = collective(flat)
-        off = 0
-        for i in idxs:
-            n = leaves[i].size
-            seg = res[..., off:off + n]
-            out[i] = seg.reshape(seg.shape[:-1] + leaves[i].shape)
-            off += n
+        _unpack(out, leaves, idxs, collective(flat))
     return jax.tree.unflatten(treedef, out)
+
+
+def _unpack(out: list, leaves, idxs, res) -> None:
+    """Slice each leaf's segment of a packed bucket's result ``res`` back
+    out of its last axis into ``out``, in the leaf's shape (keeping any
+    leading dims the collective grew)."""
+    off = 0
+    for i in idxs:
+        n = leaves[i].size
+        seg = res[..., off:off + n]
+        out[i] = seg.reshape(seg.shape[:-1] + leaves[i].shape)
+        off += n
 
 
 def _axis_world(axis) -> int:
@@ -248,16 +254,19 @@ def _allreduce_rs_ag(x, axis, world: int):
     """All-reduce one flat array as explicit reduce-scatter + all-gather.
 
     Mathematically the same cross-rank sum as ``lax.psum`` (an all-reduce
-    IS rs+ag on the wire), but expressed as two HLO collectives per
-    bucket so XLA's async scheduler can pipeline them against compute.
-    The motivation: XLA's all-reduce combiner merges every psum bucket
-    into ONE end-of-backward tuple all-reduce and PJRT exposes no
-    combiner-threshold knob, which serializes the whole exchange after
-    the last gradient; the combiner leaves rs+ag pairs alone, so they
-    stay one per bucket in the compiled schedule (whether that hides
-    them on the chip is not measured).  This realizes the reference's
-    per-parameter pipelining intent
-    (`/root/reference/ps.py:125-127,140-147`) for the identity/psum path."""
+    IS rs+ag on the wire), expressed as two HLO collectives per bucket in
+    the hope that XLA's scheduler would pipeline them against compute (the
+    reference's per-parameter pipelining intent,
+    `/root/reference/ps.py:125-127,140-147`).  What the v5e's compiler makes
+    of it (compiles for a described v5e:2x2, PERF.md §6 PRs 37 and 39): the
+    reduce-scatters are rewritten to synchronous all-reduces and the
+    all-gathers, synchronous too, are placed after the backward.  XLA's
+    combiner does not merge every psum into one end-of-backward all-reduce
+    either, as this text used to say: it makes twelve of GPT-2's and places
+    each where its last operand is made (PR 37).  `_allreduce_ring` is the
+    lowering that is in flight beside the backward; this one is kept for
+    ``decompose_allreduce`` and ``sync_mode="overlap"`` until a
+    `simplicity` issue decides (ROADMAP D2)."""
     n = x.size
     pad = (-n) % world
     if pad:
@@ -267,10 +276,178 @@ def _allreduce_rs_ag(x, axis, world: int):
     return full[:n] if pad else full
 
 
+
+# ---------------------------------------------------------------------------
+# The bucketed sum as a ring of collective-permute hops
+# ---------------------------------------------------------------------------
+#
+# On the v5e an ``all-reduce`` runs on the one core and stops it: the
+# compiled step holds no ``-start`` / ``-done`` pair for it under any compiler
+# option tried (PERF.md §6, PR 39), and `gpt2m-sync-dp4` spent 28.4 ms of a
+# 201.6 ms step in twelve of them with nothing beside (ledger, PR 37).  A
+# ``collective-permute`` is asynchronous there, so the same sum spelt as a
+# ring of `lax.ppermute` hops is in flight while the backward's fusions run.
+# `MPI_PS` takes this lowering where it sees several TPU chips on its one
+# data axis (`ps.MPI_PS._exchange_ring`); the CPU and one chip keep
+# ``lax.psum`` and the program they had.
+
+# A leaf over this is cut along its rows into pieces of at most this size,
+# one ring each: the ring's chunks in flight are a few MB whatever the leaf.
+_RING_PIECE_BYTES = 32 << 20
+# The bucket that the backward makes first keeps XLA's all-reduce if it is
+# over this.  For a language model that is the head's gradient (206 MB in
+# GPT-2), made beside the loss where the step's memory peaks and laid out
+# column-major, so that its row slices are relayouts: with it on the ring
+# `gpt2m-sync-dp4`'s step took 219.3 ms against 196.0 and the step program's
+# temporaries 10.91 GB against 9.62 (my chip run, PR 39; the all-reduce
+# form's 9.54), and `peak_hbm_gib` is bounded at 1 %.
+_RING_FIRST_MAX_BYTES = 64 << 20
+
+
+def _ring_unit(shape, dtype, world: int) -> int:
+    """Rows (of axis 0) that a ring's ``2 * world`` chunks are cut in
+    multiples of, so that every chunk is whole tiles of the array as the TPU
+    lays it out (a row slice is then no relayout): 8 sublanes of 32 bits for
+    a matrix, 8 x 128 elements for a vector, any row for more dimensions
+    (the tiles are the last two)."""
+    packed = max(1, 4 // jnp.dtype(dtype).itemsize)
+    tile = {1: 1024 * packed, 2: 8 * packed}.get(len(shape), 1)
+    return 2 * world * tile
+
+
+def _ring_turn(axis, world: int):
+    """``turn[k]`` is the rank ``k`` places on from this one, for ``k`` in
+    ``0 .. world - 1``: the one piece of index arithmetic every hop of every
+    bucket shares, made once a step."""
+    return (lax.axis_index(axis)
+            + jnp.arange(world, dtype=jnp.int32)) % world
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _allreduce_ring(x, turn, axis, world: int):
+    """All-reduce one array as rings of `lax.ppermute` hops: the same
+    cross-rank sum as ``lax.psum`` up to the order of the additions.
+
+    The array is cut along its rows (axis 0, in its own shape: flattening a
+    matrix is a relayout on the TPU) into pieces of at most
+    `_RING_PIECE_BYTES`, each piece into two halves that travel in opposite
+    senses, so that both directions of every link carry a share, and each
+    half into ``world`` chunks: ``world - 1`` hops in which a rank passes a
+    chunk on and adds its own to what arrives (the reduce-scatter), then
+    ``world - 1`` hops that pass the finished chunks round (the all-gather),
+    each written over the input's own rows.  Every rank therefore holds the
+    one sum that one rank computed: replicas stay bitwise equal.  The rows
+    past the last whole chunk (`_ring_unit`; all of a small array) are summed
+    by ``lax.psum``.  ``turn`` is `_ring_turn`'s vector.
+
+    Applied to gradients and never differentiated, so it has no
+    ``custom_vjp``.  Under `jax.jit`: a model's layers share their shapes,
+    so the hops are traced and lowered once a shape and not once a leaf
+    (GPT-2 medium: 6 bodies for 99 buckets; spelt out a leaf they were
+    26,000 lines of StableHLO and 7.8 s of every set-up, warm or cold:
+    PERF.md §6, PR 39).  A module constant read in here is frozen into the
+    first trace of a shape."""
+    rows = x.shape[0] if x.ndim else 0
+    unit = _ring_unit(x.shape, x.dtype, world)
+    if world == 1 or rows < unit:
+        return lax.psum(x, axis)
+    row_bytes = x.size // rows * x.dtype.itemsize
+    piece = max(unit, _RING_PIECE_BYTES // (row_bytes * unit) * unit)
+    out, lo = x, 0
+    while rows - lo >= unit:
+        n = min(piece, (rows - lo) // unit * unit)
+        chunk = n // (2 * world)
+        for half, sense in enumerate((1, -1)):
+            perm = [(i, (i + sense) % world) for i in range(world)]
+
+            def row(k, at=lo + half * (n // 2), sense=sense):
+                """The first row of the chunk that is ``k`` places along
+                this half's sense from the rank's own."""
+                return at + turn[sense * k % world] * chunk
+
+            carry = lax.dynamic_slice_in_dim(x, row(0), chunk, 0)
+            for hop in range(1, world):
+                carry = (lax.ppermute(carry, axis, perm)
+                         + lax.dynamic_slice_in_dim(x, row(-hop), chunk, 0))
+            # carry is the finished chunk ``world - 1`` places back
+            for hop in range(world - 1, 2 * world - 1):
+                out = lax.dynamic_update_slice_in_dim(
+                    out, carry, row(-hop), 0)
+                if hop < 2 * world - 2:
+                    carry = lax.ppermute(carry, axis, perm)
+        lo += n
+    if lo < rows:
+        # last, like the chunks: a write that is the first thing done to
+        # ``x`` is fused into what makes ``x`` and copies the whole of it
+        out = lax.dynamic_update_slice_in_dim(
+            out, lax.psum(x[lo:], axis), lo, 0)
+    return out
+
+
+def _made_order(leaves) -> "list[int]":
+    """For each leaf a number that sorts the leaves in the order in which
+    the program makes them, where they are values of a trace in progress
+    (each carries the count at which the trace made its variable: a gradient
+    of the head has a lower one than a gradient of the embedding); the
+    reverse of the order they came in where they say nothing (concrete
+    arrays, another JAX): a backward pass makes the gradients of the
+    parameters used last first."""
+    counts = [getattr(getattr(x, "val", None), "count", None) for x in leaves]
+    if all(isinstance(c, int) for c in counts):
+        return counts
+    return [-i for i in range(len(leaves))]
+
+
+def _ring_tree(tree: Tree, axis, world: int, bucket_bytes: int,
+               solo_bytes: int) -> Tree:
+    """`psum_tree_bucketed` through `_allreduce_ring`: a leaf that is a
+    bucket of its own rides in its own shape, the small ones concatenated.
+
+    **The buckets go one after another, in the order the backward makes
+    them** (`_made_order`), each held back (`lax.optimization_barrier`) until
+    the one before it is summed.  Nothing in the arithmetic asks for that;
+    the scheduler does: it works from the end of the program back, finds
+    every bucket's hops ready there, beside the optimizer's update, and
+    places them all behind the backward, where nothing hides them.  Chained,
+    only the last bucket's hops are ready at the end, and each earlier
+    bucket's come up for placement together with the backward work that
+    makes the next.  The first bucket keeps the all-reduce if it is over
+    `_RING_FIRST_MAX_BYTES`."""
+    leaves, treedef = jax.tree.flatten(tree)
+    made = _made_order(leaves)
+    plan = sorted(_plan_buckets(leaves, bucket_bytes, solo_bytes),
+                  key=lambda idxs: max(made[i] for i in idxs))
+    out: list[Any] = [None] * len(leaves)
+    turn = _ring_turn(axis, world)
+
+    def keep(idxs, summed):
+        if len(idxs) == 1:
+            out[idxs[0]] = summed
+        else:
+            _unpack(out, leaves, idxs, summed)
+
+    before = None
+    for idxs in plan:
+        bucket = (leaves[idxs[0]] if len(idxs) == 1 else
+                  jnp.concatenate([leaves[i].reshape(-1) for i in idxs]))
+        if (idxs is plan[0] and bucket.size * bucket.dtype.itemsize
+                > _RING_FIRST_MAX_BYTES):
+            keep(idxs, lax.psum(bucket, axis))
+            continue
+        if before is not None:
+            bucket, done = lax.optimization_barrier((bucket, before[1]))
+            keep(before[0], done)
+        before = (idxs, _allreduce_ring(bucket, turn, axis, world))
+    if before is not None:
+        keep(*before)
+    return jax.tree.unflatten(treedef, out)
+
+
 def psum_tree_bucketed(tree: Tree, axis: str = PS_AXIS, *,
                        bucket_bytes: "int | None" = DEFAULT_BUCKET_BYTES,
                        decompose: bool = False,
-                       solo_bytes: "int | None" = None) -> Tree:
+                       solo_bytes: "int | None" = None,
+                       ring: bool = False) -> Tree:
     """`psum_tree` with dtype-bucketed flat all-reduces — the same
     elementwise sum (bitwise-equal on the tested CPU backend; cross-rank
     reduction order on TPU is backend-scheduled, see module comment),
@@ -278,16 +455,30 @@ def psum_tree_bucketed(tree: Tree, axis: str = PS_AXIS, *,
     ``bucket_bytes=None``/0 is the per-leaf lowering (one dispatch point:
     call sites pass their knob through unconditionally).
     ``decompose=True`` lowers each bucket as reduce-scatter + all-gather
-    instead of one all-reduce (see `_allreduce_rs_ag`): same sum, but the
-    collectives stay per-bucket in the compiled schedule instead of being
-    combined into one end-of-backward tuple op, restoring comm/compute
-    overlap for this path.
+    instead of one all-reduce (see `_allreduce_rs_ag`): same sum, two
+    collectives a bucket.
+    ``ring=True`` (internal: `MPI_PS` sets it from what it sees of its
+    mesh, no caller passes it by hand) sums each bucket through
+    `_allreduce_ring`'s collective-permute hops instead (`_ring_tree`).
+    **Which lowering runs where, and why**: ``lax.psum`` on the CPU, on one
+    chip and wherever a caller asks for nothing else: one all-reduce a
+    bucket, which XLA's combiner regroups (twelve for GPT-2 medium) and the
+    v5e runs synchronously on its one core, each where its last operand is
+    made; the ring on several TPU chips, because a collective-permute is
+    the one collective the v5e's compiler keeps in flight beside the
+    backward (an asynchronous all-reduce is made and turned back, an
+    all-gather and a reduce-scatter are synchronous: PERF.md §6, PR 39);
+    ``decompose`` never by default (its reduce-scatters become all-reduces
+    again there).
     ``solo_bytes`` (None = auto, ``bucket_bytes // 16``; 0 = legacy
     pack-everything): leaves at/above the threshold skip the shared
     bucket and sum solo — the concat-in/slice-out memcpy around a leaf
     that already amortizes its collective is pure overhead (measured
     ~2x the whole step on the w8 gradsync payload; same bitwise sum
     either way, see `_plan_buckets`)."""
+    if ring and bucket_bytes:
+        return _ring_tree(tree, axis, _axis_world(axis), bucket_bytes,
+                          _solo_default(bucket_bytes, solo_bytes))
     if not bucket_bytes:
         if decompose:  # per-leaf rs+ag: the per-param lowering still
             # deserves the overlap effect the flag documents

@@ -35,6 +35,21 @@ Two reducers for the identity path:
   no combiner pathology (the virtual-CPU test mesh), and still issued
   inside backward.
 
+**Which lowering runs where** (PERF.md §6, PRs 32, 37 and 39).  This engine
+is ``sync_mode="overlap"``, which no default selects.  On the CPU mesh of the
+tests it is what the paragraphs above say.  On the v5e it buys nothing as it
+lowers today: the compiler rewrites the reduce-scatters to all-reduces, runs
+every all-reduce synchronously on the one core wherever it is placed (PR 32
+made this engine the default and `gpt2m-sync-dp4` moved 38,771 → 38,773
+tokens/s/chip), and places the all-gathers, synchronous too, behind the
+backward.  What does run beside the backward there is a
+``collective-permute``: the default ``sync_mode="bucketed"`` therefore sums
+each bucket through a ring of `lax.ppermute` hops where `MPI_PS` sees several
+TPU chips on its data axis (`collectives._ring_tree`,
+`ps.MPI_PS._exchange_ring`), and through ``lax.psum`` everywhere else.  The
+hooks here stay for the codec paths and until a `simplicity` issue decides
+(ROADMAP D2).
+
 The bucket-size knob trades schedule granularity against per-collective
 efficiency; ``auto_bucket_bytes`` picks it from the payload, the world size
 and the v5e's published HBM bandwidth, and every constructed plan is

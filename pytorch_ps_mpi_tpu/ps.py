@@ -21,8 +21,10 @@ decode-sum, update — is **one jitted SPMD program** over a
   (`bucket_mb`, `parallel/collectives.py`) into a few large flat transfers
   (a handful of collectives where the per-parameter lowering has 130 for
   ResNet-18), which XLA's scheduler may run beside the backward pass — the
-  thread pool's overlap, left to the compiler; on the chip the default
-  lowering's all-reduces are all exposed (PERF.md, `gpt2m-sync-dp4`);
+  thread pool's overlap, left to the compiler.  On the chip it does so for
+  collective-permutes and not for all-reduces, so on several TPU chips each
+  bucket's sum is a ring of hops (`MPI_PS._exchange_ring`; PERF.md,
+  `gpt2m-sync-dp4`);
 * the ``Iallgather``-of-sizes protocol (`ps.py:140-147`) existed because
   pickled payloads have unknown sizes; codec outputs have static shapes, so
   gradient exchange is a single ``all_gather`` (or, for the identity codec, a
@@ -228,14 +230,15 @@ class MPI_PS:
             raise ValueError(f"bucket_mb must be >= 0, got {bucket_mb}")
         self.bucket_bytes = (int(bucket_mb * (1 << 20))
                              if bucket_mb else None)
-        # Identity-path overlap knob: XLA's all-reduce combiner merges all
-        # psum buckets into ONE end-of-backward tuple all-reduce (no PJRT
-        # threshold knob exists), serializing the exchange after the last
-        # gradient.  With ``decompose_allreduce=True`` each bucket lowers
-        # as explicit reduce-scatter + all-gather (the same sum an
-        # all-reduce performs on the wire), which the combiner leaves
-        # per-bucket so the async scheduler can overlap them with backward
-        # compute.  Not measured on the chip.
+        # Identity-path knob from before the chip had spoken: each bucket as
+        # explicit reduce-scatter + all-gather in place of one all-reduce.
+        # What the v5e's compiler does with the default (PERF.md §5 (4),
+        # PR 37): its combiner makes twelve all-reduces of GPT-2's 1.6 GB
+        # and its scheduler places each where its last operand is made, but
+        # each is synchronous and the core runs nothing beside it.  On
+        # several TPU chips the default therefore takes `_exchange_ring`'s
+        # lowering; this knob keeps its rs+ag form (never timed on the
+        # chip) until a `simplicity` issue decides (ROADMAP D2).
         self.decompose_allreduce = bool(decompose_allreduce)
         # WHEN the cross-rank gradient sum happens (`parallel/overlap.py`):
         #   "post"     — after backward, one collective per parameter (the
@@ -587,6 +590,22 @@ class MPI_PS:
                 loss = lax.pmean(loss, self.extra_axes)
         return loss, grads, new_aux
 
+    def _exchange_ring(self) -> bool:
+        """Which lowering the identity codec's bucketed gradient sum takes,
+        from what can be seen of the mesh when the step is traced: the ring
+        of asynchronous hops (`collectives._ring_tree`) where the default
+        exchange runs over several TPU chips on one data axis, XLA's
+        all-reduce, and the program it always made, everywhere else (the
+        CPU, one chip, ``zero``, ``decompose_allreduce``, another
+        ``sync_mode``, a codec).  On the v5e a bucket's `lax.psum` compiles
+        to a synchronous ``all-reduce`` that stops the core (28.4 ms of a
+        201.6 ms step in `gpt2m-sync-dp4`, ledger PR 37); the ring's hops
+        run beside the backward (PERF.md §6, PR 39)."""
+        return (isinstance(self.code, IdentityCodec) and len(self.axes) == 1
+                and self.world_size > 1 and self.sync_mode == "bucketed"
+                and not self.zero and not self.decompose_allreduce
+                and all(d.platform == "tpu" for d in self.mesh.devices.flat))
+
     def _summed_grads(self, grads):
         """Cross-rank gradient sum, full tensors: the identity codec fuses
         to bucketed all-reduces; codecs ride all_gather + fused decode-sum."""
@@ -594,7 +613,8 @@ class MPI_PS:
             with step_scope("exchange"):
                 return collectives.psum_tree_bucketed(
                     grads, self.axis, bucket_bytes=self.bucket_bytes,
-                    decompose=self.decompose_allreduce)
+                    decompose=self.decompose_allreduce,
+                    ring=self._exchange_ring())
         meta = {n: (g.shape, g.dtype) for n, g in grads.items()}
         codes = self._encode_all(grads)
         return self._sync_codes(codes, meta)

@@ -397,3 +397,203 @@ def test_plan_buckets_groups_by_dtype_and_caps_bytes():
     assert [3] in plan
     # Deterministic: same input, same plan.
     assert plan == _plan_buckets(leaves, bucket_bytes=1500)
+
+
+# ---------------------------------------------------------------------------
+# The ring of ppermute hops that takes a bucket's all-reduce on several TPU
+# chips (`_allreduce_ring`, `_ring_tree`): platform-blind, so it runs here
+# ---------------------------------------------------------------------------
+
+
+def _ring_and_psum(world, shape, values):
+    """``values`` is ``[world, *shape]``, row r rank r's addend; returns the
+    ring's sum and `lax.psum`'s, each ``[world, *shape]`` (one row a
+    replica)."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
+
+    def body(v):
+        v = v[0]
+        turn = C._ring_turn("ps", world)
+        return (C._allreduce_ring(v, turn, "ps", world)[None],
+                lax.psum(v, "ps")[None])
+
+    f = jax.jit(jax.shard_map(
+        body, mesh=make_ps_mesh(world), in_specs=P("ps"),
+        out_specs=(P("ps"), P("ps")), check_vma=False))
+    return map(np.asarray, f(values))
+
+
+# whole chunks only; whole chunks and a tail that `lax.psum` takes; a matrix
+# cut along its rows, with and without a tail; too small for one chunk; more
+# dimensions; a scalar
+RING_SHAPES = [(2 * 4 * 1024 * 3,), (2 * 4 * 1024 + 37,), (128, 24),
+               (131, 5), (3,), (16, 2, 3), ()]
+
+
+@pytest.mark.parametrize("shape", RING_SHAPES, ids=str)
+@pytest.mark.parametrize("world", [2, 4])
+def test_ring_allreduce_is_the_sum_and_the_same_on_every_replica(
+        world, shape):
+    """Against `lax.psum`: close to rounding on random f32 (the additions
+    come in another order), exactly equal where the addends are small
+    integers, and **bitwise equal across the replicas** either way (every
+    rank holds the one sum that one rank computed), whether or not the rows
+    divide by the chunks."""
+    rng = np.random.RandomState(len(shape) + world)
+    ring, psum = _ring_and_psum(
+        world, shape, (rng.randn(world, *shape) * 100).astype(np.float32))
+    for r in range(1, world):
+        np.testing.assert_array_equal(ring[r], ring[0])
+    np.testing.assert_allclose(ring, psum, rtol=1e-6, atol=1e-4)
+    ring, psum = _ring_and_psum(
+        world, shape,
+        rng.randint(-99, 99, (world,) + shape).astype(np.float32))
+    np.testing.assert_array_equal(ring, psum)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=str)
+def test_ring_allreduce_in_pieces_keeps_the_dtype_and_the_sum(
+        dtype, monkeypatch):
+    """A leaf over `_RING_PIECE_BYTES` goes round in several pieces, one
+    ring each, and a bf16 leaf is cut in multiples of its own (16-row)
+    tiles: the same sum as `lax.psum`, in the leaf's dtype, the same bits on
+    every replica."""
+    monkeypatch.setattr(C, "_RING_PIECE_BYTES", 64 * 24 * 2)
+    rng = np.random.RandomState(7)
+    values = jnp.asarray(rng.randint(-9, 9, (4, 300, 24)), dtype)
+    ring, psum = _ring_and_psum(4, (300, 24), values)
+    assert ring.dtype == psum.dtype == jnp.dtype(dtype)
+    np.testing.assert_array_equal(ring, psum)
+    for r in range(1, 4):
+        np.testing.assert_array_equal(ring[r], ring[0])
+
+
+def _lowered_ring(shape=(64, 8), world=4):
+    from jax.sharding import PartitionSpec as P
+
+    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
+
+    f = jax.jit(jax.shard_map(
+        lambda v: C._allreduce_ring(
+            v[0], C._ring_turn("ps", world), "ps", world)[None],
+        mesh=make_ps_mesh(world), in_specs=P("ps"), out_specs=P("ps"),
+        check_vma=False))
+    return f.lower(jnp.zeros((world,) + shape, jnp.float32)).as_text()
+
+
+def test_ring_hops_are_collective_permutes_both_ways_round():
+    """What makes it worth having: ``2 * (world - 1)`` hops a half, the two
+    halves in opposite senses, and no all-reduce where the rows divide."""
+    text = _lowered_ring()
+    hops = [ln for ln in text.split("\n") if "collective_permute" in ln]
+    assert len(hops) == 12 and "all_reduce" not in text
+    senses = {"[[0, 1], [1, 2], [2, 3], [3, 0]]",
+              "[[0, 3], [1, 0], [2, 1], [3, 2]]"}
+    found = {s for s in senses for ln in hops if s in ln}
+    assert found == senses, hops[0]
+
+
+def _tree_sums(tree, *, ring, bucket_bytes=1 << 14, world=4, lower=False):
+    """`psum_tree_bucketed` of ``tree`` (leaves ``[world, ...]``, row r rank
+    r's) on ``world`` CPU devices, one replica's result; or the lowered
+    text."""
+    from jax.sharding import PartitionSpec as P
+
+    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
+
+    def body(t):
+        t = jax.tree.map(lambda v: jnp.squeeze(v, 0), t)
+        return C.psum_tree_bucketed(t, "ps", bucket_bytes=bucket_bytes,
+                                    ring=ring)
+
+    f = jax.jit(jax.shard_map(body, mesh=make_ps_mesh(world),
+                              in_specs=P("ps"), out_specs=P(),
+                              check_vma=False))
+    return f.lower(tree).as_text() if lower else jax.device_get(f(tree))
+
+
+def _ints(rng, shape, dtype=np.float32):
+    return jnp.asarray(rng.randint(-9, 9, shape), dtype)
+
+
+RING_TREES = {
+    # large leaves ride in their own shape, small ones packed, each dtype in
+    # buckets of its own, rows that do not divide by the chunks
+    "mixed": lambda rng: {"big": _ints(rng, (4, 256, 40)),
+                          "odd": _ints(rng, (4, 67, 3)),
+                          "small": _ints(rng, (4, 7)),
+                          "half": _ints(rng, (4, 4096), jnp.bfloat16),
+                          "half2d": _ints(rng, (4, 130, 40), jnp.bfloat16)},
+    "one_leaf": lambda rng: {"w": _ints(rng, (4, 192, 24))},
+    "one_small_leaf": lambda rng: {"b": _ints(rng, (4, 5))},
+    # nothing to sum, and a leaf of no elements among others
+    "empty": lambda rng: {},
+    "empty_leaf": lambda rng: {"none": jnp.zeros((4, 0, 8), jnp.float32),
+                               "w": _ints(rng, (4, 128, 8))},
+}
+
+
+@pytest.mark.parametrize("name", sorted(RING_TREES))
+def test_psum_bucketed_through_the_ring_matches_the_allreduce(name):
+    """`psum_tree_bucketed(ring=True)` against the same call without: the
+    same tree of sums, shapes and dtypes (small integers, so the order of
+    the additions cannot show)."""
+    tree = RING_TREES[name](np.random.RandomState(3))
+    ref, got = _tree_sums(tree, ring=False), _tree_sums(tree, ring=True)
+    assert jax.tree.structure(ref) == jax.tree.structure(got)
+    for k in ref:
+        assert ref[k].shape == got[k].shape and ref[k].dtype == got[k].dtype
+        np.testing.assert_array_equal(np.asarray(got[k], np.float32),
+                                      np.asarray(ref[k], np.float32))
+
+
+def test_made_order_reads_the_traces_own_counts_and_falls_back():
+    """`_made_order`: gradients come out of a trace in the order the
+    backward makes them (the parameter used last first), whatever order the
+    tree lists them in; values that are no tracers keep the reverse of the
+    order they came in."""
+    def loss(p, x):
+        h = jnp.tanh(x @ p["a"])
+        h = jnp.tanh(h @ p["b"])
+        return jnp.sum(h @ p["c"])
+
+    seen = {}
+
+    def f(p, x):
+        g = jax.grad(loss)(p, x)
+        names = sorted(g)
+        keys = C._made_order([g[n] for n in names])
+        seen["order"] = [n for _, n in sorted(zip(keys, names))]
+        return g
+
+    p = {k: jnp.ones((4, 4)) for k in "abc"}
+    jax.jit(f).lower(p, jnp.ones((2, 4)))
+    assert seen["order"] == ["c", "b", "a"]
+    keys = C._made_order([np.zeros(2), np.zeros(3), np.zeros(4)])
+    assert sorted(range(3), key=keys.__getitem__) == [2, 1, 0]
+
+
+def test_ring_tree_chains_the_buckets_and_shares_one_body_a_shape(
+        monkeypatch):
+    """`_ring_tree`: one barrier between consecutive buckets (the chain that
+    makes the scheduler place each bucket's hops beside the backward work
+    that follows it); a first bucket over `_RING_FIRST_MAX_BYTES` summed by
+    an all-reduce outside the chain; **leaves of one shape share one lowered
+    ring** (twelve hops in the text for three buckets of a shape: what keeps
+    the set-up cost of a deep model flat); and the same sums."""
+    monkeypatch.setattr(C, "_RING_FIRST_MAX_BYTES", 64 * 64 * 4)
+    rng = np.random.RandomState(5)
+    # the trace makes the leaves in the order of their keys (`_made_order`)
+    tree = {"a_huge": _ints(rng, (4, 128, 64)), "b": _ints(rng, (4, 64, 64)),
+            "c": _ints(rng, (4, 64, 64)), "d": _ints(rng, (4, 64, 64))}
+    got = _tree_sums(tree, ring=True, bucket_bytes=1 << 12)
+    for k, v in tree.items():
+        np.testing.assert_array_equal(got[k], np.asarray(v).sum(0))
+    text = _tree_sums(tree, ring=True, bucket_bytes=1 << 12, lower=True)
+    assert text.count("optimization_barrier") == 2      # b -> c -> d
+    assert text.count("all_reduce") == 1                # a_huge
+    assert text.count("collective_permute") == 12       # one body, 3 calls
+    assert text.count("call @_allreduce_ring") == 3
