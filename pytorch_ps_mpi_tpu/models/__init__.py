@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.flatten import named_params, unflatten_params
+from .evabyte import (EvaByteConfig, EvaByteLM, evabyte_aux,
+                      make_evabyte_loss)
 from .glm_moe import GlmMoeConfig, GlmMoeLM, glm_aux, make_glm_loss
 from .lenet import LeNet5
 from .mlp import init_mlp, mlp_apply, mlp_loss_fn
@@ -27,6 +29,7 @@ __all__ = [
     "make_pipelined_lm_loss",
     "GlmMoeConfig", "GlmMoeLM", "glm_aux", "make_glm_loss",
     "SambaYConfig", "SambaYLM", "sambay_aux", "make_sambay_loss",
+    "EvaByteConfig", "EvaByteLM", "evabyte_aux", "make_evabyte_loss",
     "init_mlp", "mlp_apply", "mlp_loss_fn",
     "build_model", "make_classifier_loss", "eval_accuracy",
 ]

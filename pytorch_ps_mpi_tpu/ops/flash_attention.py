@@ -862,11 +862,13 @@ def _bwd_call(q3, k3, v3, do3, lse2, delta2, *, causal, scale, true_len,
                       window=window)
 
 
-def _flash_bwd(causal, scale, interpret, window, res, dout):
+def _flash_bwd(causal, scale, interpret, window, res, dout, dlse=None):
     """Pallas blockwise backward from the saved logsumexp: the dk / dv kernel
     sweeping q per block of k, which writes dq too where the q side is
     whole, else a dq kernel sweeping k per block of q (FlashAttention-2
-    style); every live intermediate is one sub-block in VMEM."""
+    style); every live intermediate is one sub-block in VMEM.  ``dlse``
+    (``[B, H, S]``, `_flash_lse` only) is the cotangent of the logsumexp:
+    ``ds = p (dp - delta) + p dlse``, so it is taken off ``delta``."""
     q, k, v, out, lse = res
     b, s, h, d = q.shape
     pad3 = lambda x: _pad_to(_pad_to(_to_bh(x), BLOCK, 1), BLOCK, 2)
@@ -876,6 +878,10 @@ def _flash_bwd(causal, scale, interpret, window, res, dout):
     # Padded rows are all-zero -> delta 0 there; lse pads with NEG_INF so
     # the kernels' q_pos mask (not the pad value) is what keeps them inert.
     delta2 = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), -1)
+    if dlse is not None:
+        delta2 = delta2 - jnp.pad(
+            dlse.reshape(b * h, s).astype(jnp.float32),
+            ((0, 0), (0, s_pad - s)))
     lse2 = jnp.pad(lse.reshape(b * h, s), ((0, 0), (0, s_pad - s)),
                    constant_values=NEG_INF).astype(jnp.float32)
     rep = lambda x2: jnp.broadcast_to(x2[..., None], x2.shape + (BLOCK,))
@@ -889,9 +895,36 @@ def _flash_bwd(causal, scale, interpret, window, res, dout):
 _flash.defvjp(_flash_fwd_vjp, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_lse(q, k, v, causal, scale, interpret, window=None):
+    """`_flash` with the row statistics as a second output: the per-row
+    logsumexp of the scaled, masked scores, ``[B, H, S]`` f32, which the
+    forward kernel writes anyway for its backward.  With it two softmaxes
+    over different key sets join exactly outside the kernels
+    (`ops.eva_attention`: ``o = o_1 e^{lse_1 - lse} + o_2 e^{lse_2 - lse}``,
+    ``lse = logaddexp(lse_1, lse_2)``), and it is differentiable: ``d lse /
+    d s_j = p_j``, so its cotangent goes into the backward kernels through
+    the one per-row term they already subtract from ``dP`` (``delta - dlse``
+    in place of ``delta``): the kernels are the same, and `_flash` is the
+    `jax.custom_vjp` it was."""
+    return _flash_lse_fwd(q, k, v, causal, scale, interpret, window)[0]
+
+
+def _flash_lse_fwd(q, k, v, causal, scale, interpret, window):
+    out, res = _flash_fwd_res(q, k, v, causal, scale, interpret, window)
+    return (out, res[-1]), res
+
+
+def _flash_lse_bwd(causal, scale, interpret, window, res, cotangents):
+    return _flash_bwd(causal, scale, interpret, window, res, *cotangents)
+
+
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: float | None = None, window: int | None = None,
-                    impl: str = "mosaic"):
+                    return_lse: bool = False, impl: str = "mosaic"):
     """Exact attention, O(S·BLOCK) memory.  ``q,k: [B, S, H, D]``,
     ``v: [B, S, H, Dv]`` → ``[B, S, H, Dv]``; ``Dv`` may differ from ``D``
     (latent attention trains with a 192-wide q / k and a 128-wide v), each
@@ -905,9 +938,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     keys ``j`` with ``0 <= i - j < W``, the row's own among them: the
     kernels then enter only the sub-blocks the band touches and mask the
     ones its two edges cross.  ``window=None`` is the program it was before
-    there were windows, traced and compiled the same."""
+    there were windows, traced and compiled the same.  ``return_lse=True``
+    returns ``(out, lse)`` with ``lse: [B, H, S]`` f32, each row's
+    ``log sum_j exp(scale q . k_j)`` over the keys it sees, differentiable
+    like ``out``; without it the call is the program it was."""
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     if window is not None and (not causal or window < 1):
         raise ValueError("a window of at least one key, under a causal mask")
-    return _flash(q, k, v, causal, scale, use_interpreter(impl), window)
+    fn = _flash_lse if return_lse else _flash
+    return fn(q, k, v, causal, scale, use_interpreter(impl), window)

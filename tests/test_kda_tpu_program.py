@@ -6,8 +6,9 @@ kernel call of the forward, the rematerialised forward and the backward
 stays under the program's ``kda`` scope and carries its kernel's name, and
 no loop of the plain code is left under that scope.  And the kernels compile
 at the cell's shape — the flash kernels' two-level tiles too, at the shapes
-of the cells that run them (PRs 31, 33, 35; the last under a window too), and
-the selective scan's kernels: they sit here because this is
+of the cells that run them (PRs 31, 33, 35; the last under a window too), EVA
+attention's kernels' form at EvaByte's (PR 40), and the selective scan's
+kernels: they sit here because this is
 the one file that may describe a topology.
 
 This is the one test file that describes a TPU topology (the
@@ -147,6 +148,38 @@ def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
     calls = list(fa.tile_plan(s, pad(d), pad(dv), True, window=window).tiles)
     assert calls == ["flash_fwd", "flash_bwd_dkdv"]
     assert sorted(kernel for _, kernel in _CALL.findall(text)) == sorted(calls)
+
+
+def test_eva_attention_compiles_at_the_cells_shape(one_chip):
+    """`ops.eva_attention`'s kernels' form at one row of `evabyte-sync-1chip`
+    (`[1, 8192, 16, 128]`: the flash calls see `[4, 2048, 16, 128]`, a
+    head-window one grid tile at 128 / 128, and give the row statistics as a
+    second output), differentiated through both outputs and compiled by the
+    chip's compiler: the two calls `tile_plan` names, each under the
+    `eva_local` scope inside `eva_attn` (PR 40)."""
+    from pytorch_ps_mpi_tpu.ops import flash_attention as fa
+    from pytorch_ps_mpi_tpu.ops.eva_attention import eva_attention
+
+    x = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    pool = jax.ShapeDtypeStruct((16, 128), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, phi, mu):
+        o, mass = eva_attention(q, k, v, phi, mu, window=2048, chunk=16,
+                                impl="mosaic")
+        return jnp.sum(o.astype(jnp.float32)) + mass
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, pool, pool).compile().as_text()
+    calls = list(fa.tile_plan(2048, 128, 128, True).tiles)
+    assert calls == ["flash_fwd", "flash_bwd_dkdv"]
+    found = _CALL.findall(text)
+    assert sorted(kernel for _, kernel in found) == sorted(calls)
+    timing.register_program("eva_attention", lambda: text)
+    scopes = timing.program_scopes("eva_attention")
+    for name, _ in found:
+        assert timing.in_scope(scopes[name], "eva_local") \
+            and timing.in_scope(scopes[name], "eva_attn"), scopes[name]
 
 
 @pytest.mark.parametrize("what", ["forward", "backward"])
