@@ -41,7 +41,11 @@ here; the subtraction is outside it.
 own (`nn.remat`).  ``m`` and ``full_kv``'s ``k``, ``v`` leave the half-block
 that makes them and enter the ones that read them as arguments, so they are
 kept for the backward pass, not recomputed, and their gradients are the sum
-over every reader.
+over every reader.  The mixer half is rematerialised under
+`ops.flash_attention.SAVE_FLASH`: an attention layer keeps its flash call's
+output and row statistics, so the backward pass does not run the forward
+kernel again; a Mamba or GMU layer names nothing it makes, and its half is
+the program it is without the policy.
 
 Scopes for the device trace (`jax.named_scope`): ``ssm`` (the scan alone),
 ``swa`` and ``full_attn`` (the attention calls of the window layers / of
@@ -64,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.flash_attention import SAVE_FLASH
 from ..ops.selective_scan import selective_scan
 from ..parallel.ring_attention import dense_attention
 from .kimi_linear import _a_log_init, _dt_bias_init, causal_conv_silu
@@ -243,7 +248,8 @@ def _mlp_part(block: "SambaYBlock", x):
 
 class SambaYBlock(nn.Module):
     """One layer of ``kind``; each half rematerialised on its own, as
-    `models.kimi_linear.DecoderBlock`.  ``shared`` is what the kind reads:
+    `models.kimi_linear.DecoderBlock`, the mixer's keeping what the flash
+    call names (`SAVE_FLASH`).  ``shared`` is what the kind reads:
     the memory ``m`` (``gmu``), ``(k, v)`` (``cross``), else None."""
 
     cfg: SambaYConfig
@@ -269,7 +275,8 @@ class SambaYBlock(nn.Module):
         self.mlp = SwiGLU(c.d_ff, c.dtype)
 
     def __call__(self, x, shared=None):
-        x, keep, lam = nn.remat(_mixer_part)(self, x, shared)
+        x, keep, lam = nn.remat(_mixer_part, policy=SAVE_FLASH)(
+            self, x, shared)
         return nn.remat(_mlp_part)(self, x), keep, lam
 
 

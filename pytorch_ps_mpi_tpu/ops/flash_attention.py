@@ -52,6 +52,15 @@ stays the whole backward.
 ``window=None`` is the program this module traced before it knew windows:
 the same jaxpr, the same plan, the same counts.
 
+**Across a rematerialised block.**  The forward's two residuals beyond
+q, k and v, the output ``[B, S, H, Dv]`` and the row logsumexp ``[B, H, S]``
+f32, carry the checkpoint names ``flash_out`` and ``flash_lse``, put on
+inside the forward rule (a name on the call's result outside the
+``custom_vjp`` would leave the residual unnamed).  A caller that remats its
+block under `SAVE_FLASH` keeps them, and the backward pass runs `flash_fwd`
+no second time; a block without a policy recomputes them as before, and
+its program is the one it was (a name lowers to nothing).
+
 Composition: `flash_attention` is a drop-in for
 `parallel.ring_attention.dense_attention` (``[B, S, H, D]`` in/out,
 ``causal=``/``scale=``), so it plugs into `models.transformer.TransformerLM`
@@ -72,6 +81,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax._src.ad_checkpoint import name_p
+from jax.ad_checkpoint import checkpoint_name
+from jax.interpreters import mlir
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -82,6 +94,16 @@ BLOCK = 128      # lane tile the row statistics ride; also the padding unit
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked-row math
                  # finite without jnp.where laundering inside the kernel
 KERNELS = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+# The `jax.checkpoint` / `nn.remat` policy that keeps the forward's named
+# residuals (module docstring: across a rematerialised block).
+SAVE_FLASH = jax.checkpoint_policies.save_only_these_names(
+    "flash_out", "flash_lse")
+# A name lowers to nothing, but JAX first emits each distinct (name, shape)
+# as a private function, and each after the first takes a number off the
+# module's symbol counter: every function lowered after it is renumbered
+# (`_pad_92` for `_pad_91`), and a program without a policy no longer hashes
+# as it did.  Lowered in place, uncached, a name takes no number.
+mlir.register_lowering(name_p, lambda ctx, x, *, name: [x], cacheable=False)
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T, no transpose materialised
 _TN = (((0,), (0,)), ((), ()))   # a.T @ b
 
@@ -659,8 +681,10 @@ def _flash_fwd_res(q, k, v, causal, scale, interpret, window=None):
     v3 = _pad_to(_pad_to(_to_bh(v), BLOCK, 1), BLOCK, 2)
     out3, lse3 = _fwd_call(q3, k3, v3, causal=causal, scale=scale,
                            true_len=s, interpret=interpret, window=window)
-    out = _from_bh(out3[:, :s, :v.shape[-1]], b, h)
-    lse = lse3[:, :s, 0].reshape(b, h, s)
+    # the residuals `SAVE_FLASH` keeps across a rematerialised block
+    out = checkpoint_name(_from_bh(out3[:, :s, :v.shape[-1]], b, h),
+                          "flash_out")
+    lse = checkpoint_name(lse3[:, :s, 0].reshape(b, h, s), "flash_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -5,7 +5,9 @@ dense band-masked softmax, the differential combination as ``(A1 - lam A2)
 V``), at the configuration's rehearsal sizes in f32 with all six kinds of
 layer; the differential combination through two softmaxes of one flash
 call; what crosses blocks (the memory, one layer's keys and values) and how
-its gradients add up over its readers; the vocabulary's share; and the step
+its gradients add up over its readers; the flash call's output and row
+statistics kept across the mixer's remat, and the names that keep them inert
+in programs without that policy; the vocabulary's share; and the step
 through `MPI_PS`."""
 
 import functools
@@ -18,6 +20,7 @@ import numpy as np
 import pytest
 
 from perfbench.models import sambay as ref
+from pytorch_ps_mpi_tpu.models import sambay as sambay_mod
 from pytorch_ps_mpi_tpu.models.sambay import (DiffAttention, SambaYBlock,
                                               SambaYConfig, SambaYLM,
                                               dense_window_attention,
@@ -299,6 +302,141 @@ def test_eight_slices_of_the_vocabulary_tile_the_whole(sizes):
         np.testing.assert_allclose(np.asarray(got),
                                    np.asarray(want[..., 8 * k:8 * (k + 1)]),
                                    rtol=1e-5, atol=1e-5)
+
+
+# -- the flash call's output kept across the mixer's remat --------------------
+
+
+def pallas_calls(closed) -> dict:
+    """Kernel name -> how many `pallas_call`s of it the jaxpr makes, every
+    sub-jaxpr (jit, remat, custom rules) counted once for each equation
+    that calls it."""
+    calls: dict = {}
+
+    def subs(v):
+        if hasattr(v, "eqns"):
+            yield v
+        elif hasattr(getattr(v, "jaxpr", None), "eqns"):
+            yield v.jaxpr
+        elif isinstance(v, (tuple, list)):
+            for x in v:
+                yield from subs(x)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = dict(eqn.params.get("metadata") or {}).get("kernel")
+                calls[name] = calls.get(name, 0) + 1
+            for v in eqn.params.values():
+                for sub in subs(v):
+                    walk(sub)
+
+    walk(closed.jaxpr)
+    return calls
+
+
+@pytest.fixture(scope="module")
+def flash_toy(toy):
+    """The toy with the flash kernels (under the interpreter) for ``attn``,
+    and its loss of the parameters."""
+    model, params, batch = toy
+    model = SambaYLM(model.cfg, attn=functools.partial(
+        flash_attention, causal=True, impl="interpret"))
+    loss = make_sambay_loss(model)
+    return model, params, lambda p: loss(p, sambay_aux(model), batch)[0]
+
+
+@pytest.mark.parametrize("kept", [True, False])
+def test_the_backward_runs_flash_fwd_once_an_attention_layer(
+        flash_toy, monkeypatch, kept):
+    """Kept: one `flash_fwd` a layer, the forward's; under a bare `nn.remat`
+    (the policy taken away) the rematerialised forward runs it again.  The
+    backward kernel runs once a layer either way."""
+    model, params, loss = flash_toy
+    if not kept:
+        monkeypatch.setattr(sambay_mod, "SAVE_FLASH", None)
+    calls = pallas_calls(jax.make_jaxpr(jax.grad(loss))(params))
+    n = model.cfg.n_attention
+    assert n == 3
+    assert calls == {"flash_fwd": n if kept else 2 * n, "flash_bwd_dkdv": n}
+
+
+def test_kept_flash_outputs_give_the_bare_remats_loss_and_gradients(
+        flash_toy, monkeypatch):
+    """Bit for bit: the output kept is the one the rematerialised forward
+    would have made again from the same q, k and v."""
+    _, params, loss = flash_toy
+    kept = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(sambay_mod, "SAVE_FLASH", None)
+    bare = jax.jit(jax.value_and_grad(loss))(params)
+    assert float(kept[0]) == float(bare[0])
+    assert set(kept[1]) == set(bare[1]) == set(params)
+    for name in params:
+        np.testing.assert_array_equal(np.asarray(kept[1][name]),
+                                      np.asarray(bare[1][name]), err_msg=name)
+
+
+def _glm_block_step():
+    """One GLM-shaped `DecoderBlock` (rotary MLA with a low-rank q, a dense
+    MLP) through a gradient step."""
+    from pytorch_ps_mpi_tpu.models.glm_moe import GlmMoeConfig
+    from pytorch_ps_mpi_tpu.models.kimi_linear import DecoderBlock
+    cfg = GlmMoeConfig(
+        vocab_size=61, d_model=32, n_layers=1, first_k_dense=1, d_ff=48,
+        d_expert=16, n_experts=16, experts_held=(2, 3), top_k=2, n_shared=1,
+        routed_scale=1.8, n_heads=2, q_lora_rank=12, kv_lora_rank=16,
+        qk_nope_dim=12, qk_rope_dim=4, v_dim=16, rope_theta=1e6, n_mtp=0)
+    block = DecoderBlock(cfg, functools.partial(
+        flash_attention, causal=True, scale=16 ** -0.5, impl="interpret"),
+        linear=False, dense=True)
+    x = jnp.asarray(np.random.RandomState(7).randn(2, 40, 32), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(40, dtype=jnp.int32), (2, 40))
+    params = block.init(jax.random.PRNGKey(8), x, pos)
+    return lambda p: jnp.sum(jnp.sin(block.apply(p, x, pos)[0])), params
+
+
+def _gpt2_step():
+    """A two-layer GPT-2-style `TransformerLM` toy through a gradient step."""
+    from pytorch_ps_mpi_tpu.models.transformer import TransformerLM
+    model = TransformerLM(vocab_size=61, d_model=64, n_heads=2, n_layers=2,
+                          d_ff=128, max_len=64, attn=functools.partial(
+                              flash_attention, causal=True,
+                              impl="interpret"))
+    rows = jnp.asarray(np.random.RandomState(9).randint(0, 61, (2, 33)),
+                       jnp.int32)
+    tokens, targets = rows[:, :-1], rows[:, 1:]
+    pos = jnp.broadcast_to(jnp.arange(32, dtype=jnp.int32), (2, 32))
+    params = model.init(jax.random.PRNGKey(10), tokens, pos)
+
+    def loss(p):
+        logp = jax.nn.log_softmax(model.apply(p, tokens, pos), axis=-1)
+        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], -1))
+    return loss, params
+
+
+@pytest.mark.parametrize("make", [_glm_block_step, _gpt2_step],
+                         ids=["glm_decoder_block", "gpt2_toy"])
+def test_the_flash_names_are_inert_without_a_policy(monkeypatch, make):
+    """A program whose remat has no policy, or that has no remat, lowers
+    to the same StableHLO with the names as without them: so its compiled
+    program, and its entry in the compilation cache, are the ones it had."""
+    from pytorch_ps_mpi_tpu.ops import flash_attention as fa
+
+    def lowered():
+        loss, params = make()
+
+        def step(p):
+            value, grads = jax.value_and_grad(loss)(p)
+            return value, jax.tree.map(lambda w, g: w - 0.1 * g, p, grads)
+        jaxpr = str(jax.make_jaxpr(step)(params))
+        return jaxpr, jax.jit(step).lower(params).as_text()
+
+    named_jaxpr, named = lowered()
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    plain_jaxpr, plain = lowered()
+    assert "flash_out" in named_jaxpr and "flash_lse" in named_jaxpr
+    assert "flash_out" not in plain_jaxpr
+    assert named == plain
 
 
 # -- through the step ---------------------------------------------------------
