@@ -18,6 +18,8 @@ from .evabyte import (EvaByteConfig, EvaByteLM, evabyte_aux,
 from .glm_moe import GlmMoeConfig, GlmMoeLM, glm_aux, make_glm_loss
 from .lenet import LeNet5
 from .mlp import init_mlp, mlp_apply, mlp_loss_fn
+from .nemotron_h import (NemotronHConfig, NemotronHLM, make_nemotron_loss,
+                         nemotron_aux)
 from .resnet import ResNet, resnet18, resnet34, resnet50
 from .pipelined import make_pipelined_lm_loss
 from .sambay import SambaYConfig, SambaYLM, make_sambay_loss, sambay_aux
@@ -30,6 +32,7 @@ __all__ = [
     "GlmMoeConfig", "GlmMoeLM", "glm_aux", "make_glm_loss",
     "SambaYConfig", "SambaYLM", "sambay_aux", "make_sambay_loss",
     "EvaByteConfig", "EvaByteLM", "evabyte_aux", "make_evabyte_loss",
+    "NemotronHConfig", "NemotronHLM", "nemotron_aux", "make_nemotron_loss",
     "init_mlp", "mlp_apply", "mlp_loss_fn",
     "build_model", "make_classifier_loss", "eval_accuracy",
 ]

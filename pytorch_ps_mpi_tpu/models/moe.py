@@ -7,11 +7,11 @@
   No published model is run through it.
 * `ShareOfExperts` — **the share-aware dropless layer real models use**
   (sigmoid router over the published expert count, selection bias, top-k,
-  renormalised and scaled, SwiGLU experts, a shared expert).  It is told
-  which experts it holds, routes over all of them, computes its own
+  renormalised and scaled, SwiGLU or relu² experts, a shared expert).  It
+  is told which experts it holds, routes over all of them, computes its own
   experts' part of the result for the tokens routed to them and drops
   none, whatever the imbalance.  `models.kimi_linear` and `models.glm_moe`
-  run on it.  Its plan over the ``T * k`` assignments (the count an
+  run on it with SwiGLU experts, `models.nemotron_h` with relu² ones.  Its plan over the ``T * k`` assignments (the count an
   expert, the weights in sorted order) compares, sums and sorts: XLA's TPU
   gather and scatter take scalars one after another.
 
@@ -41,6 +41,7 @@ loss (Switch eq. 4) is returned for the trainer to add.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import flax.linen as nn
@@ -213,20 +214,41 @@ def _block_of(i, plan, weight):
     return expert, row0, valid, rows(plan["token"]), rows(weight)
 
 
-def _swiglu_block(xs, w_in, w_out, expert):
-    gate_up = jnp.matmul(xs, w_in[expert], preferred_element_type=jnp.float32)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    hidden = (jax.nn.silu(gate) * up).astype(xs.dtype)
+ACTIVATIONS = ("swiglu", "relu2")
+
+
+def _expert_block(xs, w_in, w_out, expert, act):
+    """One block's expert products: ``(the activation's inputs, hidden,
+    out)``.  ``"swiglu"``: ``w_in`` is ``[gate | up]``, hidden ``silu(gate)
+    * up``; ``"relu2"``: ``w_in`` is ``up``, hidden ``relu(up)^2``."""
+    pre = jnp.matmul(xs, w_in[expert], preferred_element_type=jnp.float32)
+    if act == "swiglu":
+        gate, up = jnp.split(pre, 2, axis=-1)
+        pre, hidden = (gate, up), jax.nn.silu(gate) * up
+    else:
+        pre, hidden = (pre,), jnp.square(jax.nn.relu(pre))
+    hidden = hidden.astype(xs.dtype)
     out = jnp.matmul(hidden, w_out[expert],
                      preferred_element_type=jnp.float32)
-    return gate, up, hidden, out
+    return pre, hidden, out
 
 
-@jax.custom_vjp
-def _grouped_swiglu(x, w_gate, w_up, w_down, weight, plan):
-    """``sum over the assignments routed here of weight * SwiGLU_e(x_token)``
-    as ``[T, d]`` in f32.  The assignments come sorted by held expert
-    (``plan``); the sweep takes one block of `BLOCK_ROWS` of them at a
+def _activation_grad(act, pre, dhidden):
+    """``d hidden / d (xs w_in)`` applied to ``dhidden``, in f32."""
+    if act == "swiglu":
+        gate, up = pre
+        sig = jax.nn.sigmoid(gate)
+        dgate = dhidden * up * sig * (1.0 + gate * (1.0 - sig))
+        return jnp.concatenate([dgate, dhidden * gate * sig], axis=-1)
+    return dhidden * 2.0 * jax.nn.relu(pre[0])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _grouped_experts(x, w_gate, w_up, w_down, weight, plan, act):
+    """``sum over the assignments routed here of weight * E_e(x_token)`` as
+    ``[T, d]`` in f32, ``E_e`` expert ``e`` under ``act`` (`_expert_block`;
+    ``w_gate`` None for ``"relu2"``).  The assignments come sorted by held
+    expert (``plan``); the sweep takes one block of `BLOCK_ROWS` of them at a
     time, all of one expert, under a loop whose trip count is the number of
     blocks the routing actually filled — so the matrix products follow the
     tokens that came here, not the worst case, and nothing has a capacity
@@ -240,20 +262,21 @@ def _grouped_swiglu(x, w_gate, w_up, w_down, weight, plan):
     cast were transposed by JAX in two more passes over ``[held, d, 2 f]``
     in f32, and their results kept for the backward: 0.6 ms a layer and
     228 MB of `glm47-flash-sync-1chip`'s peak; my chip runs, PR 36.)"""
-    return _grouped_fwd(x, w_gate, w_up, w_down, weight, plan)[0]
+    return _grouped_fwd(x, w_gate, w_up, w_down, weight, plan, act)[0]
 
 
 def _read_weights(x, w_gate, w_up, w_down):
-    return (jnp.concatenate([w_gate, w_up], axis=-1).astype(x.dtype),
-            w_down.astype(x.dtype))
+    w_in = w_up if w_gate is None else jnp.concatenate([w_gate, w_up],
+                                                       axis=-1)
+    return w_in.astype(x.dtype), w_down.astype(x.dtype)
 
 
-def _grouped_fwd(x, w_gate, w_up, w_down, weight, plan):
+def _grouped_fwd(x, w_gate, w_up, w_down, weight, plan, act):
     w_in, w_out = _read_weights(x, w_gate, w_up, w_down)
 
     def body(i, y):
         expert, _, _, tokens, w = _block_of(i, plan, weight)
-        out = _swiglu_block(x[tokens], w_in, w_out, expert)[3]
+        out = _expert_block(x[tokens], w_in, w_out, expert, act)[2]
         return y.at[tokens].add(out * w[:, None])
 
     y = lax.fori_loop(0, plan["n_blocks"], body,
@@ -261,7 +284,7 @@ def _grouped_fwd(x, w_gate, w_up, w_down, weight, plan):
     return y, (x, w_gate, w_up, w_down, weight, plan)
 
 
-def _grouped_bwd(res, dy):
+def _grouped_bwd(act, res, dy):
     x, w_gate, w_up, w_down, weight, plan = res
     w_in, w_out = _read_weights(x, w_gate, w_up, w_down)
     dy = dy.astype(jnp.float32)
@@ -270,7 +293,7 @@ def _grouped_bwd(res, dy):
         dx, dw_in, dw_out, dweight = acc
         expert, row0, valid, tokens, w = _block_of(i, plan, weight)
         xs = x[tokens]
-        gate, up, hidden, out = _swiglu_block(xs, w_in, w_out, expert)
+        pre, hidden, out = _expert_block(xs, w_in, w_out, expert, act)
         dys = dy[tokens]
         dweight = lax.dynamic_update_slice(
             dweight, jnp.where(valid, jnp.sum(out * dys, axis=-1), 0.0),
@@ -280,13 +303,10 @@ def _grouped_bwd(res, dy):
             hidden.T, dout, preferred_element_type=jnp.float32))
         dhidden = jnp.matmul(dout, w_out[expert].T,
                              preferred_element_type=jnp.float32)
-        sig = jax.nn.sigmoid(gate)
-        dgate = dhidden * up * sig * (1.0 + gate * (1.0 - sig))
-        dgate_up = jnp.concatenate([dgate, dhidden * gate * sig],
-                                   axis=-1).astype(x.dtype)
+        dpre = _activation_grad(act, pre, dhidden).astype(x.dtype)
         dw_in = dw_in.at[expert].add(jnp.matmul(
-            xs.T, dgate_up, preferred_element_type=jnp.float32))
-        dxs = jnp.matmul(dgate_up, w_in[expert].T,
+            xs.T, dpre, preferred_element_type=jnp.float32))
+        dxs = jnp.matmul(dpre, w_in[expert].T,
                          preferred_element_type=jnp.float32)
         dx = dx.at[tokens].add(jnp.where(valid[:, None], dxs, 0.0))
         return dx, dw_in, dw_out, dweight
@@ -297,16 +317,20 @@ def _grouped_bwd(res, dy):
          jnp.zeros(w_in.shape, jnp.float32),
          jnp.zeros(w_out.shape, jnp.float32),
          jnp.zeros(weight.shape, jnp.float32)))
-    f = w_gate.shape[-1]
     as_read = lambda g, w: g.astype(x.dtype).astype(w.dtype)
     no_grad = jax.tree.map(
         lambda a: np.zeros(a.shape, jax.dtypes.float0), plan)
-    return (dx.astype(x.dtype), as_read(dw_in[..., :f], w_gate),
-            as_read(dw_in[..., f:], w_up), as_read(dw_out, w_down), dweight,
-            no_grad)
+    dx = dx.astype(x.dtype)
+    if w_gate is None:
+        d_gate, d_up = None, as_read(dw_in, w_up)
+    else:
+        f = w_gate.shape[-1]
+        d_gate = as_read(dw_in[..., :f], w_gate)
+        d_up = as_read(dw_in[..., f:], w_up)
+    return dx, d_gate, d_up, as_read(dw_out, w_down), dweight, no_grad
 
 
-_grouped_swiglu.defvjp(_grouped_fwd, _grouped_bwd)
+_grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 @jax.custom_vjp
@@ -335,10 +359,10 @@ _sorted_by.defvjp(_sorted_by_fwd, _sorted_by_bwd)
 
 
 def routed_here(x, chosen, weight, w_gate, w_up, w_down, *, n_experts: int,
-                held: "tuple[int, ...]"):
+                held: "tuple[int, ...]", act: str = "swiglu"):
     """The held experts' part of the layer: ``sum_{e chosen and held} w_e *
-    SwiGLU_e(x)`` for ``x: [T, d]``, and the load: assignments on each held
-    expert, then their sum.  Sorts the ``T * k`` assignments by held expert
+    E_e(x)`` for ``x: [T, d]`` (``E_e`` under ``act``, `_expert_block`),
+    and the load: assignments on each held expert, then their sum.  Sorts the ``T * k`` assignments by held expert
     (those on experts held elsewhere go last and are never visited)."""
     top_k = chosen.shape[1]
     n_held = len(held)
@@ -356,7 +380,8 @@ def routed_here(x, chosen, weight, w_gate, w_up, w_down, *, n_experts: int,
         "blocks": blocks, "block_end": jnp.cumsum(blocks),
         "n_blocks": jnp.sum(blocks),
     }
-    y = _grouped_swiglu(x, w_gate, w_up, w_down, pad(sorted_weight), plan)
+    y = _grouped_experts(x, w_gate, w_up, w_down, pad(sorted_weight), plan,
+                         act)
     load = jnp.concatenate([count, jnp.sum(count, keepdims=True)])
     return y, load.astype(jnp.float32)
 
@@ -368,7 +393,9 @@ class ShareOfExperts(nn.Module):
     ``n_experts`` is the published count and the router's width; ``held``
     lists the experts whose weights live here (the only expert weights the
     layer has).  Every token is routed over all ``n_experts``; the result
-    is ``sum_{e chosen and held} w_e SwiGLU_e(x) + SwiGLU_shared(x)``.
+    is ``sum_{e chosen and held} w_e E_e(x) + E_shared(x)``, every expert
+    ``E`` a `SwiGLU` (``act="swiglu"``) or a `ReluSquaredMLP` (``"relu2"``,
+    no ``w_gate``).
     What the experts held elsewhere would add is left out: on one chip
     there is no exchange, and nothing here stands in for the absent chips.
     ``load`` is ``[len(held) + 1]`` in f32: the assignments that landed on
@@ -382,6 +409,7 @@ class ShareOfExperts(nn.Module):
     scale: float = 1.0
     d_shared: int = 0            # width of the shared expert, 0 for none
     dtype: jnp.dtype = jnp.float32
+    act: str = "swiglu"          # one of `ACTIVATIONS`
 
     @nn.compact
     def __call__(self, x):
@@ -397,8 +425,11 @@ class ShareOfExperts(nn.Module):
                           (self.n_experts,), jnp.float32)
         expert_init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                                    batch_axis=(0,))
-        w_gate = self.param("w_gate", expert_init, (n_held, d, f),
-                            jnp.float32)
+        if self.act not in ACTIVATIONS:
+            raise ValueError(f"expert activation {self.act!r}: know "
+                             f"{ACTIVATIONS}")
+        w_gate = None if self.act == "relu2" else self.param(
+            "w_gate", expert_init, (n_held, d, f), jnp.float32)
         w_up = self.param("w_up", expert_init, (n_held, d, f), jnp.float32)
         w_down = self.param("w_down", expert_init, (n_held, f, d),
                             jnp.float32)
@@ -406,10 +437,11 @@ class ShareOfExperts(nn.Module):
                                      scale=self.scale)
         y, load = routed_here(
             toks, chosen, weight, w_gate, w_up, w_down,
-            n_experts=self.n_experts, held=tuple(self.held))
+            n_experts=self.n_experts, held=tuple(self.held), act=self.act)
         y = y.astype(self.dtype)
         if self.d_shared:
-            y = y + SwiGLU(self.d_shared, self.dtype, name="shared")(toks)
+            shared = SwiGLU if self.act == "swiglu" else ReluSquaredMLP
+            y = y + shared(self.d_shared, self.dtype, name="shared")(toks)
         return y.reshape(b, s, d).astype(x.dtype), load
 
 
@@ -424,6 +456,20 @@ class SwiGLU(nn.Module):
     def __call__(self, x):
         hidden = nn.silu(bias_free_dense(self.width, self.dtype, "gate")(x)) \
             * bias_free_dense(self.width, self.dtype, "up")(x)
+        return bias_free_dense(x.shape[-1], self.dtype, "down")(hidden)
+
+
+class ReluSquaredMLP(nn.Module):
+    """``down(relu(up(x))^2)``, bias-free, f32 parameters read in
+    ``dtype``."""
+
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        hidden = jnp.square(nn.relu(
+            bias_free_dense(self.width, self.dtype, "up")(x)))
         return bias_free_dense(x.shape[-1], self.dtype, "down")(hidden)
 
 
