@@ -11,9 +11,11 @@
   form and Pallas kernels).
 * `selective_scan`: `selective_scan(x, dt, A, B, C, D)`, the Mamba-1
   state-space scan (blocked plain form, hand-written backward).
+* `ssd`, `ssd_pallas`: `ssd(x, dt, A, B, C, D, chunk=)`, Mamba-2's
+  state-space duality scan (chunked plain form and Pallas kernels).
 
 Nothing is imported here: a program that never attends does not load Pallas.
 """
 
 __all__ = ["codecs", "eva_attention", "flash_attention", "kda", "kda_pallas", "pallas_kernels",
-           "robust", "selective_scan"]
+           "robust", "selective_scan", "ssd", "ssd_pallas"]

@@ -32,20 +32,29 @@ that overflows where a chunk's decay underflows (`ops/kda.py` had to learn
 that), and where it underflows its factor is 0.  Masked entries take their
 exponent from ``-inf``, never from a positive difference times zero.
 
-`ssd` is plain `jax.numpy` on every platform, under `jax.named_scope("ssd")`
-where its caller puts it, differentiated by JAX: its backward keeps the
-chunk-sized products and one state a chunk, never one a token.  The
-products take their operands in f32 and the platform's default precision
-for them (on the TPU, bf16 operands with f32 accumulation, as the model's
-other products).  `ssd_loop` is the recurrence as written above, one token a
+Two implementations compute it.  `ssd_chunked` is plain `jax.numpy`,
+differentiated by JAX: its backward keeps the chunk-sized products and one
+state a chunk, never one a token.  Its products take their operands in f32
+and the platform's default precision for them (on the TPU, bf16 operands
+with f32 accumulation, as the model's other products).  The Pallas kernels
+of `ops/ssd_pallas.py` (`ssd_fwd`, `ssd_bwd`) build each chunk's decay
+matrices and carry the state in VMEM, with a hand-written backward, at the
+widths they take (heads of 64 in pairs, a state of 128).  `ssd`, at the
+end of this file, picks between them from what the program can observe;
+it sits under `jax.named_scope("ssd")` where its caller puts it, and so do
+both kernels.  `ssd_loop` is the recurrence as written above, one token a
 step, differentiated by JAX: the oracle of the tests.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from . import ssd_pallas
 
 
 def _groups(B, heads: int):
@@ -88,9 +97,9 @@ def _check_length(s: int, chunk: int) -> None:
                          f"chunks of {chunk}")
 
 
-def ssd(x, dt, A, B, C, D, *, chunk: int):
+def ssd_chunked(x, dt, A, B, C, D, *, chunk: int):
     """``y_t = S_t C_t + D x_t`` of the recurrence in the module's
-    docstring, exact, in chunks of ``chunk`` tokens.  ``x: [rows, S, H,
+    docstring, exact, in chunks of ``chunk`` tokens, in plain `jax.numpy`.  ``x: [rows, S, H,
     P]``, ``dt: [rows, S, H]`` (after its softplus), ``A, D: [H]`` (``A <
     0``), ``B, C: [rows, S, G, N]`` with ``G`` dividing ``H`` -> ``y:
     [rows, S, H, P]`` f32.  ``S`` has to be a whole number of chunks."""
@@ -134,6 +143,33 @@ def ssd(x, dt, A, B, C, D, *, chunk: int):
     y = y.reshape(rows, s, h, p)
     skip = D.astype(jnp.float32)[:, None] * x.reshape(rows, s, h, p)
     return y + skip
+
+
+def ssd(x, dt, A, B, C, D, *, chunk: int):
+    """The scan by the implementation the program can see it needs;
+    arguments and result as `ssd_chunked`.
+
+    Two things decide, neither of them a setting.  The shapes: the kernels
+    of `ssd_pallas` take heads of 64 in pairs, a state of 128 and a length
+    of whole chunks (`ssd_pallas.supports`); any other shape is
+    `ssd_chunked`.  And the platform the program is lowered for
+    (`lax.platform_dependent`, not the process's default backend): a TPU
+    gets the Mosaic kernels, everything else `ssd_chunked`.  The kernels
+    take their own chunk, `ssd_pallas.CHUNK`: the scan is exact for any."""
+    _check_length(x.shape[1], chunk)
+    if not ssd_pallas.supports(x, B, chunk):
+        return ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
+    return _for_the_platform(x, dt, A, B, C, D, chunk=chunk)
+
+
+@functools.partial(jax.jit, static_argnames="chunk")
+def _for_the_platform(x, dt, A, B, C, D, *, chunk):
+    """Both implementations are traced, and differentiated, whatever the
+    platform, and the one that is lowered is picked then: under `jax.jit`
+    the layers of a model that call this at one shape share that work."""
+    return lax.platform_dependent(
+        x, dt, A, B, C, D, tpu=ssd_pallas.ssd_kernels,
+        default=functools.partial(ssd_chunked, chunk=chunk))
 
 
 def carried_share(dt, A, *, chunk: int):

@@ -8,8 +8,9 @@ no loop of the plain code is left under that scope.  And the kernels compile
 at the cell's shape — the flash kernels' two-level tiles too, at the shapes
 of the cells that run them (PRs 31, 33, 35; the last under a window too), EVA
 attention's kernels' form at EvaByte's (PR 40), and the selective scan's
-kernels: they sit here because this is
-the one file that may describe a topology.
+kernels, and the state-space duality scan's `ssd_fwd` / `ssd_bwd` in a toy
+`Mamba2Mixer` under `nn.remat` and at `nemotron3-nano-sync-1chip`'s shape:
+they sit here because this is the one file that may describe a topology.
 
 This is the one test file that describes a TPU topology (the
 `on-chip-measurement` guide, section 2): only inside a fixture, never while
@@ -207,3 +208,80 @@ def test_the_selective_scan_compiles_at_the_cells_shape(one_chip, what):
     assert kernels == (["ssm_bwd", "ssm_fwd"] if what == "backward"
                        else ["ssm_fwd"])
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 29
+
+
+SSD_KERNELS = {"ssd_fwd", "ssd_bwd"}
+
+
+@pytest.fixture(scope="module")
+def mamba_program(one_chip):
+    """`jax.grad` of a toy `Mamba2Mixer` under `nn.remat` (2 groups of 2
+    heads of 64, a state of 128, 256 tokens), compiled for the chip and
+    registered as `MPI_PS.step` registers its program."""
+    import flax.linen as nn
+
+    from pytorch_ps_mpi_tpu.models.nemotron_h import (Mamba2Mixer,
+                                                      NemotronHConfig)
+
+    cfg = NemotronHConfig(
+        vocab_size=64, d_model=128, pattern="M", d_expert=8, d_shared=8,
+        n_experts=2, experts_held=(0,), top_k=1, routed_scale=1.0,
+        n_heads=2, n_kv_heads=1, head_dim=64, mamba_heads=4,
+        mamba_head_dim=64, n_groups=2, d_state=128, chunk=128,
+        dtype=jnp.bfloat16)
+    layer = nn.remat(Mamba2Mixer)(cfg)
+    x = jnp.zeros((1, 256, 128), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        y, _ = layer.apply(params, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    compiled = jax.jit(jax.grad(loss)).lower(
+        _shapes(params, one_chip), _shapes(x, one_chip)).compile()
+    timing.register_program("test.ssd_tpu", compiled.as_text)
+    return compiled.as_text(), timing.program_scopes("test.ssd_tpu")
+
+
+def test_every_ssd_kernel_call_is_under_the_ssd_scope_by_name(mamba_program):
+    text, scopes = mamba_program
+    calls = [(n, k) for n, k in _CALL.findall(text) if k in SSD_KERNELS]
+    # forward, the forward again under the remat, and the backward
+    assert sorted(kernel for _, kernel in calls) == [
+        "ssd_bwd", "ssd_fwd", "ssd_fwd"]
+    for name, kernel in calls:
+        assert name.startswith(kernel), (name, kernel)
+        assert name in scopes, f"{name}: the registry did not read it"
+        assert timing.in_scope(scopes[name], "ssd"), scopes[name]
+    backward = [scopes[n] for n, kernel in calls if kernel == "ssd_bwd"]
+    assert "transpose(" in backward[0]
+
+
+def test_no_loop_of_the_plain_chunk_carry_is_left_under_the_ssd_scope(
+        mamba_program):
+    _, scopes = mamba_program
+    loops = [n for n, op in scopes.items()
+             if timing.in_scope(op, "ssd") and "while" in op]
+    assert loops == []
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_the_ssd_kernels_compile_at_the_cells_shape(one_chip, what):
+    """One Mamba-2 layer of `nemotron3-nano-sync-1chip`: x ``[1, 8192, 64,
+    64]`` bf16, B and C ``[1, 8192, 8, 128]`` bf16, dt ``[1, 8192, 64]``
+    f32: tiling and VMEM are the chip's compiler's to refuse."""
+    from pytorch_ps_mpi_tpu.ops import ssd_pallas
+
+    shape = lambda dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    group = shape((1, 8192, 8, 128), jnp.bfloat16)
+    args = (shape((1, 8192, 64, 64), jnp.bfloat16), shape((1, 8192, 64)),
+            shape((64,)), group, group, shape((64,)))
+    fn = functools.partial(ssd_pallas.ssd_kernels, impl="mosaic")
+    if what == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(ssd_pallas.ssd_kernels(*a)),
+                      argnums=range(6))
+    kernels = sorted(k for _, k in _CALL.findall(
+        jax.jit(fn).lower(*args).compile().as_text()))
+    assert kernels == (["ssd_bwd", "ssd_fwd"] if what == "backward"
+                       else ["ssd_fwd"])
