@@ -73,12 +73,15 @@ def _qkv(seed, b=2, s=96, h=2, d=16):
 # -- the row statistics of the flash call -------------------------------------
 
 
-@pytest.mark.parametrize("s,window", [(96, None), (200, None), (200, 70)])
+@pytest.mark.parametrize("s,window", [(96, None), (200, None), (200, 70),
+                                      (600, None)])
 def test_the_row_statistics_are_the_logsumexp_and_differentiable(s, window):
     """`flash_attention(return_lse=True)` gives each row's logsumexp of its
     scaled, masked scores beside the output, and a loss that reads both has
     the gradients of the dense form: the cotangent of the statistics goes
-    through the kernels' ``delta``."""
+    through the kernels' ``delta``.  At 600 of 640 rows the q side is two
+    tiles and `flash_bwd_dq` reads ``delta - dlse`` too, as a row, beside
+    `flash_bwd_dkdv`."""
     q, k, v, _, _ = _qkv(3, s=s)
 
     def dense(q, k, v):

@@ -205,9 +205,10 @@ def test_v_is_not_widened_to_the_q_width():
     accumulator and the output at 128: v is not padded to q's width."""
     seen = [[s.shape[-1] for s in jax.tree.leaves(kw["out_shape"])]
             for kw in _pallas_calls(*_qkv_widths(2, s=64, d=192, dv=128))]
-    # forward: o 128 (+ the row statistics' lane tile); the one backward call
-    # (the q side is whole at 64 rows): dk 256, dv 128, dq 256
-    assert seen == [[128, BLOCK], [256, 128, 256]]
+    # forward: o 128 (+ the row statistics, one row of the padded length,
+    # 128); the one backward call (the q side is whole at 64 rows): dk 256,
+    # dv 128, dq 256
+    assert seen == [[128, 128], [256, 128, 256]]
 
 
 # PR 28 pinned its refactor to the parent's bits with digests of these three
@@ -590,3 +591,47 @@ def test_without_a_window_the_program_is_the_one_it_was():
             == _fa.tile_plan(*shape, True)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, causal=False, window=100)
+
+
+# -- the row statistics cross HBM as rows -------------------------------------
+
+
+@pytest.mark.parametrize("causal,window", [(False, None), (True, None),
+                                           (True, 100)])
+def test_the_row_statistics_are_rows_and_the_gradients_hold(causal, window):
+    """The forward writes each row's logsumexp once, as ``[BH, 1, S_pad]``
+    f32 (not a copy in each of 128 lanes), equal to the dense logsumexp on
+    the true rows; and the backward, which reads the logsumexp and ``delta``
+    as such rows, gives the dense gradients at a padded length (600 of
+    640: two q tiles, so `flash_bwd_dq` runs beside `flash_bwd_dkdv`), at
+    another v width (192 / 128), with and without the causal mask and under
+    a window, where the padded rows' ``NEG_INF`` statistics leave no NaN."""
+    s, scale = 600, 192 ** -0.5
+    q, k, v = _qkv_widths(13, b=1, s=s, h=2, d=192, dv=128)
+    pad3 = lambda x: _fa._pad_to(_fa._pad_to(_fa._to_bh(x), BLOCK, 1),
+                                 BLOCK, 2)
+    out3, lse3 = _fa._fwd_call(pad3(q), pad3(k), pad3(v), causal=causal,
+                               scale=scale, true_len=s, interpret=True,
+                               window=window)
+    assert lse3.shape == (2, 1, 640) and lse3.dtype == jnp.float32
+    assert out3.shape == (2, 640, 128)
+    age = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    seen = age >= 0 if causal else jnp.ones_like(age, bool)
+    if window is not None:
+        seen &= age < window
+    scores = lambda q, k: jnp.where(
+        seen, jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse3[:, 0, :s]),
+        np.asarray(jax.nn.logsumexp(scores(q, k), -1).reshape(2, s)),
+        rtol=1e-5, atol=2e-5)
+    dense = lambda q, k, v: jnp.einsum(
+        "bhqk,bkhd->bqhd", jax.nn.softmax(scores(q, k), axis=-1), v)
+    flash = functools.partial(flash_attention, causal=causal, window=window)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5, err_msg=name)

@@ -127,6 +127,7 @@ def test_the_kernels_compile_at_the_cells_shape(one_chip, what):
     (1, 8192, 20, 256, 256, None),  # glm47-flash-sync-1chip: v as wide as q
     (1, 8192, 40, 64, 128, None),   # phi4flash-sync-1chip: full and cross
     (1, 8192, 40, 64, 128, 512),    # ... and its window layer (PR 35)
+    (1, 8192, 32, 128, 128, None),  # nemotron3-nano-sync-1chip
 ])
 def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
                                                        dv, window):
@@ -136,7 +137,10 @@ def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
     compiled program holds exactly the calls `tile_plan` names: at these
     shapes the q side of a head is whole in `flash_bwd_dkdv`'s tile, which
     writes dq too (8 MiB more of VMEM scratch at 256 / 256), so the backward
-    is that one call (PR 34)."""
+    is that one call (PR 34).  The row statistics cross HBM as rows: the
+    forward returns the logsumexp as ``f32[BH, 1, S_pad]``, the backward
+    takes it and ``delta`` so, and no call takes or returns a lane-replicated
+    ``f32[BH, S_pad, 128]`` copy."""
     from pytorch_ps_mpi_tpu.ops import flash_attention as fa
 
     qk = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
@@ -149,6 +153,28 @@ def test_the_flash_kernels_compile_at_the_cells_shapes(one_chip, b, s, h, d,
     calls = list(fa.tile_plan(s, pad(d), pad(dv), True, window=window).tiles)
     assert calls == ["flash_fwd", "flash_bwd_dkdv"]
     assert sorted(kernel for _, kernel in _CALL.findall(text)) == sorted(calls)
+    bh, s_pad = b * h, pad(s)
+    f32 = {kernel: (results, operands)
+           for kernel, results, operands in _flash_arrays(text)}
+    assert sorted(f32) == sorted(calls)
+    for kernel, (results, operands) in f32.items():
+        assert [bh, s_pad, fa.BLOCK] not in results + operands, kernel
+    assert f32["flash_fwd"] == ([[bh, 1, s_pad]], [])
+    assert f32["flash_bwd_dkdv"] == ([], [[bh, 1, s_pad]] * 2)
+
+
+def _flash_arrays(text):
+    """``(kernel, results, operands)`` of each `flash_*` call in a compiled
+    program, each list the dimensions of its f32 arrays."""
+    f32 = lambda part: [[int(n) for n in dims.split(",")]
+                        for dims in re.findall(r"\bf32\[([\d,]+)\]", part)]
+    for call in _CALL.finditer(text):
+        if not call.group(2).startswith("flash_"):
+            continue
+        results, _, rest = call.group(0).partition(" custom-call(")
+        operands = rest.partition("operand_layout_constraints={")[2]
+        yield (call.group(2), f32(results.partition(" = ")[2]),
+               f32(operands.partition("}}")[0]))
 
 
 def test_eva_attention_compiles_at_the_cells_shape(one_chip):
@@ -157,7 +183,10 @@ def test_eva_attention_compiles_at_the_cells_shape(one_chip):
     head-window one grid tile at 128 / 128, and give the row statistics as a
     second output), differentiated through both outputs and compiled by the
     chip's compiler: the two calls `tile_plan` names, each under the
-    `eva_local` scope inside `eva_attn` (PR 40)."""
+    `eva_local` scope inside `eva_attn` (PR 40).  The statistics come out
+    of `flash_fwd` as rows one sublane deep (``T(1,128)``) and reach the
+    join copied once into full ``(8, 128)`` tiles, heads down the
+    sublanes."""
     from pytorch_ps_mpi_tpu.ops import flash_attention as fa
     from pytorch_ps_mpi_tpu.ops.eva_attention import eva_attention
 
@@ -176,6 +205,11 @@ def test_eva_attention_compiles_at_the_cells_shape(one_chip):
     assert calls == ["flash_fwd", "flash_bwd_dkdv"]
     found = _CALL.findall(text)
     assert sorted(kernel for _, kernel in found) == sorted(calls)
+    rows = re.findall(r"%(\S+) = f32\[64,1,2048\]\{2,1,0:T\(1,128\)[^}]*\} "
+                      r"get-tuple-element\(%flash_fwd[\w.]*\), index=1", text)
+    assert len(rows) == 1, rows
+    assert re.search(r"= f32\[64,1,2048\]\{2,0,1:T\(8,128\)[^}]*\} "
+                     rf"copy\(%{re.escape(rows[0])}\)", text)
     timing.register_program("eva_attention", lambda: text)
     scopes = timing.program_scopes("eva_attention")
     for name, _ in found:
