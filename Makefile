@@ -14,11 +14,6 @@ test:
 tier1:
 	set -o pipefail; rm -rf /tmp/_t1.log /tmp/_t1.xml; timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=$${PIPESTATUS[0]}; said=$$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null | head -n 1 | awk '{n=$$1-$$2-$$3-$$4; print (n<0 ? 0 : n)}'); echo DOTS_PASSED=$${said:-$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c)}; echo WORKERS_DOWN=$$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null); exit $$rc
 
-# Fast CPU smoke for the overlap sync engine: exercises the scheduler
-# logic (plan, hooks, parity, refusals, no-recompile) without TPUs.
-smoke-overlap:
-	env JAX_PLATFORMS=cpu python -m pytest tests/test_overlap.py tests/test_collectives.py -q -m 'not slow' -p no:cacheprovider
-
 # Seeded fault-injection suite (FaultPlan chaos: CRC quarantine, worker
 # eviction, reconnect backoff, PS crash-resume, checkpoint corruption).
 # Endurance chaos runs (>60 s, real CLI processes) are `slow`-marked so
@@ -125,9 +120,8 @@ smoke-bucket:
 # PARM/DELT/REPL frames, delta framing off the post-decode ring
 # (bitwise patches, full-snapshot fallback on ring miss / redial /
 # restore, forced-full after load_state_dict), encode-once delta
-# fanout, standby promotion through a compressed REPL stream, the
-# fused-sync-encode counter, and the CLI refusal matrix.  The fused
-# sync encode's parity tests ride smoke-overlap (tests/test_overlap.py).
+# fanout, standby promotion through a compressed REPL stream, and the
+# CLI refusal matrix.
 smoke-codec-wire:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_codec_wire.py -q -m 'not slow' -p no:cacheprovider
 
@@ -145,4 +139,4 @@ smoke-races:
 chip-smoke:
 	python chip_smoke.py
 
-.PHONY: chip-smoke test tier1 smoke-overlap smoke-chaos smoke-elastic smoke-robust smoke-shard smoke-failover smoke-hier smoke-overload lint lint-json lint-fast smoke-serve smoke-bucket smoke-codec-wire smoke-races
+.PHONY: chip-smoke test tier1 smoke-chaos smoke-elastic smoke-robust smoke-shard smoke-failover smoke-hier smoke-overload lint lint-json lint-fast smoke-serve smoke-bucket smoke-codec-wire smoke-races

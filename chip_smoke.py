@@ -536,18 +536,12 @@ def phase_kernel_parity(run: Run) -> None:
     """Each Pallas kernel against its jnp reference, on this device, at the
     shapes the phases above use and at awkward ones.  Codes must be equal
     bit for bit; scales and f32 sums close."""
-    from collections import OrderedDict
-
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
 
     from pytorch_ps_mpi_tpu.ops import pallas_kernels as pk
-    from pytorch_ps_mpi_tpu.ops.codecs import BlockQuantizeCodec
     from pytorch_ps_mpi_tpu.ops.flash_attention import flash_attention
-    from pytorch_ps_mpi_tpu.parallel import overlap
-    from pytorch_ps_mpi_tpu.parallel.mesh import make_ps_mesh
     from pytorch_ps_mpi_tpu.parallel.ring_attention import dense_attention
 
     impl = run.impl
@@ -600,37 +594,6 @@ def phase_kernel_parity(run: Run) -> None:
         c_r = pk.cast_sum(xb, block_rows=rows, impl="ref")
         close(c_k, c_r, 8 * eps, f"cast_sum n={n} world={w}")
         checks.append(f"codec n={n} rows={rows} world={w}")
-
-    # Fused bucket encode: a 4 MiB bucket of uneven leaves, codes compared
-    # with the reference bit for bit; then the whole fused exchange on the
-    # mesh of every visible device.
-    shapes = ([(3, 3, 256, 256), (3, 3, 256, 128), (256,), (1000, 77)]
-              if not run.tiny else [(40, 7), (111,), (5, 3, 2)])
-    cot = OrderedDict(("g%d" % i, jnp.asarray(
-        rng.randn(*s).astype(np.float32))) for i, s in enumerate(shapes))
-    enc = {i: jax.jit(lambda c, i=i: overlap._blockq_bucket_encode(
-        c, BlockQuantizeCodec(impl=i))[:2]) for i in (impl, "ref")}
-    (q_k, s_k), (q_r, s_r) = enc[impl](cot), enc["ref"](cot)
-    if not np.array_equal(np.asarray(q_k), np.asarray(q_r)):
-        raise AssertionError("fused bucket encode: codes differ")
-    close(s_k, s_r, 2 * eps, "fused bucket encode scales")
-    mesh = make_ps_mesh()
-
-    def fused(i):
-        codec = BlockQuantizeCodec(impl=i)
-
-        def body(scale):
-            return overlap._sync_blockq_fused(
-                OrderedDict((n, g * scale[0]) for n, g in cot.items()),
-                "ps", codec)
-        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("ps"),
-                                     out_specs=P(), check_vma=False))(
-            np.arange(1, run.n + 1, dtype=np.float32))
-    f_k, f_r = fused(impl), fused("ref")
-    for n in cot:
-        close(f_k[n], f_r[n], 8 * eps * run.n, f"fused exchange {n}")
-    checks.append(f"fused bucket encode {sum(g.size for g in cot.values())}"
-                  f" elements, exchange on {run.n} device(s)")
 
     # Flash attention, forward and all three gradients, against dense
     # attention computed in f32 from the same bf16 inputs.

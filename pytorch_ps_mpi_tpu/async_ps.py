@@ -408,13 +408,10 @@ class AsyncPS:
             # Compressed parameter wire (ISSUE 16, protocol v12): raw
             # f32 leaf bytes vs post-codec wire bytes per fresh PARM
             # encode (the bytes-per-version evidence — their ratio IS
-            # the compression gate), delta-ring serves vs full-snapshot
-            # fallbacks on the DELT path, and sync-path bucket syncs
-            # that ran the fused in-graph encode twin
-            # (`parallel.overlap.make_bucket_sync_fn(fused_encode=...)`).
+            # the compression gate) and delta-ring serves vs full-snapshot
+            # fallbacks on the DELT path.
             "parm_bytes_raw": 0, "parm_bytes_wire": 0,
-            "delta_hits": 0, "delta_misses": 0,
-            "fused_sync_encodes": 0}
+            "delta_hits": 0, "delta_misses": 0}
 
         if devices is None:
             devices = default_devices()

@@ -3011,7 +3011,7 @@ class AsyncPSWorker:
                     from .parallel.overlap import (make_async_bucket_step,
                                                    plan_overlap)
                     self._bucket_plan = plan_overlap(
-                        params, self.bucket_bytes, record=False)
+                        params, self.bucket_bytes)
                     fn = make_async_bucket_step(
                         loss_fn, self.code, self._bucket_plan, transform,
                         fused=self.fused_encode)

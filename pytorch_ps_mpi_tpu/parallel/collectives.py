@@ -250,33 +250,6 @@ def _axis_world(axis) -> int:
     return w
 
 
-def _allreduce_rs_ag(x, axis, world: int):
-    """All-reduce one flat array as explicit reduce-scatter + all-gather.
-
-    Mathematically the same cross-rank sum as ``lax.psum`` (an all-reduce
-    IS rs+ag on the wire), expressed as two HLO collectives per bucket in
-    the hope that XLA's scheduler would pipeline them against compute (the
-    reference's per-parameter pipelining intent,
-    `/root/reference/ps.py:125-127,140-147`).  What the v5e's compiler makes
-    of it (compiles for a described v5e:2x2, PERF.md §6 PRs 37 and 39): the
-    reduce-scatters are rewritten to synchronous all-reduces and the
-    all-gathers, synchronous too, are placed after the backward.  XLA's
-    combiner does not merge every psum into one end-of-backward all-reduce
-    either, as this text used to say: it makes twelve of GPT-2's and places
-    each where its last operand is made (PR 37).  `_allreduce_ring` is the
-    lowering that is in flight beside the backward; this one is kept for
-    ``decompose_allreduce`` and ``sync_mode="overlap"`` until a
-    `simplicity` issue decides (ROADMAP D2)."""
-    n = x.size
-    pad = (-n) % world
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
-    mine = lax.psum_scatter(x, axis, scatter_dimension=0, tiled=True)
-    full = lax.all_gather(mine, axis, axis=0, tiled=True)
-    return full[:n] if pad else full
-
-
-
 # ---------------------------------------------------------------------------
 # The bucketed sum as a ring of collective-permute hops
 # ---------------------------------------------------------------------------
@@ -445,7 +418,6 @@ def _ring_tree(tree: Tree, axis, world: int, bucket_bytes: int,
 
 def psum_tree_bucketed(tree: Tree, axis: str = PS_AXIS, *,
                        bucket_bytes: "int | None" = DEFAULT_BUCKET_BYTES,
-                       decompose: bool = False,
                        solo_bytes: "int | None" = None,
                        ring: bool = False) -> Tree:
     """`psum_tree` with dtype-bucketed flat all-reduces — the same
@@ -454,22 +426,18 @@ def psum_tree_bucketed(tree: Tree, axis: str = PS_AXIS, *,
     ~#buckets collectives instead of ~#leaves.
     ``bucket_bytes=None``/0 is the per-leaf lowering (one dispatch point:
     call sites pass their knob through unconditionally).
-    ``decompose=True`` lowers each bucket as reduce-scatter + all-gather
-    instead of one all-reduce (see `_allreduce_rs_ag`): same sum, two
-    collectives a bucket.
     ``ring=True`` (internal: `MPI_PS` sets it from what it sees of its
     mesh, no caller passes it by hand) sums each bucket through
     `_allreduce_ring`'s collective-permute hops instead (`_ring_tree`).
-    **Which lowering runs where, and why**: ``lax.psum`` on the CPU, on one
-    chip and wherever a caller asks for nothing else: one all-reduce a
+    **The two lowerings, and where each runs**: ``lax.psum`` on the CPU, on
+    one chip and wherever a caller asks for nothing else: one all-reduce a
     bucket, which XLA's combiner regroups (twelve for GPT-2 medium) and the
     v5e runs synchronously on its one core, each where its last operand is
-    made; the ring on several TPU chips, because a collective-permute is
-    the one collective the v5e's compiler keeps in flight beside the
-    backward (an asynchronous all-reduce is made and turned back, an
-    all-gather and a reduce-scatter are synchronous: PERF.md §6, PR 39);
-    ``decompose`` never by default (its reduce-scatters become all-reduces
-    again there).
+    made; the ring where `MPI_PS` passes ``ring=True``, on several TPU
+    chips, because a collective-permute is the one collective the v5e's
+    compiler keeps in flight beside the backward (an asynchronous
+    all-reduce is made and turned back, an all-gather and a reduce-scatter
+    are synchronous: PERF.md §6).
     ``solo_bytes`` (None = auto, ``bucket_bytes // 16``; 0 = legacy
     pack-everything): leaves at/above the threshold skip the shared
     bucket and sum solo — the concat-in/slice-out memcpy around a leaf
@@ -480,21 +448,10 @@ def psum_tree_bucketed(tree: Tree, axis: str = PS_AXIS, *,
         return _ring_tree(tree, axis, _axis_world(axis), bucket_bytes,
                           _solo_default(bucket_bytes, solo_bytes))
     if not bucket_bytes:
-        if decompose:  # per-leaf rs+ag: the per-param lowering still
-            # deserves the overlap effect the flag documents
-            world = _axis_world(axis)
-            return jax.tree.map(
-                lambda x: _allreduce_rs_ag(
-                    x.reshape(-1), axis, world).reshape(x.shape), tree)
         return psum_tree(tree, axis)
-    solo = _solo_default(bucket_bytes, solo_bytes)
-    if decompose:
-        world = _axis_world(axis)
-        return _bucketed_leafwise(
-            tree, lambda x: _allreduce_rs_ag(x, axis, world), bucket_bytes,
-            solo)
     return _bucketed_leafwise(
-        tree, lambda x: lax.psum(x, axis), bucket_bytes, solo)
+        tree, lambda x: lax.psum(x, axis), bucket_bytes,
+        _solo_default(bucket_bytes, solo_bytes))
 
 
 def allgather_tree_bucketed(tree: Tree, axis: str = PS_AXIS, *,

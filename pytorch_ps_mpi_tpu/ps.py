@@ -17,14 +17,15 @@ decode-sum, update — is **one jitted SPMD program** over a
 `jax.sharding.Mesh`, via `jax.shard_map`.  The reference's machinery dissolves:
 
 * backward hooks + a 200-thread encode pool (`ps.py:63-66,85,98-101`) existed
-  to overlap encoding with backward; here the gradient exchange is bucketed
-  (`bucket_mb`, `parallel/collectives.py`) into a few large flat transfers
-  (a handful of collectives where the per-parameter lowering has 130 for
-  ResNet-18), which XLA's scheduler may run beside the backward pass — the
-  thread pool's overlap, left to the compiler.  On the chip it does so for
-  collective-permutes and not for all-reduces, so on several TPU chips each
-  bucket's sum is a ring of hops (`MPI_PS._exchange_ring`; PERF.md,
-  `gpt2m-sync-dp4`);
+  to overlap encoding with backward; here the step has one gradient
+  exchange, after the backward, bucketed (`bucket_mb`,
+  `parallel/collectives.py`) into a few large flat transfers (a handful of
+  collectives where the per-parameter lowering, ``bucket_mb=0``, has 130
+  for ResNet-18), which XLA's scheduler may run beside the backward pass —
+  the thread pool's overlap, left to the compiler.  On the chip it does so
+  for collective-permutes and not for all-reduces, so on several TPU chips
+  each bucket's sum is a ring of hops, chosen from the mesh
+  (`MPI_PS._exchange_ring`; PERF.md, `gpt2m-sync-dp4`);
 * the ``Iallgather``-of-sizes protocol (`ps.py:140-147`) existed because
   pickled payloads have unknown sizes; codec outputs have static shapes, so
   gradient exchange is a single ``all_gather`` (or, for the identity codec, a
@@ -181,10 +182,6 @@ class MPI_PS:
                  error_feedback: bool = False, ema_decay: float | None = None,
                  bucket_mb: float | None =
                  collectives.DEFAULT_BUCKET_BYTES / (1 << 20),
-                 decompose_allreduce: bool = False,
-                 sync_mode: str | None = None,
-                 overlap_reducer: str = "rs_ag",
-                 fused_encode: bool = False,
                  consensus_every: int = 0,
                  consensus_policy: str = "abort",
                  **hyper):
@@ -230,70 +227,6 @@ class MPI_PS:
             raise ValueError(f"bucket_mb must be >= 0, got {bucket_mb}")
         self.bucket_bytes = (int(bucket_mb * (1 << 20))
                              if bucket_mb else None)
-        # Identity-path knob from before the chip had spoken: each bucket as
-        # explicit reduce-scatter + all-gather in place of one all-reduce.
-        # What the v5e's compiler does with the default (PERF.md §5 (4),
-        # PR 37): its combiner makes twelve all-reduces of GPT-2's 1.6 GB
-        # and its scheduler places each where its last operand is made, but
-        # each is synchronous and the core runs nothing beside it.  On
-        # several TPU chips the default therefore takes `_exchange_ring`'s
-        # lowering; this knob keeps its rs+ag form (never timed on the
-        # chip) until a `simplicity` issue decides (ROADMAP D2).
-        self.decompose_allreduce = bool(decompose_allreduce)
-        # WHEN the cross-rank gradient sum happens (`parallel/overlap.py`):
-        #   "post"     — after backward, one collective per parameter (the
-        #                reference's per-param loop transliterated);
-        #   "bucketed" — after backward, dtype-bucketed flat transfers
-        #                (the default whenever bucket_mb is set);
-        #   "overlap"  — bucket-scheduled custom_vjp hooks issue each
-        #                bucket's collective INSIDE the backward pass, as
-        #                soon as its last contributing layer's cotangents
-        #                exist — the reference's thread-pool pipelining
-        #                (`/root/reference/ps.py:63-66,98-101`), compiled.
-        if sync_mode is None:
-            sync_mode = "bucketed" if self.bucket_bytes else "post"
-        if sync_mode not in ("post", "bucketed", "overlap"):
-            raise ValueError(f"sync_mode must be one of ('post', 'bucketed',"
-                             f" 'overlap'), got {sync_mode!r}")
-        if sync_mode == "post":
-            self.bucket_bytes = None  # per-parameter lowering, explicitly
-        if overlap_reducer not in ("rs_ag", "psum"):
-            raise ValueError(f"overlap_reducer must be 'rs_ag' or 'psum', "
-                             f"got {overlap_reducer!r}")
-        self.sync_mode = sync_mode
-        self.overlap_reducer = overlap_reducer
-        # Fused per-bucket sync encode (ISSUE 16, the MFU residual):
-        # swap the overlap engine's per-leaf codec encode for ONE
-        # quantize sweep per bucket (`parallel.overlap.
-        # _sync_blockq_fused`).  Only meaningful under the overlap
-        # engine — anywhere else the knob would be silently inert, so
-        # it refuses (the CLI refusal-matrix discipline, in-process).
-        self.fused_encode = bool(fused_encode)
-        # Flipped by `_overlap_wrap` once the fused twin is actually
-        # compiled into the step program; read at each step() to count
-        # `fused_sync_encodes` (one per dispatched step, not per bucket).
-        self._count_fused_sync = False
-        if self.fused_encode and sync_mode != "overlap":
-            raise ValueError(
-                "fused_encode requires sync_mode='overlap' — the fused "
-                "per-bucket encode lives inside the overlap engine's "
-                "backward hooks and would be silently inert under "
-                f"sync_mode={sync_mode!r}")
-        if sync_mode == "overlap":
-            if error_feedback:
-                raise ValueError(
-                    "sync_mode='overlap' does not compose with "
-                    "error_feedback: the EF residual must be read and "
-                    "written around the codec inside each bucket's "
-                    "backward hook; use sync_mode='bucketed'")
-            if skip_nonfinite and not isinstance(self.code, IdentityCodec):
-                raise ValueError(
-                    "sync_mode='overlap' + skip_nonfinite needs the "
-                    "identity codec: the finiteness consensus then runs on "
-                    "the summed gradient (NaN/inf propagates through the "
-                    "sum), whereas a lossy codec could launder a NaN "
-                    "before any post-sync check; use sync_mode='bucketed', "
-                    "which checks the raw per-rank gradients pre-encode")
         # ZeRO-style sharded optimizer state: each data-parallel rank owns
         # 1/world of every elementwise state buffer (momentum, Adam
         # moments).  Gradients reduce-scatter straight to the owning chunk,
@@ -362,21 +295,6 @@ class MPI_PS:
                     -(-int(np.prod(p.shape)) // self.world_size))
                 for n, p in self.params.items()}
             self.state = self._chunk_and_place_state(self.state)
-        # The overlap engine's bucket schedule is a compile-time decision
-        # over the (static) parameter shapes; build it once here and record
-        # it so the chosen schedule is inspectable (`utils/timing.py`).
-        # bucket_mb=0/None auto-tunes (`overlap.auto_bucket_bytes`).
-        self.overlap_plan = None
-        if sync_mode == "overlap":
-            from .parallel import overlap as _overlap
-            from .utils.timing import record_overlap_schedule
-            self.overlap_plan = _overlap.plan_overlap(
-                self.params, self.bucket_bytes, world=self.world_size,
-                record=False)
-            record_overlap_schedule({
-                **self.overlap_plan.describe(),
-                "reducer": overlap_reducer, "codec": self.code.name,
-                "world": self.world_size, "zero": bool(zero)})
         # Optional per-step carried state beyond params/state/aux, one
         # extras tree so the jitted step's signature stays fixed: "ef" is
         # the per-rank EF residual ([world, ...], sharded over the data
@@ -414,11 +332,7 @@ class MPI_PS:
         # counters here, rollback events appended by the training loop.
         self.fault_stats: dict[str, Any] = {
             "sdc_checks": 0, "sdc_mismatches": 0, "sdc_rebroadcasts": 0,
-            "sdc_first_leaf": None, "sdc_events": [],
-            # Compressed-wire MFU residual (protocol v12): steps whose
-            # gradient sync ran through the fused per-bucket encode twin
-            # (one quantize sweep per bucket) instead of per-leaf encodes.
-            "fused_sync_encodes": 0, "rollbacks": []}
+            "sdc_first_leaf": None, "sdc_events": [], "rollbacks": []}
         # `ps.py:80` accumulator: one dict a step, the newest 65,536.
         self.timings: list[dict[str, float]] = BoundedList()
         self._byte_metrics: "dict[str, float] | None" = None
@@ -596,14 +510,13 @@ class MPI_PS:
         of asynchronous hops (`collectives._ring_tree`) where the default
         exchange runs over several TPU chips on one data axis, XLA's
         all-reduce, and the program it always made, everywhere else (the
-        CPU, one chip, ``zero``, ``decompose_allreduce``, another
-        ``sync_mode``, a codec).  On the v5e a bucket's `lax.psum` compiles
-        to a synchronous ``all-reduce`` that stops the core (28.4 ms of a
-        201.6 ms step in `gpt2m-sync-dp4`, ledger PR 37); the ring's hops
-        run beside the backward (PERF.md §6, PR 39)."""
+        CPU, one chip, ``zero``, ``bucket_mb=0``, a codec).  On the v5e a
+        bucket's `lax.psum` compiles to a synchronous ``all-reduce`` that
+        stops the core (28.4 ms of a 201.6 ms step in `gpt2m-sync-dp4`, in
+        the ledger); the ring's hops run beside the backward (PERF.md §6)."""
         return (isinstance(self.code, IdentityCodec) and len(self.axes) == 1
-                and self.world_size > 1 and self.sync_mode == "bucketed"
-                and not self.zero and not self.decompose_allreduce
+                and self.world_size > 1 and bool(self.bucket_bytes)
+                and not self.zero
                 and all(d.platform == "tpu" for d in self.mesh.devices.flat))
 
     def _summed_grads(self, grads):
@@ -613,7 +526,6 @@ class MPI_PS:
             with step_scope("exchange"):
                 return collectives.psum_tree_bucketed(
                     grads, self.axis, bucket_bytes=self.bucket_bytes,
-                    decompose=self.decompose_allreduce,
                     ring=self._exchange_ring())
         meta = {n: (g.shape, g.dtype) for n, g in grads.items()}
         codes = self._encode_all(grads)
@@ -657,33 +569,10 @@ class MPI_PS:
         table = {"ef": P(self.axes), "ema": P()}
         return OrderedDict((k, table[k]) for k in self.extras)
 
-    def _overlap_wrap(self, loss_fn):
-        """Wrap ``loss_fn`` so its parameter gradients come back cross-rank
-        SUMMED, with each bucket's collective issued inside the backward
-        pass (`parallel/overlap.py`).  Gradient-shaping that runs *after*
-        backward (pmean over extra axes, clip) is linear, so it commutes
-        with the in-backward sum — update math is unchanged."""
-        from .parallel import overlap as _overlap
-        codec = (None if isinstance(self.code, IdentityCodec) else self.code)
-        sync_fn = _overlap.make_bucket_sync_fn(
-            axis=self.axis, world=self.world_size,
-            codec=codec, reducer=self.overlap_reducer,
-            fused_encode=self.fused_encode)
-        if self.fused_encode:
-            # Host-side accounting: the fused twin replaces the per-leaf
-            # encode for EVERY bucket of every step compiled from here
-            # on; counted once per dispatched step in step().
-            self._count_fused_sync = True
-        return _overlap.wrap_loss(loss_fn, self.overlap_plan, sync_fn)
-
     def _make_spmd_step(self, loss_fn, has_aux: bool):
         identity = isinstance(self.code, IdentityCodec)
         use_ef = self.error_feedback
         ema_decay = self.ema_decay
-        overlap = self.sync_mode == "overlap"
-        if overlap:
-            loss_fn = self._overlap_wrap(loss_fn)
-
         counters = self._has_counters
 
         # The phases below and in the helpers they call run under
@@ -691,16 +580,11 @@ class MPI_PS:
         # on the operations, which `utils.timing.step_phase` reads back out
         # of the compiled text to split a device trace by phase (PERF.md §3).
         def core(params, state, aux, batch, extras):
-            # With overlap, `grads` leave the backward ALREADY cross-rank
-            # summed (the bucket hooks ran the exchange in-flight).
             loss, grads, new_aux = self._grads_and_aux(
                 loss_fn, has_aux, params, aux, batch)
             if self.skip_nonfinite:
                 # Checked on the RAW gradients, before the residual mixes
                 # in: a NaN batch must not poison the carried residual.
-                # (Overlap mode: the check sees the summed gradient —
-                # identity-codec only, enforced at construction, so any
-                # rank's NaN/inf propagates through the sum.)
                 bad = sum(jnp.sum(~jnp.isfinite(g)).astype(jnp.float32)
                           for g in jax.tree.leaves(grads))
                 with step_scope("exchange"):
@@ -714,21 +598,13 @@ class MPI_PS:
             if self.zero:
                 # Identity + zero skips the full sum entirely: the
                 # reduce-scatter inside _zero_sync IS the sync.
-                # Overlap mode instead arrives with the full sum in hand
-                # (paid inside backward); the chunk slice is free.
-                if overlap:
-                    d_sum = grads
-                elif not use_ef:
+                if not use_ef:
                     d_sum = None if identity else self._summed_grads(grads)
-                d_chunks = self._zero_sync(
-                    None if overlap else grads, d_sum)
+                d_chunks = self._zero_sync(grads, d_sum)
                 new_params, new_state = self._zero_apply(
                     params, state, d_chunks)
             else:
-                if overlap:
-                    d_ps = grads
-                else:
-                    d_ps = d_sum if use_ef else self._summed_grads(grads)
+                d_ps = d_sum if use_ef else self._summed_grads(grads)
                 if self.clip_norm is not None:
                     d_ps = self._clip_tree(d_ps)
                 new_params, new_state = self._apply_updates(
@@ -886,15 +762,6 @@ class MPI_PS:
         """
         if accum_steps < 1:
             raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
-        if accum_steps > 1 and self.sync_mode == "overlap":
-            # The bucket hooks live inside the per-microbatch backward: the
-            # scan would re-run the full exchange every microbatch (K x the
-            # wire traffic), defeating accumulation's purpose.  Refuse, do
-            # not silently degrade.
-            raise ValueError(
-                "sync_mode='overlap' does not compose with accum_steps > 1 "
-                "(each microbatch's backward would re-run the cross-rank "
-                "exchange); use sync_mode='bucketed' with accumulation")
         self._accum = int(accum_steps)
         self._loss_fn = loss_fn  # raw: wrapping happens at build time only
         self._remat = remat
@@ -991,8 +858,6 @@ class MPI_PS:
             else:
                 self.params, self.state, self.aux, loss, skipped = out
             self.steps_completed += 1
-            if self._count_fused_sync:
-                self.fault_stats["fused_sync_encodes"] += 1
             if block:
                 with span("sync.block") as wait:
                     jax.block_until_ready(out)

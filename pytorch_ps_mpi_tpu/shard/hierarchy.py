@@ -563,7 +563,7 @@ class LocalAggregator(AsyncPSServer):
             self._bucket_plan = plan_overlap(
                 OrderedDict((n, np.asarray(p))
                             for n, p in self.params.items()),
-                self._bucket_bytes, record=False)
+                self._bucket_bytes)
             if (transform is None
                     and bucket_streamable(
                         self.aggregate,

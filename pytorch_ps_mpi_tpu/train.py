@@ -120,9 +120,6 @@ def ps_kwargs_from_args(args) -> dict:
                 skip_nonfinite=args.skip_nonfinite,
                 error_feedback=args.error_feedback,
                 ema_decay=args.ema_decay, bucket_mb=args.bucket_mb,
-                decompose_allreduce=args.decompose_allreduce,
-                sync_mode=args.sync_mode,
-                overlap_reducer=args.overlap_reducer,
                 consensus_every=args.sdc_check_every,
                 consensus_policy=args.sdc_policy)
 
@@ -236,28 +233,6 @@ def main(argv=None):
                         "leaves concatenate into <=MB MiB flat buckets, "
                         "one collective each (0 = one collective per "
                         "parameter, the reference's per-param lowering)")
-    p.add_argument("--sync-mode", default=None,
-                   choices=["post", "bucketed", "overlap"],
-                   help="when the cross-rank gradient sum runs: 'post' = "
-                        "after backward, per-parameter collectives; "
-                        "'bucketed' = after backward, flat bucketed "
-                        "transfers (default when --bucket-mb > 0); "
-                        "'overlap' = each bucket's collective is issued "
-                        "INSIDE the backward pass via per-bucket "
-                        "custom-vjp hooks (--bucket-mb 0 auto-tunes the "
-                        "bucket size, parallel.overlap.auto_bucket_bytes)")
-    p.add_argument("--overlap-reducer", default="rs_ag",
-                   choices=["rs_ag", "psum"],
-                   help="--sync-mode overlap, identity codec: lower each "
-                        "bucket as reduce-scatter+all-gather (survives "
-                        "XLA's all-reduce combiner, the TPU overlap "
-                        "lowering) or as one all-reduce per bucket")
-    p.add_argument("--decompose-allreduce", action="store_true",
-                   help="lower each identity-codec gradient bucket as "
-                        "reduce-scatter + all-gather instead of one "
-                        "all-reduce: same sum, but XLA's combiner can't "
-                        "merge the buckets into one end-of-backward op, "
-                        "so the exchange overlaps backward compute")
     p.add_argument("--async-ps", action="store_true",
                    help="AsySG-InCon async PS (quota'd updates, "
                         "inconsistent reads) instead of the sync step")
@@ -910,11 +885,10 @@ def _dispatch(args):
                          "there is no replicated state to shard")
     if ((args.accum_steps > 1
          or args.clip_norm is not None or args.error_feedback
-         or args.ema_decay is not None or args.remat
-         or args.sync_mode is not None)
+         or args.ema_decay is not None or args.remat)
             and (args.async_ps or args.serve is not None or args.connect)):
         raise SystemExit("--accum-steps / --clip-norm / "
-                         "--error-feedback / --ema-decay / --sync-mode / "
+                         "--error-feedback / --ema-decay / "
                          "--remat apply to "
                          "the sync PS only; the async paths do not support "
                          "them yet (dropping the flag silently would be "

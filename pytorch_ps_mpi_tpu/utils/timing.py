@@ -40,33 +40,6 @@ STEP_METRIC_KEYS = (
 
 
 # ---------------------------------------------------------------------------
-# Overlap-schedule instrumentation
-# ---------------------------------------------------------------------------
-# The overlap sync engine (`parallel/overlap.py`) makes a scheduling
-# decision at compile time — how the gradient pytree partitions into
-# buckets — that the per-step wall-clock dicts above cannot see.  Every
-# constructed plan lands here so a run's chosen schedule (bucket count,
-# bytes, auto-tuned or explicit) is inspectable after the fact, the
-# schedule-level analogue of the reference's per-phase timing story.
-
-_OVERLAP_SCHEDULES: list[dict[str, Any]] = []
-
-
-def record_overlap_schedule(info: "dict[str, Any]") -> None:
-    """Append one schedule record (see `OverlapPlan.describe`)."""
-    _OVERLAP_SCHEDULES.append(dict(info))
-
-
-def overlap_schedules() -> "list[dict[str, Any]]":
-    """All schedule records since process start (or the last clear)."""
-    return list(_OVERLAP_SCHEDULES)
-
-
-def clear_overlap_schedules() -> None:
-    _OVERLAP_SCHEDULES.clear()
-
-
-# ---------------------------------------------------------------------------
 # Fault-tolerance observability
 # ---------------------------------------------------------------------------
 
@@ -346,10 +319,10 @@ def format_fault_stats(fs: "dict[str, Any]") -> str:
                 "infer_requests", "infer_shed", "param_swaps",
                 # Compressed parameter wire (ISSUE 16, v12): raw vs
                 # wire bytes per fresh PARM encode (their ratio is the
-                # compression evidence), delta-ring serves vs full
-                # fallbacks, and fused sync-encode bucket syncs.
+                # compression evidence) and delta-ring serves vs full
+                # fallbacks.
                 "parm_bytes_raw", "parm_bytes_wire",
-                "delta_hits", "delta_misses", "fused_sync_encodes",
+                "delta_hits", "delta_misses",
                 # Sync-trainer resilience counters (`MPI_PS.fault_stats`):
                 # SDC-guard runs, hits and rebroadcasts.
                 "sdc_checks", "sdc_mismatches", "sdc_rebroadcasts"):
@@ -692,10 +665,10 @@ def in_scope(op_name: str, scope: str) -> bool:
 
 
 # The scopes `MPI_PS.step` puts round the phases of its own program
-# (`ps.py:_make_spmd_step`, `parallel/overlap.py`), in the order `step_phase`
-# asks for them: a scope of the step's own beats the transformation wrappers
-# round it (the bucket hooks of ``sync_mode="overlap"`` run their sums inside
-# the backward), and ``ps.grad`` comes last.  The names hold a dot so that no
+# (`ps.py:_make_spmd_step`), in the order `step_phase` asks for them: a scope
+# of the step's own beats the transformation wrappers round it (were a phase
+# traced inside the backward, its operations would still count as that
+# phase), and ``ps.grad`` comes last.  The names hold a dot so that no
 # model scope or flax module is called the same.  (A codec's encode and
 # decode and the non-finite guard have no scope: no cell runs them, so no
 # metric would read one.)

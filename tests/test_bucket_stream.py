@@ -38,6 +38,7 @@ from pytorch_ps_mpi_tpu.models import init_mlp, mlp_loss_fn
 from pytorch_ps_mpi_tpu.multihost_async import (AsyncPSWorker,
                                                 AsyncSGDServer)
 from pytorch_ps_mpi_tpu.ops.codecs import get_codec
+from pytorch_ps_mpi_tpu.parallel import overlap as OV
 from pytorch_ps_mpi_tpu.parallel.overlap import (make_async_bucket_step,
                                                  merge_buckets,
                                                  plan_overlap, split_tree)
@@ -93,7 +94,7 @@ def _host_tree(tree):
 
 def test_split_merge_roundtrip_covers_every_param_once():
     params = _params()
-    plan = plan_overlap(params, 4096, record=False)
+    plan = plan_overlap(params, 4096)
     assert plan.n_buckets > 1
     subs = split_tree(params, plan)
     names = [n for sub in subs for n in sub]
@@ -101,6 +102,40 @@ def test_split_merge_roundtrip_covers_every_param_once():
     merged = merge_buckets(subs, list(params))
     assert list(merged) == list(params)
     assert all(merged[n] is params[n] for n in params)
+
+
+def test_plan_overlap_buckets_cover_all_params_once():
+    from collections import OrderedDict
+    params = OrderedDict(
+        (f"p{i}", np.zeros((100 * (i + 1),), np.float32)) for i in range(9))
+    plan = plan_overlap(params, 1200)
+    names = [n for b in plan.buckets for n in b]
+    assert sorted(names) == sorted(params)
+    assert plan.n_buckets > 1
+    assert plan.total_bytes == sum(v.nbytes for v in params.values())
+
+
+def test_auto_bucket_bytes_bounds_and_determinism(tmp_path, monkeypatch):
+    lo = OV.auto_bucket_bytes(10, world=8)
+    hi = OV.auto_bucket_bytes(100 << 30, world=8)
+    assert OV.MIN_BUCKET_BYTES <= lo <= OV.MAX_BUCKET_BYTES
+    assert hi == OV.MAX_BUCKET_BYTES
+    mid = OV.auto_bucket_bytes(256 << 20, world=8)
+    assert mid == OV.auto_bucket_bytes(256 << 20, world=8)
+    # A function of its arguments: the values it has always returned,
+    assert (lo, mid, hi) == (5733000, 16 << 20, 32 << 20)
+    assert OV.auto_bucket_bytes(1_600_000_000, world=4) == 32 << 20
+    # and the same ones from another working directory, with no file opened.
+    import builtins
+
+    def no_open(*a, **k):
+        raise AssertionError(f"auto_bucket_bytes opened a file: {a}")
+    with monkeypatch.context() as m:
+        m.chdir(tmp_path)
+        m.setattr(builtins, "open", no_open)
+        elsewhere = (OV.auto_bucket_bytes(10, world=8),
+                     OV.auto_bucket_bytes(256 << 20, world=8))
+    assert elsewhere == (lo, mid)
 
 
 def test_solo_plan_gives_large_leaves_their_own_bucket():
@@ -142,7 +177,7 @@ def test_solo_psum_bitwise_matches_packed_psum(mesh8):
 def test_fused_encode_matches_host_encode(codec):
     params = _params()
     code = get_codec(codec, "cpu")
-    plan = plan_overlap(params, 4096, record=False)
+    plan = plan_overlap(params, 4096)
     fused = make_async_bucket_step(mlp_loss_fn, code, plan, fused=True)
     host = make_async_bucket_step(mlp_loss_fn, code, plan, fused=False)
     batch = _batch()
@@ -162,7 +197,7 @@ def test_fused_encode_matches_host_encode(codec):
 def test_single_bucket_step_equals_whole_tree_step():
     params = _params()
     code = get_codec(None, "cpu")
-    plan = plan_overlap(params, 1 << 30, record=False)
+    plan = plan_overlap(params, 1 << 30)
     assert plan.n_buckets == 1
     bucketed = make_async_bucket_step(mlp_loss_fn, code, plan, fused=True)
     whole = make_worker_step(mlp_loss_fn, code)
@@ -194,7 +229,7 @@ def test_pallas_blockq_interpreter_encode_matches_reference():
 
 def test_bucketed_step_steady_state_never_retraces():
     params = _params()
-    plan = plan_overlap(params, 4096, record=False)
+    plan = plan_overlap(params, 4096)
     fn = make_async_bucket_step(mlp_loss_fn, get_codec(None, "cpu"), plan,
                                 fused=True)
     if not hasattr(fn, "_cache_size"):
@@ -218,7 +253,7 @@ def _drive(bucket_bytes, steps=3):
     kw = {} if bucket_bytes is None else dict(bucket_bytes=bucket_bytes)
     w = AsyncPSWorker("127.0.0.1", srv.address[1], **kw)
     version, params = w.pull()
-    plan = (plan_overlap(params, bucket_bytes, record=False)
+    plan = (plan_overlap(params, bucket_bytes)
             if bucket_bytes is not None else None)
     fn = (make_async_bucket_step(mlp_loss_fn, w.code, plan, fused=True)
           if plan is not None else make_worker_step(mlp_loss_fn, w.code))
@@ -279,7 +314,7 @@ def test_partial_bucket_times_out_without_double_apply():
     t = _serve(srv, 1, out)
     w = AsyncPSWorker("127.0.0.1", srv.address[1], bucket_bytes=4096)
     version, params = w.pull()
-    plan = plan_overlap(params, 4096, record=False)
+    plan = plan_overlap(params, 4096)
     fn = make_async_bucket_step(mlp_loss_fn, w.code, plan, fused=True)
     loss, buckets = fn(params, _batch())
     host = [_host_tree(sub) for sub in buckets]
@@ -308,7 +343,7 @@ def test_interleaved_rank_streams_fill_rank_distinct():
     ws = [AsyncPSWorker("127.0.0.1", srv.address[1], bucket_bytes=4096)
           for _ in range(2)]
     pulls = [w.pull() for w in ws]
-    plan = plan_overlap(pulls[0][1], 4096, record=False)
+    plan = plan_overlap(pulls[0][1], 4096)
     fn = make_async_bucket_step(mlp_loss_fn, ws[0].code, plan, fused=True)
     hosts = []
     for i, w in enumerate(ws):
@@ -356,7 +391,7 @@ def test_duplicate_bucket_frame_drops_without_decode():
     t = _serve(srv, 2, out)
     w = AsyncPSWorker("127.0.0.1", srv.address[1], bucket_bytes=4096)
     version, params = w.pull()
-    plan = plan_overlap(params, 4096, record=False)
+    plan = plan_overlap(params, 4096)
     fn = make_async_bucket_step(mlp_loss_fn, w.code, plan, fused=True)
     loss, buckets = fn(params, _batch())
     host = [_host_tree(sub) for sub in buckets]
@@ -595,7 +630,7 @@ def test_mismatched_bucket_plan_is_quarantined():
     t = _serve(srv, 1, out)
     w = AsyncPSWorker("127.0.0.1", srv.address[1], bucket_bytes=4096)
     version, params = w.pull()
-    plan = plan_overlap(params, 4096, record=False)
+    plan = plan_overlap(params, 4096)
     fn = make_async_bucket_step(mlp_loss_fn, w.code, plan, fused=True)
     loss, buckets = fn(params, _batch())
     host = [_host_tree(sub) for sub in buckets]

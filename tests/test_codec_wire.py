@@ -498,7 +498,7 @@ def test_v12_counters_render_and_stats_stay_keyed():
     srv = _server(quota=1, wire_codec="bf16", delta_parm=True)
     try:
         for key in ("parm_bytes_raw", "parm_bytes_wire", "delta_hits",
-                    "delta_misses", "fused_sync_encodes"):
+                    "delta_misses"):
             assert key in srv.fault_stats, key
         srv.fault_stats["parm_bytes_raw"] = 2704
         srv.fault_stats["parm_bytes_wire"] = 1420

@@ -179,15 +179,15 @@ def test_reduce_scatter(mesh8):
         np.asarray(out), np.arange(n * 2, dtype=np.float32) * n)
 
 
-def test_psum_bucketed_decomposed_matches_allreduce(mesh8):
-    """``decompose=True`` lowers each bucket as reduce-scatter+all-gather;
-    the result must equal the plain bucketed all-reduce (same elementwise
-    cross-rank sum).  Covers the padding path (leaf sizes not divisible by
-    world) and mixed dtypes (separate buckets)."""
+def test_psum_bucketed_matches_the_per_leaf_sum(mesh8):
+    """The bucketed all-reduce (same-dtype leaves concatenated, one
+    ``lax.psum`` a bucket) against the per-leaf lowering
+    (``bucket_bytes=None``): the same sums, shapes and dtypes.  Covers
+    leaf sizes that do not divide by the world (7, 3*5=15, 10) and mixed
+    dtypes (separate buckets)."""
     from jax.sharding import PartitionSpec as P
     n = world_size(mesh8)
     rng = np.random.RandomState(0)
-    # Sizes chosen so flat totals (7, 3*5=15, 10) are NOT multiples of 8.
     tree = {
         "a": jax.device_put(
             rng.randn(n, 7).astype(np.float32), batch_sharded(mesh8)),
@@ -197,30 +197,28 @@ def test_psum_bucketed_decomposed_matches_allreduce(mesh8):
             rng.randn(n, 10).astype(np.float16), batch_sharded(mesh8)),
     }
 
-    def run(decompose, bucket_bytes=1 << 20):
+    def run(bucket_bytes):
         def body(t):
             t = jax.tree.map(lambda v: jnp.squeeze(v, 0), t)
-            return C.psum_tree_bucketed(t, bucket_bytes=bucket_bytes,
-                                        decompose=decompose)
+            return C.psum_tree_bucketed(t, bucket_bytes=bucket_bytes)
         f = jax.jit(jax.shard_map(body, mesh=mesh8, in_specs=P("ps"),
                                   out_specs=P(), check_vma=False))
         return jax.device_get(f(tree))
 
-    ref = run(False)
-    # Bucketed AND per-leaf (bucket_bytes=None) decomposed lowerings: the
-    # flag must not silently no-op in the per-param configuration.
-    for dec in (run(True), run(True, bucket_bytes=None)):
-        for k in ref:
-            assert ref[k].shape == dec[k].shape
-            assert ref[k].dtype == dec[k].dtype
-            np.testing.assert_allclose(np.asarray(dec[k], np.float64),
-                                       np.asarray(ref[k], np.float64),
-                                       rtol=1e-3 if k == "c" else 1e-6)
+    ref, got = run(None), run(1 << 20)
+    for k in ref:
+        assert ref[k].shape == got[k].shape
+        assert ref[k].dtype == got[k].dtype
+        np.testing.assert_allclose(np.asarray(got[k], np.float64),
+                                   np.asarray(ref[k], np.float64),
+                                   rtol=1e-3 if k == "c" else 1e-6)
 
 
-def test_psum_bucketed_decomposed_tuple_axes():
-    """Hierarchical data-parallel axes (the hybrid (dcn, ps) shape): the
-    decomposed lowering must sum over BOTH axes like the psum it replaces."""
+@pytest.mark.parametrize("bucket_bytes", [1 << 20, None],
+                         ids=["bucketed", "per-leaf"])
+def test_psum_bucketed_tuple_axes(bucket_bytes):
+    """Hierarchical data-parallel axes (the hybrid (dcn, ps) shape): both
+    lowerings sum over BOTH axes."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from pytorch_ps_mpi_tpu.parallel.mesh import make_dp_tp_mesh
@@ -232,8 +230,7 @@ def test_psum_bucketed_decomposed_tuple_axes():
     def body(t):
         t = jnp.squeeze(t, 0)
         return C.psum_tree_bucketed({"g": t}, ("ps", "tp"),
-                                    bucket_bytes=1 << 20,
-                                    decompose=True)["g"]
+                                    bucket_bytes=bucket_bytes)["g"]
 
     f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(("ps", "tp")),
                               out_specs=P(), check_vma=False))

@@ -74,7 +74,7 @@ def phases_of(scopes):
      "dot_general", "backward"),
     ("jit(step)/ps.update/sub", "update"),
     ("jit(spmd_step)/shard_map/ps.grad/transpose(ps.grad)/jvp(ps.exchange)/"
-     "psum", "exchange"),                # the overlap hook, inside backward
+     "psum", "exchange"),                # a step scope inside backward
     ("jit(step)/ps.update/ps.exchange/psum", "exchange"),
     ("jit(step)/ps.exchange/ps.update/mul", "exchange"),   # not by depth
     ("jit(step)/ps.encode/round", None),    # no such scope of the step's
@@ -99,12 +99,12 @@ def test_the_scope_names_cannot_collide_with_a_model_scope():
 # ids spelt out: an id made from a set's order differs between xdist workers
 @pytest.mark.parametrize("kw", [
     pytest.param({}, id="default"),
-    pytest.param({"sync_mode": "overlap"}, id="overlap"),
+    pytest.param({"bucket_mb": 0}, id="per-parameter"),
     pytest.param({"zero": True}, id="zero"),
-    pytest.param({"zero": True, "sync_mode": "overlap"}, id="zero-overlap"),
+    pytest.param({"zero": True, "bucket_mb": 0}, id="zero-per-parameter"),
     pytest.param({"code": QuantizeCodec(8)}, id="codec"),
-    pytest.param({"code": QuantizeCodec(8), "sync_mode": "overlap"},
-                 id="codec-overlap"),
+    pytest.param({"code": QuantizeCodec(8), "bucket_mb": 0},
+                 id="codec-per-parameter"),
     pytest.param({"clip_norm": 1.0, "skip_nonfinite": True,
                   "ema_decay": 0.9}, id="clip-guard-ema"),
     pytest.param({"zero": True, "clip_norm": 1.0, "skip_nonfinite": True},
@@ -122,10 +122,12 @@ def test_every_phase_is_in_the_program_and_every_collective_is_exchange(
         assert step_phase(scopes[name]) == "exchange", (name, scopes[name])
 
 
-@pytest.mark.parametrize("mode", ["bucketed", "overlap"])
-def test_remat_adds_the_phase_remat_and_nothing_else_has_it(mesh4, mode):
-    _, plain, _ = stepped(mesh4, sync_mode=mode)
-    _, again, _ = stepped(mesh4, sync_mode=mode, remat=True)
+@pytest.mark.parametrize("kw", [
+    pytest.param({}, id="bucketed"),
+    pytest.param({"bucket_mb": 0}, id="per-parameter")])
+def test_remat_adds_the_phase_remat_and_nothing_else_has_it(mesh4, kw):
+    _, plain, _ = stepped(mesh4, **kw)
+    _, again, _ = stepped(mesh4, remat=True, **kw)
     assert "remat" not in phases_of(plain)
     assert "remat" in phases_of(again)
     # the same forward work, once under jvp and once more inside backward
